@@ -515,6 +515,10 @@ def main(argv=None) -> int:
     ap.add_argument("--no-stamp", action="store_true",
                     help="skip the bench-history regression stamp")
     args = ap.parse_args(argv)
+    # a measurement entry point: no TPU, no replay numbers (the library
+    # functions above stay backend-agnostic — the tests drive them on CPU)
+    from benchmarks.preflight import require_chip
+    require_chip()
     if args.preempt:
         line = run_preempt_replay(sf=args.sf, rounds=args.iters,
                                   stamp=not args.no_stamp)

@@ -1,12 +1,10 @@
 """spark-rapids-tpu: a TPU-native columnar SQL execution framework.
 
 Re-design of the RAPIDS Accelerator for Apache Spark (NVIDIA/spark-rapids @ v0.3.0)
-for TPU: plan-rewrite engine -> columnar TpuExec operators -> jax/XLA/Pallas kernels
+for TPU: plan-rewrite engine -> columnar TpuExec operators -> jitted jax/XLA programs
 over padded Arrow-layout device buffers -> mesh/ICI shuffle. See SURVEY.md (reference
 blueprint) and DESIGN.md (TPU-first decisions).
 """
-
-import os
 
 import jax
 
@@ -14,23 +12,18 @@ import jax
 jax.config.update("jax_enable_x64", True)
 
 # Persistent XLA compilation cache: fused-stage programs (sort-based
-# group-bys especially) can take minutes to compile, and every fresh
-# process would otherwise pay that again. Opt out / relocate with
-# SPARK_RAPIDS_TPU_COMPILE_CACHE=off|<dir>. This import-time default is
-# the XLA-level substrate only (>=2s compiles); setting
-# spark.rapids.tpu.sql.compile.cacheDir upgrades it to the full managed
-# cache — engine signature index, cold-vs-disk classification, compile
-# seconds metering, and persistence of EVERY program
+# group-bys especially) take tens of seconds each to compile for the
+# chip, and every fresh process would otherwise pay that again. ONE
+# helper decides the directory (JAX_COMPILATION_CACHE_DIR, else a
+# session's compile.cacheDir, else a fixed path in the checkout —
+# exec/compile_cache.xla_cache_dir); turning the cache off is jax's own
+# jax_enable_compilation_cache. Setting
+# spark.rapids.tpu.sql.compile.cacheDir adds the managed layer — engine
+# signature index, cold-vs-disk classification, prewarm corpus
 # (exec/compile_cache.py, docs/compile.md).
-_cache_dir = os.environ.get("SPARK_RAPIDS_TPU_COMPILE_CACHE", "")
-if _cache_dir.lower() != "off":
-    try:
-        jax.config.update(
-            "jax_compilation_cache_dir",
-            _cache_dir or os.path.expanduser("~/.cache/spark_rapids_tpu/xla"))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 2.0)
-    except Exception:                     # older jax without the knob
-        pass
+from .exec.compile_cache import (  # noqa: E402
+    point_xla_cache as _point_xla_cache, xla_cache_dir as _xla_cache_dir)
+_point_xla_cache(_xla_cache_dir())
 
 __version__ = "0.1.0"
 

@@ -567,8 +567,8 @@ HASH_OPTIMIZE_SORT = _conf("spark.rapids.tpu.sql.hashOptimizeSort.enabled").doc(
 AGG_PIPELINE_DEPTH = _conf("spark.rapids.tpu.sql.agg.pipelineDepth").doc(
     "Input batches kept in flight by the streaming aggregation before the "
     "oldest batch's partial result is landed: probe-stat readbacks overlap "
-    "device compute across this window, hiding dispatch/link latency "
-    "(dominant on tunneled or remote devices). The oldest half of the "
+    "device compute across this window, hiding the host-sync latency of "
+    "each readback. The oldest half of the "
     "window lands when it fills, so stat transfers get half a window of "
     "dispatch work to hide behind. Device residency grows by one input "
     "batch per slot"
@@ -646,12 +646,15 @@ COMPILE_CACHE_DIR = _conf("spark.rapids.tpu.sql.compile.cacheDir").doc(
     "Directory for the persistent (on-disk) XLA compilation cache plus "
     "the engine's fused-program signature index: a fresh process serving "
     "query shapes it has served before loads compiled executables from "
-    "disk instead of paying seconds of cold compile per shape (session "
-    "bootstrap wires jax.config.jax_compilation_cache_dir; the recompile "
-    "audit then splits builds into cold builds vs disk hits with compile "
-    "seconds per kernel family). Empty disables; an unusable directory "
-    "logs a loud warning and degrades to in-memory caching, never a "
-    "query failure (exec/compile_cache.py, docs/compile.md)"
+    "disk instead of paying seconds of cold compile per shape, and the "
+    "recompile audit splits builds into cold builds vs disk hits with "
+    "compile seconds per kernel family. Where the environment sets "
+    "JAX_COMPILATION_CACHE_DIR that directory is used instead and no "
+    "other is ever set. Empty leaves the XLA cache at its default place "
+    "(a fixed .jax_cache in the checkout) without the signature index; "
+    "an unusable directory logs a loud warning and degrades to in-memory "
+    "caching, never a query failure (exec/compile_cache.py, "
+    "docs/compile.md)"
 ).string_conf.create_with_default("")
 
 COMPILE_DONATE = _conf("spark.rapids.tpu.sql.compile.donate").doc(
@@ -942,8 +945,12 @@ class TpuConf:
         return TpuConf(merged)
 
     def is_operator_enabled(self, key: str, default: bool) -> bool:
+        # unset -> THIS caller's default, not the default of whoever
+        # registered the key first (an incompatible op asks with False,
+        # then with True: the answer must not depend on query order)
         entry = REGISTRY.register_dynamic(key, "(per-operator enable key)", default)
-        return self.get(entry)
+        raw = self._settings.get(key)
+        return default if raw is None else entry.convert(raw)
 
     # Convenience typed properties used across the codebase ------------------
     @property

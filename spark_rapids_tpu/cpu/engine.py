@@ -836,12 +836,11 @@ def execute(plan: lp.LogicalPlan) -> pd.DataFrame:
 
 
 def _obj_df(columns: Dict[str, List[Any]]) -> pd.DataFrame:
-    df = pd.DataFrame()
-    for k, v in columns.items():
-        df[k] = pd.Series(v, dtype=object)
-    if not columns:
-        return pd.DataFrame()
-    return df
+    # ONE construction: inserting column by column grows the (arrow-backed
+    # string) column Index once per insert, which pandas 3 makes ~1 ms
+    # each — per-pair residual evaluation in _exec_join calls this per row
+    return pd.DataFrame({k: pd.Series(v, dtype=object)
+                         for k, v in columns.items()})
 
 
 def _from_arrow(table) -> pd.DataFrame:
@@ -1210,18 +1209,21 @@ def _exec_join(plan: lp.Join) -> pd.DataFrame:
             matched_right.add(j)
 
     if residual is not None:
+        # the residual over ALL candidate pairs at once: one frame with a
+        # row per pair (evaluation is row-wise either way; a one-row frame
+        # per pair cost milliseconds of pandas construction each)
         keep_pairs = []
-        for (i, j) in pairs:
-            row = {}
+        if pairs:
+            cols = {}
             for c in lnames:
-                row[c] = [left[c].iloc[i]]
+                vals = left[c].tolist()
+                cols[c] = [vals[i] for i, _ in pairs]
             for c in rnames:
-                row[f"__r_{c}"] = [right[c].iloc[j]]
-            merged = _obj_df(row)
+                vals = right[c].tolist()
+                cols[f"__r_{c}"] = [vals[j] for _, j in pairs]
             cond = _rewire_condition(residual, lnames, rnames)
-            v = CpuEvaluator(merged).eval(cond)[0]
-            if v is True:
-                keep_pairs.append((i, j))
+            verdicts = CpuEvaluator(_obj_df(cols)).eval(cond)
+            keep_pairs = [p for p, v in zip(pairs, verdicts) if v is True]
         # recompute matched flags under the residual
         pairs = keep_pairs
         l_matched = [False] * len(left)
@@ -1237,20 +1239,20 @@ def _exec_join(plan: lp.Join) -> pd.DataFrame:
         keep = [i for i in range(len(left)) if not l_matched[i]]
         return left.iloc[keep].reset_index(drop=True)
 
+    # column lists once: a pandas scalar .iloc per cell costs ~10 us
+    lvals = [left[c].tolist() for c in lnames]
+    rvals = [right[c].tolist() for c in rnames]
     rows = []
     for (i, j) in pairs:
-        rows.append([left[c].iloc[i] for c in lnames] +
-                    [right[c].iloc[j] for c in rnames])
+        rows.append([v[i] for v in lvals] + [v[j] for v in rvals])
     if how in ("left", "full"):
         for i in range(len(left)):
             if not l_matched[i]:
-                rows.append([left[c].iloc[i] for c in lnames] +
-                            [None] * len(rnames))
+                rows.append([v[i] for v in lvals] + [None] * len(rnames))
     if how in ("right", "full"):
         for j in range(len(right)):
             if j not in matched_right:
-                rows.append([None] * len(lnames) +
-                            [right[c].iloc[j] for c in rnames])
+                rows.append([None] * len(lnames) + [v[j] for v in rvals])
     # positional build: duplicate column names (self-joins, USING) must not
     # collapse through a dict
     names = lnames + rnames

@@ -3,14 +3,18 @@
 Compile time and device memory are managed resources at the ``_fused_fn``
 funnel (``plan/physical.py``), not side effects:
 
-* **Persistent compile cache** — ``spark.rapids.tpu.sql.compile.cacheDir``
-  points JAX's on-disk XLA compilation cache at a directory
-  (``jax.config.jax_compilation_cache_dir``) AND keeps an engine-level
-  *signature index* (one JSONL line per fused-program cache key ever
-  built) beside it. A fresh process serving query shapes it has served
+* **Persistent compile cache** — JAX's on-disk XLA compilation cache
+  lives in ONE directory decided by :func:`xla_cache_dir`: where
+  ``JAX_COMPILATION_CACHE_DIR`` is set it is that directory and this
+  package sets no other; otherwise ``spark.rapids.tpu.sql.compile.cacheDir``
+  when a session names one, else a fixed ``.jax_cache`` inside the
+  checkout (the path is part of XLA's cache key: a directory that moves
+  never hits). Every program is kept, however fast it compiled. With
+  ``compile.cacheDir`` set the engine also keeps a *signature index*
+  (one JSONL line per fused-program cache key ever built) in that same
+  directory. A fresh process serving query shapes it has served
   before classifies each build as a **disk** hit (the executable loads
-  from the XLA cache instead of recompiling — the millions-of-users
-  restart scenario pays zero cold builds) versus a **cold** build, and
+  from the XLA cache instead of recompiling) versus a **cold** build, and
   the recompile audit reports the split per kernel family with compile
   *seconds*, not just counts. An unwritable/unusable cache dir logs a
   loud warning and degrades to in-memory-only caching — never a query
@@ -58,6 +62,12 @@ log = logging.getLogger("spark_rapids_tpu.compile")
 #: lets a fresh process distinguish disk hits from cold builds
 INDEX_NAME = "fused_signature_index.jsonl"
 
+#: where the XLA cache lives when neither JAX_COMPILATION_CACHE_DIR nor
+#: compile.cacheDir names a directory: one fixed path in the checkout
+_CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
 _lock = named_lock("exec.compile_cache._lock")
 _cache_dir: Optional[str] = None     # active persistent dir (None = off)
 _index: Set[str] = set()             # signature hashes known on disk
@@ -65,6 +75,37 @@ _index_path: Optional[str] = None
 _writable: bool = False
 _warned_unwritable: bool = False
 _donate_cache: Optional[bool] = None
+
+
+def xla_cache_dir(conf_dir: str = "") -> str:
+    """THE directory of the on-disk XLA compilation cache (and of the
+    signature index, prewarm corpus and AQE checkpoint kept beside it).
+    ``JAX_COMPILATION_CACHE_DIR`` wins outright; then a session's
+    ``compile.cacheDir``; then the fixed in-checkout default."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR", "").strip()
+    if env:
+        return env
+    if conf_dir:
+        return os.path.abspath(os.path.expanduser(conf_dir))
+    return _CHECKOUT_CACHE_DIR
+
+
+def point_xla_cache(d: str) -> None:
+    """Point JAX's persistent cache at ``d`` and keep EVERY program (a
+    cold run on a fresh machine wants the sub-second builds back too).
+    With ``JAX_COMPILATION_CACHE_DIR`` set jax already reads ``d`` from
+    the environment and no directory is set from code."""
+    import jax
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR", "").strip() and \
+            jax.config.jax_compilation_cache_dir != d:
+        jax.config.update("jax_compilation_cache_dir", d)
+        # jax binds its cache object to the directory at the first
+        # compile: a later move needs the binding dropped
+        from jax.experimental.compilation_cache import (
+            compilation_cache as jax_cc)
+        jax_cc.reset_cache()
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
 
 
 def configure(conf=None) -> None:
@@ -95,13 +136,14 @@ def configure(conf=None) -> None:
     except Exception:
         d = ""
     if not d:
+        point_xla_cache(xla_cache_dir())
         with _lock:
             _cache_dir = None
             _index_path = None
             _writable = False
             _index.clear()
         return
-    d = os.path.abspath(os.path.expanduser(d))
+    d = xla_cache_dir(d)
     index_path = os.path.join(d, INDEX_NAME)
     try:
         os.makedirs(d, exist_ok=True)
@@ -121,22 +163,7 @@ def configure(conf=None) -> None:
             _index_path = None
             _writable = False
         return
-    # point XLA's own on-disk compilation cache at the dir; each knob is
-    # best-effort (older jax lacks some, CPU backends gained support
-    # late) — a missing knob degrades that feature, never the session
-    try:
-        import jax
-        jax.config.update("jax_compilation_cache_dir", d)
-    except Exception as e:
-        log.warning("jax compilation cache unavailable (%s): signature "
-                    "index still recorded, executables recompile", e)
-    for knob, val in (("jax_persistent_cache_min_compile_time_secs", 0.0),
-                      ("jax_persistent_cache_min_entry_size_bytes", -1)):
-        try:
-            import jax
-            jax.config.update(knob, val)
-        except Exception:
-            pass
+    point_xla_cache(d)
     loaded: Set[str] = set()
     try:
         with open(index_path) as f:
@@ -284,6 +311,18 @@ def _map_count() -> int:
         return -1
 
 
+def drop_program_caches() -> None:
+    """Clear every registered compiled-program cache and collect: the
+    executables they pinned (and their process mappings) are released."""
+    for clear in list(_PROGRAM_CACHE_CLEARS):
+        try:
+            clear()
+        except Exception:
+            log.exception("program-cache clear failed")
+    import gc
+    gc.collect()
+
+
 def jit_map_guard() -> None:
     """Pre-compile check (TimedFirstCall first call): every
     ``_RELIEF_CHECK_EVERY`` builds, read the process map count and
@@ -313,13 +352,7 @@ def jit_map_guard() -> None:
         "are %s.", n, _map_limit, len(_PROGRAM_CACHE_CLEARS), count,
         "disk hits (compile.cacheDir set)" if _cache_dir
         else "cold (set compile.cacheDir to make them disk hits)")
-    for clear in list(_PROGRAM_CACHE_CLEARS):
-        try:
-            clear()
-        except Exception:
-            log.exception("program-cache clear failed during map relief")
-    import gc
-    gc.collect()
+    drop_program_caches()
     # NOTE: deliberately NOT jax.clear_caches() here — it would also
     # invalidate every LIVE jitted function's traced cache, turning one
     # relief into a process-wide retrace storm. Dropping the program
@@ -384,7 +417,8 @@ class TimedFirstCall:
             except OSError:
                 nmaps = -1
             with open(trace, "a") as f:
-                f.write(f"BEGIN {self._kind} {self._kernel} maps={nmaps} "
+                f.write(f"BEGIN {time.time():.1f} {self._kind} "
+                        f"{self._kernel} maps={nmaps} "
                         f"args={[getattr(a, 'shape', a) for a in args]}\n")
         t0 = time.perf_counter()
         out = self._fn(*args, **kwargs)
@@ -393,7 +427,7 @@ class TimedFirstCall:
                              self._kind)
         if trace:
             with open(trace, "a") as f:
-                f.write(f"END {self._kernel}\n")
+                f.write(f"END {time.time():.1f} {self._kernel}\n")
         return out
 
 

@@ -1,9 +1,9 @@
 """Background compile pool: cold fused-stage builds off the query thread
 (docs/compile.md §5, the ISSUE 17 tentpole).
 
-BENCH_r03 measured q6 COLD at 20.5s against ~221 Mrows/s warm fused
-throughput: first-touch latency is XLA whole-program compilation, paid
-synchronously on the thread that owes the user rows. This module moves
+A cold query's first-touch latency is XLA whole-program compilation
+(seconds to minutes on the chip, PERF.md), paid synchronously on the
+thread that owes the user rows. This module moves
 that compile OFF the query thread when the caller is latency-sensitive:
 
 * a **streaming collect** (``DataFrame.collect_iter``) must yield its

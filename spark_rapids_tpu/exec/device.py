@@ -37,17 +37,19 @@ class DeviceManager:
         self.memory_budget_bytes = self._compute_budget()
 
     def _compute_budget(self) -> int:
-        """allocFraction * device memory (GpuDeviceManager.scala:159-262)."""
+        """allocFraction * device memory (GpuDeviceManager.scala:159-262).
+        An accelerator that reports no ``bytes_limit`` is an error — a
+        guessed budget would size every batch and spill decision wrong;
+        only the CPU backend (tests) has none, and gets a fixed budget."""
         frac = self.conf.get(cfg.ALLOC_FRACTION)
-        stats = None
-        try:
-            stats = self.device.memory_stats()
-        except Exception:
-            stats = None
-        if stats and "bytes_limit" in stats:
-            return int(stats["bytes_limit"] * frac)
-        # CPU backend / no stats: fall back to a conservative fixed budget
-        return int(self.conf.get(cfg.BATCH_SIZE_BYTES)) * 8
+        if self.platform == "cpu":
+            return int(self.conf.get(cfg.BATCH_SIZE_BYTES)) * 8
+        stats = self.device.memory_stats()
+        if not stats or "bytes_limit" not in stats:
+            raise RuntimeError(
+                f"{self.device} reports no memory bytes_limit "
+                f"(memory_stats()={stats!r}): cannot size the HBM budget")
+        return int(stats["bytes_limit"] * frac)
 
     @classmethod
     def get(cls, conf: Optional[cfg.TpuConf] = None) -> "DeviceManager":

@@ -16,6 +16,7 @@ instead of N transient bytearrays.
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 import subprocess
 import threading
@@ -30,7 +31,9 @@ _lib_tried = False
 
 
 def _build_and_load() -> Optional[ctypes.CDLL]:
-    """Compile the C++ allocator once per interpreter (cached .so)."""
+    """Load the C++ allocator, compiling it from the tracked source on
+    first use (the ``.so`` is a build product, never committed). A failed
+    build or load warns once and leaves the python mirror in charge."""
     global _lib, _lib_tried
     with _lib_lock:
         if _lib is not None or _lib_tried:
@@ -42,10 +45,14 @@ def _build_and_load() -> Optional[ctypes.CDLL]:
         try:
             if (not os.path.exists(out) or
                     os.path.getmtime(out) < os.path.getmtime(src)):
+                # build beside the target and rename: concurrent first
+                # users (test workers) must never load a half-written .so
+                tmp = f"{out}.{os.getpid()}.tmp"
                 subprocess.run(  # lint: lock-blocking-ok one-time toolchain compile must be serialized; every later call hits the cached .so
                     ["g++", "-O2", "-shared", "-fPIC", "-std=c++17",
-                     src, "-o", out],
+                     src, "-o", tmp],
                     check=True, capture_output=True, timeout=120)
+                os.replace(tmp, out)
             lib = ctypes.CDLL(out)
             lib.asa_create.restype = ctypes.c_void_p
             lib.asa_create.argtypes = [ctypes.c_uint64]
@@ -59,7 +66,13 @@ def _build_and_load() -> Optional[ctypes.CDLL]:
                 getattr(lib, f).restype = ctypes.c_uint64
                 getattr(lib, f).argtypes = [ctypes.c_void_p]
             _lib = lib
-        except Exception:
+        except (OSError, subprocess.SubprocessError, AttributeError) as e:
+            detail = getattr(e, "stderr", b"") or b""
+            logging.getLogger("spark_rapids_tpu.native").warning(
+                "native staging allocator unavailable (%s%s): using the "
+                "pure-python mirror", e,
+                (": " + detail.decode(errors="replace").strip()[-300:])
+                if detail else "")
             _lib = None
         return _lib
 

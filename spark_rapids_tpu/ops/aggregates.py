@@ -634,114 +634,6 @@ def dense_feature_count(specs: Sequence[AggSpec]) -> int:
     return n
 
 
-# ---------------------------------------------------------------------------
-# Single-word-key MXU group-by: the fully TPU-native fast path
-# ---------------------------------------------------------------------------
-#
-# For a single fixed-width key column the whole group-by avoids large gathers
-# and scatters entirely:
-#   1. sort the VALUES of the order-encoded key (no argsort, no row gather)
-#   2. distinct count -> host sync -> static K bucket
-#   3. distinct keys via Kb-sized gathers (binary search on the sorted array)
-#   4. per-row group id = rank of the key among distinct keys, computed as a
-#      chunked compare-reduce (sum_g [uniq_g < key_i]) on the VPU — no gather
-#   5. every aggregate rides ONE chunked one-hot matmul on the MXU
-# Cost on 8M rows ~ one sort + one cumsum + one compare-reduce + one matmul.
-
-def _encode_single_word(col: Column) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """(uint64 order-encoded key, usable mask). Single-word dtypes only."""
-    words = K.encode_orderable_words(col.data, col.dtype)
-    if len(words) == 1:
-        return words[0].astype(jnp.uint64), col.validity
-    # floats encode as (nan_rank, value): fold into one word via bitcast
-    nan_rank, value = words
-    bits = jax.lax.bitcast_convert_type(value.astype(jnp.float64), jnp.uint64) \
-        if value.dtype == jnp.float64 else \
-        jax.lax.bitcast_convert_type(value.astype(jnp.float32),
-                                     jnp.uint32).astype(jnp.uint64)
-    sign = bits >> (63 if value.dtype == jnp.float64 else 31)
-    flip = jnp.where(sign == 1, ~bits,
-                     bits | jnp.uint64(0x8000_0000_0000_0000))
-    return (nan_rank.astype(jnp.uint64) << 63) | (flip >> 1), col.validity
-
-
-def _decode_single_word(enc: jnp.ndarray, dtype: dt.DType) -> jnp.ndarray:
-    if dtype == dt.BOOL:
-        return enc.astype(jnp.uint8) != 0
-    w = dtype.byte_width
-    u = enc.astype(_UNSIGNED_BY_W[w]) ^ jnp.asarray(
-        K._SIGNBIT[w], dtype=_UNSIGNED_BY_W[w])
-    return u.astype(dtype.numpy_dtype)
-
-
-_UNSIGNED_BY_W = {1: jnp.uint8, 2: jnp.uint16, 4: jnp.uint32, 8: jnp.uint64}
-
-_KEY_SENTINEL = jnp.uint64(0xFFFF_FFFF_FFFF_FFFF)
-
-
-def _singleword_supported(col: Column) -> bool:
-    return col.dtype != dt.STRING and not col.dtype.is_floating
-
-
-def groupby_singleword(key_col: Column, specs: Sequence[AggSpec],
-                       num_rows, capacity: int,
-                       extra_mask: Optional[jnp.ndarray] = None
-                       ) -> Optional[Tuple[List[Column], List[Column], int]]:
-    """MXU group-by for one fixed-width integral key. Returns None when the
-    distinct-count bucket exceeds MATMUL_MAX_GROUPS (caller falls back).
-    NULL keys group together under the sentinel slot (Spark groupby keeps
-    null groups)."""
-    live = jnp.arange(capacity) < num_rows
-    if extra_mask is not None:
-        live = live & extra_mask
-    enc, usable = _encode_single_word(key_col)
-    # null keys get sentinel-1 (still a group); padding gets the sentinel
-    enc = jnp.where(live & usable, enc,
-                    jnp.where(live, _KEY_SENTINEL - 1, _KEY_SENTINEL))
-    sorted_enc = jnp.sort(enc)
-    prev = jnp.concatenate([sorted_enc[:1] ^ jnp.uint64(1), sorted_enc[:-1]])
-    starts = (sorted_enc != prev) & (sorted_enc != _KEY_SENTINEL)
-    n_groups = int(jnp.sum(starts))  # lint: host-sync-ok single-word group-count sync sizes the dense bucket (documented dynamic-size read)
-    if n_groups == 0:
-        return [], [], 0
-
-    from ..columnar.column import bucket as _bucket
-    Kb = _bucket(n_groups, 128)
-    if Kb > MATMUL_MAX_GROUPS:
-        return None
-
-    seg_sorted = jnp.cumsum(starts.astype(jnp.int32)) - 1
-    pos = jnp.searchsorted(seg_sorted, jnp.arange(Kb, dtype=jnp.int32),
-                           side="left")
-    uniq = sorted_enc[jnp.clip(pos, 0, capacity - 1)]
-    uniq = jnp.where(jnp.arange(Kb) < n_groups, uniq, _KEY_SENTINEL)
-
-    # per-row rank among distinct keys: chunked compare-reduce (VPU)
-    ch = _mm_chunks(capacity)
-    encc = enc.reshape(ch, -1)
-
-    def per_chunk(kk):
-        return jnp.sum((kk[:, None] > uniq[None, :]).astype(jnp.int32),
-                       axis=1)
-
-    seg_ids = jax.lax.map(per_chunk, encc).reshape(-1)
-    seg_ids = jnp.clip(seg_ids, 0, Kb - 1)
-
-    group_live = jnp.arange(Kb) < n_groups
-    key_data = _decode_single_word(uniq, key_col.dtype)
-    null_slot = uniq == _KEY_SENTINEL - 1
-    key_valid = group_live & ~null_slot
-    key_data = jnp.where(key_valid, key_data,
-                         jnp.zeros((), key_data.dtype))
-    out_keys = [Column(key_col.dtype, key_data, key_valid)]
-
-    out_aggs: List[Column] = []
-    for spec in specs:
-        agg = segment_aggregate_matmul(spec, seg_ids, live, Kb)
-        out_aggs.append(_mask_to(agg, group_live))
-    return out_keys, out_aggs, n_groups
-
-
 def _dense_spec_supported(spec: AggSpec) -> bool:
     if spec.op in ("count", "count_star"):
         return True

@@ -95,6 +95,39 @@ def _hash_bytes(data: jnp.ndarray, lengths: jnp.ndarray,
     return _fmix(h1, lengths)
 
 
+_POW2_STEPS = (512, 256, 128, 64, 32, 16, 8, 4, 2, 1)
+
+
+def _float64_bits(x: jnp.ndarray) -> jnp.ndarray:
+    """``Double.doubleToLongBits`` by arithmetic: int64 IEEE-754 bits of a
+    float64 array, NaN canonical. There is deliberately NO f64->int
+    bitcast here — the TPU compiler's 64-bit rewrite does not implement
+    one (only the int->f64 direction), so a bitcast in a hash-partitioned
+    key would make the whole exchange program uncompilable on the chip.
+    Every scaling is by a power of two, so the result is bit-exact
+    wherever f64 arithmetic is IEEE (tests pin it against numpy's view).
+    Subnormals and -0.0 read as +0.0: XLA flushes subnormals in every
+    comparison the engine makes, and Spark's hash normalizes -0.0."""
+    a0 = jnp.abs(x)
+    tiny = a0 < 2.0 ** -1022
+    a = jnp.where(jnp.isnan(x) | jnp.isinf(x) | tiny, 1.0, a0)
+    e = jnp.zeros(x.shape, jnp.int32)
+    for k in _POW2_STEPS:                 # a >= 2: scale down into [1, 2)
+        big = a >= 2.0 ** k
+        a = jnp.where(big, a * 2.0 ** -k, a)
+        e = e + jnp.where(big, k, 0)
+    for k in _POW2_STEPS:                 # a < 1: scale up into [1, 2)
+        small = a < 2.0 ** (1 - k)
+        a = jnp.where(small, a * 2.0 ** k, a)
+        e = e - jnp.where(small, k, 0)
+    bits = ((e + 1023).astype(jnp.int64) << 52) | \
+        ((a - 1.0) * 2.0 ** 52).astype(jnp.int64)
+    bits = jnp.where(tiny, jnp.int64(0), bits)
+    bits = jnp.where(jnp.isinf(x), jnp.int64(0x7FF0_0000_0000_0000), bits)
+    bits = jnp.where(x < 0, bits | jnp.int64(-(1 << 63)), bits)
+    return jnp.where(jnp.isnan(x), jnp.int64(0x7FF8_0000_0000_0000), bits)
+
+
 def murmur3_column(col: Column, seed: jnp.ndarray) -> jnp.ndarray:
     """int32 hash per row; NULL rows leave the seed unchanged (Spark semantics:
     null columns don't contribute to the hash)."""
@@ -105,9 +138,7 @@ def murmur3_column(col: Column, seed: jnp.ndarray) -> jnp.ndarray:
     elif col.dtype == dt.FLOAT64:
         # Spark: normalize -0.0 to 0.0, hash as long bits
         norm = jnp.where(col.data == 0.0, 0.0, col.data)
-        import jax
-        bits = jax.lax.bitcast_convert_type(norm, jnp.int64)
-        h = _hash_int64(bits, seed)
+        h = _hash_int64(_float64_bits(norm), seed)
     elif col.dtype == dt.FLOAT32:
         norm = jnp.where(col.data == 0.0, jnp.float32(0.0), col.data)
         import jax

@@ -8,9 +8,10 @@ eagerly, under ``jax.jit``, or inside a fused whole-stage computation (DESIGN.md
 Key techniques (TPU-first, no data-dependent shapes):
 * filter = stable compaction by ``argsort`` of the keep-mask — output capacity equals
   input capacity, the true row count travels as a device scalar
-* sort = ``jnp.lexsort`` over *order-preserving unsigned key encodings* (sign-flip for
-  ints, IEEE total-order trick for floats, big-endian packed words for strings) with
-  explicit null-rank and padding-rank keys
+* sort = lexicographic order over *order-preserving unsigned key encodings* (sign-flip
+  for ints, floats kept as floats behind a NaN rank, big-endian packed words for
+  strings) with explicit null-rank and padding-rank keys, taken as stable
+  least-significant-first passes of one two-operand sort (``_lexsort_passes``)
 """
 
 from __future__ import annotations
@@ -190,9 +191,57 @@ def sort_indices(keys: Sequence[SortKey], num_rows, capacity: int,
     msf: List[Tuple[jnp.ndarray, Optional[int]]] = [(pad_rank, 1)]
     for key in keys:
         msf.extend(_key_arrays_bits(key))
-    packed = pack_key_bits(msf)
-    # jnp.lexsort wants least-significant first
-    return jnp.lexsort(tuple(reversed(packed)))
+    return _lexsort_passes(pack_key_bits(msf))
+
+
+def _sort_pass(lane: jnp.ndarray, perm: jnp.ndarray) -> jnp.ndarray:
+    """One stable pass: reorder ``perm`` by ``lane`` (read through it)."""
+    _, perm = jax.lax.sort((lane[perm], perm), num_keys=1, is_stable=True)
+    return perm
+
+
+def _lexsort_passes(lanes_msf: List[jnp.ndarray]) -> jnp.ndarray:
+    """Stable int32 permutation ordering rows by most-significant-first key
+    lanes — ``jnp.lexsort`` done as least-significant-first PASSES of one
+    two-operand stable sort (key lane, permutation), the uint32 lanes
+    walked by a ``fori_loop`` so the program holds ONE sort instruction
+    however many lanes there are.
+
+    Why not one variadic sort: the TPU compiler's cost for a sort grows
+    steeply with its operand count — a 6-lane lexsort of 64 Ki rows took
+    it 270 s on the chip (q3's partial group-by) where this loop takes
+    ~20 s — and with every large sort at 30-100 s, the operand count was
+    what made a cold query take twenty minutes. Same permutation: stable
+    LSD passes ARE the lexicographic order.
+
+    uint64 lanes (values wider than 32 bits) split into two uint32 lanes
+    first — the TPU emulates 64-bit compares as two 32-bit ones anyway;
+    float lanes (unpackable value keys) get a pass of their own."""
+    lanes: List[jnp.ndarray] = []
+    for a in lanes_msf:
+        if a.dtype == jnp.uint64:
+            lanes += [(a >> jnp.uint64(32)).astype(jnp.uint32),
+                      a.astype(jnp.uint32)]
+        else:
+            lanes.append(a)
+    perm = jnp.arange(lanes[0].shape[0], dtype=jnp.int32)
+    hi = len(lanes)
+    while hi > 0:                        # least-significant lane first
+        lo = hi
+        while lo > 0 and lanes[lo - 1].dtype == jnp.uint32:
+            lo -= 1
+        if hi - lo > 1:                  # a run of uint32 lanes: one loop
+            run = jnp.stack(lanes[lo:hi])
+            k = hi - lo
+            perm = jax.lax.fori_loop(
+                0, k, lambda t, p, run=run, k=k: _sort_pass(
+                    jax.lax.dynamic_index_in_dim(run, k - 1 - t, 0,
+                                                 keepdims=False), p), perm)
+            hi = lo
+        else:
+            perm = _sort_pass(lanes[hi - 1], perm)
+            hi -= 1
+    return perm
 
 
 # ---------------------------------------------------------------------------

@@ -25,7 +25,8 @@ from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax import shard_map
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..columnar import dtypes as dt
 from ..columnar.batch import ColumnarBatch
@@ -36,9 +37,14 @@ from ..ops.hashing import murmur3_batch
 
 
 def make_mesh(n_devices: Optional[int] = None) -> Mesh:
+    """One ``workers`` axis over the first ``n_devices`` devices, ordered
+    by the interconnect (neighbours on the axis are neighbours on the
+    ICI ring where the platform has one), not by enumeration."""
+    from jax.experimental import mesh_utils
     devs = jax.devices()
     n = n_devices or len(devs)
-    return Mesh(np.array(devs[:n]), ("workers",))
+    return Mesh(mesh_utils.create_device_mesh((n,), devices=devs[:n]),
+                ("workers",))
 
 
 def maybe_mesh(conf=None) -> Optional[Mesh]:
@@ -110,16 +116,77 @@ def _cached_fn(key: tuple, builder):
 
 
 def _shard_map(fn, mesh: Mesh, in_specs, out_specs):
-    try:
-        from jax import shard_map
-    except ImportError:          # older jax
-        from jax.experimental.shard_map import shard_map
-    try:
-        return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=False)
-    except TypeError:            # older jax spelling
-        return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
+    return shard_map(fn, mesh=mesh, in_specs=in_specs,
+                     out_specs=out_specs, check_vma=False)
+
+
+# ---------------------------------------------------------------------------
+# Stage boundary: per-worker shards <-> the SPMD program's global arrays
+# ---------------------------------------------------------------------------
+
+#: device spread of the last call of each SPMD stage kind, for the
+#: multichip smoke: {kind: {"in": distinct input devices, "out": ...}}
+_PLACEMENT: Dict[str, Dict[str, int]] = {}
+
+
+def placement_report() -> Dict[str, Dict[str, int]]:
+    """How many distinct devices held the inputs / outputs of the last
+    call of each SPMD stage kind (``groupby``, ``copart``, ``sort``,
+    ``pexch``, ``groupby-round``)."""
+    return {k: dict(v) for k, v in _PLACEMENT.items()}
+
+
+def _spread(arrays) -> int:
+    devs = set()
+    for a in arrays:
+        devs.update(sh.device for sh in a.addressable_shards)
+    return len(devs)
+
+
+def _place_shards(mesh: Mesh, per_worker: List[List[jnp.ndarray]]
+                  ) -> List[jax.Array]:
+    """One global ``[n, ...]`` array per array position, worker w's slice
+    RESIDENT on mesh device w. Each slice is copied straight to its own
+    device: a ``jnp.stack`` would first materialize all n shards on the
+    default device and leave the jit to scatter them."""
+    devs = list(mesh.devices.flat)
+    sharding = NamedSharding(mesh, P("workers"))
+    out = []
+    for i in range(len(per_worker[0])):
+        pieces = [jax.device_put(pw[i], d)[None]
+                  for pw, d in zip(per_worker, devs)]
+        out.append(jax.make_array_from_single_device_arrays(
+            (len(devs),) + pieces[0].shape[1:], sharding, pieces))
+    return out
+
+
+def _place_counts(mesh: Mesh, counts: Sequence[int]) -> jax.Array:
+    return jax.device_put(np.asarray(counts, dtype=np.int32),
+                          NamedSharding(mesh, P("workers")))
+
+
+def _call_spmd(kind: str, fn, inputs: Sequence[jax.Array]
+               ) -> Tuple[jax.Array, ...]:
+    outs = fn(*inputs)
+    _PLACEMENT[kind] = {"in": _spread(inputs), "out": _spread(outs)}  # lint: unguarded-ok last-call diagnostic; a racing overwrite loses one report, never data
+    return outs
+
+
+def _worker_outputs(mesh: Mesh, outs: Sequence[jax.Array]
+                    ) -> List[List[jnp.ndarray]]:
+    """Per worker, its slice of every SPMD output, gathered onto the
+    default device — the one placement every downstream operator (and
+    every merge of partitions) already assumes. ``o[w]`` would instead
+    all-gather each slice onto EVERY mesh device and run everything
+    downstream replicated."""
+    home = jax.local_devices()[0]
+    rows: List[List[jnp.ndarray]] = [[None] * len(outs)
+                                     for _ in range(mesh.devices.size)]
+    for j, o in enumerate(outs):
+        for sh in o.addressable_shards:
+            rows[sh.index[0].start or 0][j] = jax.device_put(
+                sh.data, home)[0]
+    return rows
 
 
 # ---------------------------------------------------------------------------
@@ -251,17 +318,17 @@ def run_partition_exchange(mesh: Mesh, batches: List[ColumnarBatch],
     assert len(batches) == n and len(pids) == n, "one shard per worker"
     cap = max(b.capacity for b in batches)
     col_dtypes = [c.dtype for c in batches[0].columns]
-    stacked = _stack_shards(batches, cap)
-    pid_stack = jnp.stack([
-        p if p.shape[0] == cap else
-        jnp.zeros(cap, jnp.int32).at[:p.shape[0]].set(p)
-        for p in pids]).astype(jnp.int32)
-    counts = jnp.asarray([b.num_rows for b in batches], dtype=jnp.int32)
+    per_worker = _shard_arrays(batches, cap)
+    for arrays, p in zip(per_worker, pids):
+        arrays.append((p if p.shape[0] == cap else
+                       jnp.zeros(cap, jnp.int32).at[:p.shape[0]].set(p)
+                       ).astype(jnp.int32))
     fn = _cached_fn(
         ("pexch", _mesh_key(mesh), tuple(col_dtypes), cap, num_partitions),
         lambda: partition_exchange_fn(mesh, col_dtypes, cap,
                                       num_partitions))
-    outs = fn(*stacked, pid_stack, counts)
+    outs = _call_spmd("pexch", fn, _place_shards(mesh, per_worker) + [
+        _place_counts(mesh, [b.num_rows for b in batches])])
     from ..analysis.sync_audit import allowed_host_transfer
     with allowed_host_transfer("ici exchange sizing"):
         pcounts = np.asarray(outs[-1])     # ONE readback per exchange
@@ -275,8 +342,7 @@ def run_partition_exchange(mesh: Mesh, batches: List[ColumnarBatch],
                   {"workers": int(n), "partitions": int(num_partitions),
                    "rows": int(pcounts.sum())})
     results: List[Tuple[List[Column], np.ndarray]] = []
-    for w in range(n):
-        arrays = [o[w] for o in outs[:-1]]
+    for w, arrays in enumerate(_worker_outputs(mesh, outs[:-1])):
         results.append((_rebuild_columns(col_dtypes, arrays), pcounts[w]))
     return results
 
@@ -454,19 +520,35 @@ def copartition_exchange_fn(mesh: Mesh, col_dtypes: Sequence[dt.DType],
     return jax.jit(_shard_map(per_worker, mesh, in_specs, P("workers")))
 
 
-def _stack_shards(batches: List[ColumnarBatch], cap: int) -> List[jnp.ndarray]:
-    """Stack per-worker batches (rebucketed to a common cap) on a leading
-    workers axis, one stacked array per underlying column array."""
+def _shard_arrays(batches: List[ColumnarBatch], cap: int,
+                  columns: Optional[Sequence[int]] = None
+                  ) -> List[List[jnp.ndarray]]:
+    """Per worker, the flat arrays of its batch's columns (all, or the
+    ``columns`` positions in that order) rebucketed to the common ``cap``
+    (uniform shapes let the whole stage trace once)."""
     per_worker = []
     for b in batches:
         arrays = []
-        for c in b.columns:
+        for c in (b.columns if columns is None
+                  else [b.columns[i] for i in columns]):
             if c.capacity != cap:
                 c = K.rebucket_column(c, b.num_rows, cap)
             arrays.extend(c.arrays())
         per_worker.append(arrays)
-    return [jnp.stack([pw[i] for pw in per_worker])
-            for i in range(len(per_worker[0]))]
+    return per_worker
+
+
+def _exchanged_batches(mesh: Mesh, outs, schema: dt.Schema,
+                       col_dtypes: Sequence[dt.DType]
+                       ) -> List[ColumnarBatch]:
+    """Per-worker result batches of a row-moving SPMD stage whose last
+    output is the per-worker received row count (ONE readback)."""
+    from ..analysis.sync_audit import allowed_host_transfer
+    with allowed_host_transfer("mesh stage sizing"):
+        recv = np.asarray(outs[-1])  # lint: host-sync-ok mesh stage boundary: ONE per-worker row-count readback sizes the output batches
+    return [ColumnarBatch(schema, _rebuild_columns(col_dtypes, arrays),
+                          int(recv[w]))
+            for w, arrays in enumerate(_worker_outputs(mesh, outs[:-1]))]
 
 
 def run_copartition_exchange(mesh: Mesh, batches: List[ColumnarBatch],
@@ -478,21 +560,14 @@ def run_copartition_exchange(mesh: Mesh, batches: List[ColumnarBatch],
     assert len(batches) == n, "one shard per worker"
     cap = max(b.capacity for b in batches)
     col_dtypes = [c.dtype for c in batches[0].columns]
-    stacked = _stack_shards(batches, cap)
-    counts = jnp.asarray([b.num_rows for b in batches], dtype=jnp.int32)
     fn = _cached_fn(
         ("copart", _mesh_key(mesh), tuple(col_dtypes),
          tuple(key_positions), cap),
         lambda: copartition_exchange_fn(mesh, col_dtypes, key_positions, cap))
-    outs = fn(*stacked, counts)
-    schema = batches[0].schema
-    results = []
-    for w in range(n):
-        arrays = [o[w] for o in outs[:-1]]
-        recv_n = int(outs[-1][w])
-        cols = _rebuild_columns(col_dtypes, arrays)
-        results.append(ColumnarBatch(schema, cols, recv_n))
-    return results
+    outs = _call_spmd("copart", fn, _place_shards(
+        mesh, _shard_arrays(batches, cap)) + [
+        _place_counts(mesh, [b.num_rows for b in batches])])
+    return _exchanged_batches(mesh, outs, batches[0].schema, col_dtypes)
 
 
 # ---------------------------------------------------------------------------
@@ -616,23 +691,16 @@ def run_distributed_sort(mesh: Mesh, batches: List[ColumnarBatch],
     assert len(batches) == n, "one shard per worker"
     cap = max(b.capacity for b in batches)
     col_dtypes = [c.dtype for c in batches[0].columns]
-    stacked = _stack_shards(batches, cap)
-    counts = jnp.asarray([b.num_rows for b in batches], dtype=jnp.int32)
     fn = _cached_fn(
         ("sort", _mesh_key(mesh), tuple(col_dtypes), tuple(key_positions),
          tuple(ascending), tuple(nulls_first), cap),
         lambda: distributed_sort_fn(mesh, col_dtypes, key_positions,
                                     tuple(ascending), tuple(nulls_first),
                                     cap))
-    outs = fn(*stacked, counts)
-    schema = batches[0].schema
-    results = []
-    for w in range(n):
-        arrays = [o[w] for o in outs[:-1]]
-        recv_n = int(outs[-1][w])
-        cols = _rebuild_columns(col_dtypes, arrays)
-        results.append(ColumnarBatch(schema, cols, recv_n))
-    return results
+    outs = _call_spmd("sort", fn, _place_shards(
+        mesh, _shard_arrays(batches, cap)) + [
+        _place_counts(mesh, [b.num_rows for b in batches])])
+    return _exchanged_batches(mesh, outs, batches[0].schema, col_dtypes)
 
 
 def distributed_groupby_round_fn(mesh: Mesh, key_dtypes, val_dtypes,
@@ -797,12 +865,14 @@ def run_distributed_groupby_streaming(mesh: Mesh,
         lambda: distributed_groupby_round_fn(
             mesh, key_dtypes, val_dtypes, agg_ops, w_cap, acc_cap))
 
-    # zeroed accumulator [n, acc_cap] per key/partial array + counts
+    # zeroed accumulator [n, acc_cap] per key/partial array + counts,
+    # born sharded: worker w's slots live on device w from round one
+    sharded = NamedSharding(mesh, P("workers"))
     acc: List[jnp.ndarray] = []
     for t in key_dtypes + partial_dtypes:
-        acc.append(jnp.zeros((n, acc_cap), t.numpy_dtype))
-        acc.append(jnp.zeros((n, acc_cap), jnp.bool_))
-    acc_n = jnp.zeros(n, jnp.int32)
+        acc.append(jnp.zeros((n, acc_cap), t.numpy_dtype, device=sharded))
+        acc.append(jnp.zeros((n, acc_cap), jnp.bool_, device=sharded))
+    acc_n = _place_counts(mesh, [0] * n)
 
     for r in range(rounds):
         lo = r * window_rows
@@ -816,10 +886,8 @@ def run_distributed_groupby_streaming(mesh: Mesh,
                 arrs.extend(c.arrays())
             win_arrays.append(arrs)
             counts.append(take)
-        stacked = [jnp.stack([wa[i] for wa in win_arrays])
-                   for i in range(len(win_arrays[0]))]
-        outs = fn(*stacked, jnp.asarray(counts, jnp.int32),
-                  *acc, acc_n)
+        outs = _call_spmd("groupby-round", fn, _place_shards(
+            mesh, win_arrays) + [_place_counts(mesh, counts), *acc, acc_n])
         acc = list(outs[:-1])
         acc_n_dev = outs[-1]
         overflow = np.asarray(acc_n_dev)
@@ -837,19 +905,10 @@ def run_distributed_groupby_streaming(mesh: Mesh,
                                      acc_cap))
     outs = ffn(*acc, acc_n)
     agg_out_dtypes = output_dtypes(agg_ops, val_dtypes)
-    nk_arrays = len(key_dtypes) * 2
-    results = []
-    acc_n_host = np.asarray(acc_n)
-    for w in range(n):
-        arrays = [o[w] for o in outs]
-        keys = _rebuild_columns(key_dtypes, arrays[:nk_arrays])
-        aggs = _rebuild_columns(agg_out_dtypes, arrays[nk_arrays:])
-        fields = [dt.Field(f"k{i}", t) for i, t in enumerate(key_dtypes)]
-        fields += [dt.Field(f"a{i}", t)
-                   for i, t in enumerate(agg_out_dtypes)]
-        results.append(ColumnarBatch(dt.Schema(fields), keys + aggs,
-                                     int(acc_n_host[w])))
-    return results
+    fields = [dt.Field(f"k{i}", t) for i, t in enumerate(key_dtypes)]
+    fields += [dt.Field(f"a{i}", t) for i, t in enumerate(agg_out_dtypes)]
+    return _exchanged_batches(mesh, list(outs) + [acc_n], dt.Schema(fields),
+                              list(key_dtypes) + agg_out_dtypes)
 
 
 def _string_key_words(col: Column, w8: int) -> List[Column]:
@@ -969,41 +1028,16 @@ def run_distributed_groupby(mesh: Mesh, batches: List[ColumnarBatch],
     key_dtypes = [batches[0].columns[i].dtype for i in key_idx]
     val_dtypes = [batches[0].columns[i].dtype for i in val_idx]
 
-    # stack shards on a leading workers axis
-    def stack(get_arrays):
-        per_worker = [get_arrays(b) for b in batches]
-        return [jnp.stack([pw[i] for pw in per_worker])
-                for i in range(len(per_worker[0]))]
-
-    def arrays_of(b: ColumnarBatch):
-        out = []
-        for i in key_idx + val_idx:
-            c = b.columns[i]
-            if c.capacity < cap:
-                c = K.rebucket_column(c, b.num_rows, cap)
-            out.extend(c.arrays())
-        return out
-
-    stacked = stack(arrays_of)
-    counts = jnp.asarray([b.num_rows for b in batches], dtype=jnp.int32)
-
     fn = _cached_fn(
         ("groupby", _mesh_key(mesh), tuple(key_dtypes), tuple(val_dtypes),
          tuple(agg_ops), cap),
         lambda: distributed_groupby_fn(mesh, key_dtypes, val_dtypes,
                                        agg_ops, cap))
-    outs = fn(*stacked, counts)
-
-    # unpack per-worker results
+    outs = _call_spmd("groupby", fn, _place_shards(
+        mesh, _shard_arrays(batches, cap, key_idx + val_idx)) + [
+        _place_counts(mesh, [b.num_rows for b in batches])])
     agg_out_dtypes = output_dtypes(agg_ops, val_dtypes)
-    results = []
-    nk_arrays = sum(3 if t.var_width else 2 for t in key_dtypes)
-    for w in range(n):
-        arrays = [o[w] for o in outs[:-1]]
-        n_groups = int(outs[-1][w])
-        keys = _rebuild_columns(key_dtypes, arrays[:nk_arrays])
-        aggs = _rebuild_columns(agg_out_dtypes, arrays[nk_arrays:])
-        fields = [dt.Field(f"k{i}", t) for i, t in enumerate(key_dtypes)]
-        fields += [dt.Field(f"a{i}", t) for i, t in enumerate(agg_out_dtypes)]
-        results.append(ColumnarBatch(dt.Schema(fields), keys + aggs, n_groups))
-    return results
+    fields = [dt.Field(f"k{i}", t) for i, t in enumerate(key_dtypes)]
+    fields += [dt.Field(f"a{i}", t) for i, t in enumerate(agg_out_dtypes)]
+    return _exchanged_batches(mesh, outs, dt.Schema(fields),
+                              list(key_dtypes) + agg_out_dtypes)

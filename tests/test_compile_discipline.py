@@ -67,6 +67,7 @@ def _run_child(cache_dir):
     env = {**os.environ, "JAX_PLATFORMS": "cpu"}
     env.pop("SPARK_RAPIDS_TPU_CONF__SPARK__RAPIDS__TPU__SQL"
             "__ANALYSIS__LOCKDEP", None)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)   # the test places its own
     proc = subprocess.run(
         [sys.executable, "-c", _CHILD, cache_dir],
         capture_output=True, text=True, timeout=600, cwd=ROOT, env=env)
@@ -110,6 +111,65 @@ def test_unwritable_cache_dir_warns_never_fails(caplog,
     assert compile_cache.active_dir() is None
     rows = session.createDataFrame({"a": [1, 2, 3]}).collect()
     assert [r[0] for r in rows] == [1, 2, 3]
+
+
+# ---------------------------------------------------------------------------
+# Where the cache lives: one helper, placeable from outside
+# ---------------------------------------------------------------------------
+
+_CACHE_DIR_CHILD = r"""
+import json, sys
+import jax
+import spark_rapids_tpu
+seen = [jax.config.jax_compilation_cache_dir]          # after import
+from spark_rapids_tpu.api.session import TpuSession
+for conf_dir in ("", sys.argv[1]):
+    TpuSession.builder.config({
+        "spark.rapids.tpu.sql.explain": "NONE",
+        "spark.rapids.tpu.sql.compile.cacheDir": conf_dir}).getOrCreate()
+    seen.append(jax.config.jax_compilation_cache_dir)  # after bootstrap
+from spark_rapids_tpu.exec import compile_cache
+print(json.dumps({"seen": seen, "managed": compile_cache.active_dir(),
+                  "minS": jax.config.jax_persistent_cache_min_compile_time_secs}))
+"""
+
+
+def _cache_dir_child(conf_dir, env_dir=None):
+    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if env_dir is not None:
+        env["JAX_COMPILATION_CACHE_DIR"] = env_dir
+    proc = subprocess.run(
+        [sys.executable, "-c", _CACHE_DIR_CHILD, conf_dir],
+        capture_output=True, text=True, timeout=300, cwd=ROOT, env=env)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    return json.loads(proc.stdout.strip().splitlines()[-1])
+
+
+def test_env_cache_dir_is_the_only_directory_the_program_uses(tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set, importing the package and
+    bootstrapping a session — without and with compile.cacheDir — leaves
+    jax's cache directory equal to it; the managed layer (signature
+    index, prewarm corpus) sits in that same directory."""
+    env_dir = str(tmp_path / "from_env")
+    out = _cache_dir_child(str(tmp_path / "from_conf"), env_dir=env_dir)
+    assert out["seen"] == [env_dir] * 3
+    assert out["managed"] == env_dir
+    assert not os.path.exists(str(tmp_path / "from_conf"))
+    assert out["minS"] == 0.0            # every program is kept
+
+
+def test_default_cache_dir_is_one_fixed_path_in_the_checkout(tmp_path):
+    """Unset, the directory is a fixed path inside the checkout —
+    identical across two fresh processes (never home-, pid-, time- or
+    mkdtemp-derived: XLA keys its cache by path) — and a session's
+    compile.cacheDir still places it."""
+    conf_dir = str(tmp_path / "from_conf")
+    first = _cache_dir_child(conf_dir)
+    second = _cache_dir_child(conf_dir)
+    fixed = os.path.join(ROOT, ".jax_cache")
+    assert first["seen"] == second["seen"] == [fixed, fixed, conf_dir]
+    assert first["managed"] == conf_dir
 
 
 # ---------------------------------------------------------------------------

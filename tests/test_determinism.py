@@ -16,6 +16,8 @@ import threading
 import pandas as pd
 import pytest
 
+from procs import readline_bounded
+
 from spark_rapids_tpu.analysis import divergence
 from spark_rapids_tpu.analysis.divergence import DesyncError
 
@@ -185,7 +187,7 @@ _WORKER = """
 import sys, json, threading
 sys.path.insert(0, {repo!r})
 import os
-os.environ.setdefault("SPARK_RAPIDS_TPU_COMPILE_CACHE", "off")
+os.environ.setdefault("JAX_ENABLE_COMPILATION_CACHE", "false")
 from spark_rapids_tpu.shuffle.manager import init_worker
 
 wid = int(sys.argv[1]); n = int(sys.argv[2])
@@ -269,7 +271,7 @@ def _run_concurrent_cluster(fault="none", n_workers=2):
     try:
         ports = {}
         for wid, p in enumerate(procs):
-            line = p.stdout.readline()
+            line = readline_bounded(p)
             assert line, p.stderr.read()
             ports[wid] = ("127.0.0.1", json.loads(line)["port"])
         peers = json.dumps({str(w): list(a) for w, a in ports.items()})

@@ -82,6 +82,33 @@ def test_sort_multi_key_stability():
     assert o2 == ["a", "a", "b", "x", "y"]
 
 
+def test_lexsort_passes_equal_one_variadic_lexsort():
+    """The LSD-pass formulation (one two-operand sort in a loop — what
+    keeps the TPU compile in seconds) is the SAME stable permutation as
+    one variadic jnp.lexsort, over every lane kind sort_indices emits:
+    packed uint32 runs, a raw uint64 (split hi/lo), a float value lane."""
+    import jax.numpy as jnp
+    rng = np.random.default_rng(11)
+    n = 4096
+    lanes = [
+        jnp.asarray(rng.integers(0, 3, n).astype(np.uint32)),
+        jnp.asarray(rng.integers(0, 2 ** 63, n).astype(np.uint64) *
+                    np.uint64(2) + rng.integers(0, 2, n).astype(np.uint64)),
+        jnp.asarray(rng.integers(0, 4, n).astype(np.uint32)),
+        jnp.asarray(np.round(rng.normal(0, 2, n), 1)),       # ties
+        jnp.asarray(rng.integers(0, 5, n).astype(np.uint32)),
+        jnp.asarray(rng.integers(0, 5, n).astype(np.uint32)),
+    ]
+    got = np.asarray(K._lexsort_passes(lanes))
+    want = np.asarray(jnp.lexsort(tuple(reversed(lanes))))
+    assert got.dtype == np.int32
+    np.testing.assert_array_equal(got, want)
+    # a single lane is one pass
+    np.testing.assert_array_equal(
+        np.asarray(K._lexsort_passes(lanes[:1])),
+        np.asarray(jnp.argsort(lanes[0], stable=True)))
+
+
 def test_compact_columns():
     col = _col([10, 20, 30, 40, 50], dt.INT64)
     keep = np.zeros(col.capacity, dtype=bool)

@@ -19,13 +19,15 @@ import json
 import pandas as pd
 import pytest
 
+from procs import readline_bounded
+
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 _WORKER = """
 import sys, json, socket, time
 sys.path.insert(0, {repo!r})
 import os
-os.environ.setdefault("SPARK_RAPIDS_TPU_COMPILE_CACHE", "off")
+os.environ.setdefault("JAX_ENABLE_COMPILATION_CACHE", "false")
 from spark_rapids_tpu.shuffle.manager import init_worker
 
 wid = int(sys.argv[1]); n = int(sys.argv[2]); query = sys.argv[3]
@@ -93,7 +95,7 @@ def _run_cluster(query: str, n_workers: int = 2):
     try:
         ports = {}
         for wid, p in enumerate(procs):
-            line = p.stdout.readline()
+            line = readline_bounded(p)
             assert line, p.stderr.read()
             ports[wid] = ("127.0.0.1", json.loads(line)["port"])
         peers = json.dumps({str(w): list(a) for w, a in ports.items()})
@@ -197,7 +199,8 @@ def test_fetch_when_complete_waits_for_late_map():
         t.start()
         client = ShuffleClient.for_address("127.0.0.1", srv.port)
         got = client.fetch_when_complete(7, [0], timeout_s=10)
-        t.join()
+        t.join(timeout=30)
+        assert not t.is_alive()
         assert len(got) == 1 and sorted(got[0].rows()) == [(1,), (2,), (3,)]
     finally:
         srv.stop()

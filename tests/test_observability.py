@@ -319,25 +319,16 @@ def test_sync_counter_stack_survives_concurrent_enter_exit():
 
 
 # ---------------------------------------------------------------------------
-# Bench preflight (the un-darkened bench)
+# Bench preflight: no chip, no measurement
 # ---------------------------------------------------------------------------
 
-def test_preflight_timeout_degrades_to_labeled_cpu():
-    from benchmarks.preflight import probe_devices
-    probe = probe_devices(timeout_s=0.01)   # nothing spawns in 10ms
-    assert probe["ok"] is False
-    assert "timed out" in probe["error"]
-    assert probe["latencyS"] >= 0.0
-    # the preflight labeling contract: a failed probe means an explicit
-    # cpu-degraded backend, never a zeroed value
-    backend = probe["platform"] if probe["ok"] else "cpu-degraded"
-    assert backend == "cpu-degraded"
-
-
-@pytest.mark.slow
-def test_preflight_probe_succeeds_on_cpu():
-    from benchmarks.preflight import preflight
-    pf = preflight(timeout_s=60)
-    assert pf["deviceProbe"]["ok"] is True
-    assert pf["backend"] == "cpu"
-    assert pf["deviceProbe"]["latencyS"] > 0
+def test_preflight_without_a_chip_fails_with_the_probe_error():
+    """A measurement entry point never falls back to the CPU: on this
+    CPU-only backend the probe raises, naming the platform it found."""
+    from benchmarks import preflight
+    with pytest.raises(RuntimeError, match="'cpu'.*not a TPU"):
+        preflight.require_chip()
+    assert not hasattr(preflight, "force_cpu_backend")
+    with pytest.raises(RuntimeError, match="not a TPU"):
+        from benchmarks.runner import run_benchmark
+        run_benchmark(sf=0.0005, query_names=["q6"], iterations=1)

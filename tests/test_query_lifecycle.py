@@ -21,6 +21,8 @@ import numpy as np
 import pandas as pd
 import pytest
 
+from procs import readline_bounded
+
 from spark_rapids_tpu.api.session import TpuSession
 from spark_rapids_tpu.shuffle.exchange import (collect_stage_stats,
                                                compute_stage_stats)
@@ -439,7 +441,7 @@ def test_durable_gc_budget_evicts_oldest_completed(tmp_path):
 _WORKER = """
 import sys, json, os
 sys.path.insert(0, {repo!r})
-os.environ.setdefault("SPARK_RAPIDS_TPU_COMPILE_CACHE", "off")
+os.environ.setdefault("JAX_ENABLE_COMPILATION_CACHE", "false")
 from spark_rapids_tpu.shuffle.manager import init_worker
 
 wid = int(sys.argv[1]); n = int(sys.argv[2]); log_dir = sys.argv[3]
@@ -510,7 +512,7 @@ def test_two_process_merged_timeline_and_query_log(tmp_path):
     try:
         ports = {}
         for wid, p in enumerate(procs):
-            line = p.stdout.readline()
+            line = readline_bounded(p)
             assert line, p.stderr.read()
             ports[wid] = ("127.0.0.1", json.loads(line)["port"])
         peers = json.dumps({str(w): list(a) for w, a in ports.items()})
@@ -800,7 +802,7 @@ def test_wfq_weighted_share_and_no_starvation():
 _CANCEL_WORKER = """
 import sys, json, os
 sys.path.insert(0, {repo!r})
-os.environ.setdefault("SPARK_RAPIDS_TPU_COMPILE_CACHE", "off")
+os.environ.setdefault("JAX_ENABLE_COMPILATION_CACHE", "false")
 from spark_rapids_tpu.shuffle.manager import init_worker
 
 wid = int(sys.argv[1]); n = int(sys.argv[2])
@@ -863,7 +865,7 @@ def test_two_process_cancel_propagates_over_meta(tmp_path):
     try:
         ports = {}
         for wid, p in enumerate(procs):
-            line = p.stdout.readline()
+            line = readline_bounded(p)
             assert line, p.stderr.read()
             ports[wid] = ("127.0.0.1", json.loads(line)["port"])
         peers = json.dumps({str(w): list(a) for w, a in ports.items()})
@@ -871,7 +873,7 @@ def test_two_process_cancel_propagates_over_meta(tmp_path):
             p.stdin.write(peers + "\n")
             p.stdin.flush()
         for wid, p in enumerate(procs):
-            line = p.stdout.readline()
+            line = readline_bounded(p)
             assert line, p.stderr.read()
             results[wid] = json.loads(line)
         for p in procs:            # release the stay-alive gate

@@ -15,6 +15,8 @@ import numpy as np
 import pandas as pd
 import pytest
 
+from procs import readline_bounded
+
 from spark_rapids_tpu.analysis import faults
 from spark_rapids_tpu.analysis.faults import FaultSpecError
 from spark_rapids_tpu.api.session import RuntimeConf, TpuSession
@@ -537,7 +539,7 @@ _CHAOS_WORKER = """
 import sys, json, threading
 sys.path.insert(0, {repo!r})
 import os
-os.environ.setdefault("SPARK_RAPIDS_TPU_COMPILE_CACHE", "off")
+os.environ.setdefault("JAX_ENABLE_COMPILATION_CACHE", "false")
 from spark_rapids_tpu.shuffle.manager import init_worker
 
 wid = int(sys.argv[1]); n = int(sys.argv[2]); durable_root = sys.argv[3]
@@ -663,7 +665,7 @@ def test_two_process_chaos_worker_death_and_conn_kill(tmp_path):
     try:
         ports = {}
         for wid, p in enumerate(procs):
-            line = p.stdout.readline()
+            line = readline_bounded(p)
             assert line, p.stderr.read()
             ports[wid] = ("127.0.0.1", json.loads(line)["port"])
         peers = json.dumps({str(w): list(a) for w, a in ports.items()})

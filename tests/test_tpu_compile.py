@@ -1,0 +1,320 @@
+"""The chip's compiler, asked before the chip is: the main path's programs
+compiled for a DESCRIBED TPU v5e (no device attached, nothing runs).
+
+The suite runs on the CPU backend, where the accelerator-only branches —
+MXU matmul segment reductions, HBM-sized batch capacities, 64-bit
+emulation — never build. What the TPU compiler refuses (a 64-bit float
+bitcast, a program that does not fit HBM, a collective it cannot
+partition) shows here at no chip time. A compile that passes is not a
+chip run: ``chip_smoke.py`` is.
+
+Programs WITHOUT a large sort compile at the capacities the chip run
+uses (the batch autotuner's pick for a 16 GB chip). Each large sort costs
+the TPU compiler 30-100 s whatever else the program holds, so programs
+built around one compile here at a capacity under its threshold: a
+refusal is a matter of ops and dtypes, not of rows.
+
+The topology is described inside a module-scoped fixture — never at
+import, never in a child process: one process at a time may load the
+TPU's library, and every xdist worker imports this file.
+"""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+from spark_rapids_tpu.columnar import dtypes as dt
+from spark_rapids_tpu.columnar.column import Column
+
+HBM_BYTES = 16 << 30            # one v5e chip
+SMALL_SORT = 1 << 12            # under the TPU compiler's big-sort path
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    try:
+        t = topologies.get_topology_desc(platform="tpu",
+                                         topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a described-topology executable can be written to the persistent
+    # cache but not read back without a chip: keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield t
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def four_chips(topo):
+    mesh = Mesh(np.array(topo.devices[:4]), ("workers",))
+    return mesh, NamedSharding(mesh, P("workers"))
+
+
+def _column_structs(dtype: dt.DType, cap: int, sharding, lead=()):
+    """ShapeDtypeStructs of one Column's flat arrays (data, validity,
+    + lengths for strings) at ``cap`` rows."""
+    def s(shape, npdt):
+        return jax.ShapeDtypeStruct(tuple(lead) + shape, npdt,
+                                    sharding=sharding)
+    if dtype == dt.STRING:
+        return [s((cap, 8), jnp.uint8), s((cap,), jnp.bool_),
+                s((cap,), jnp.int32)]
+    return [s((cap,), dtype.numpy_dtype), s((cap,), jnp.bool_)]
+
+
+def _compile(fn, *structs, static_argnums=()):
+    return jax.jit(fn, static_argnums=static_argnums).lower(
+        *structs).compile()
+
+
+def _fits_hbm(compiled) -> int:
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes +
+             m.temp_size_in_bytes)
+    assert total < HBM_BYTES, f"{total} bytes do not fit one chip"
+    return total
+
+
+# ---------------------------------------------------------------------------
+# The scan: every query starts by carving columns out of one staging buffer
+# ---------------------------------------------------------------------------
+
+# lineitem as benchmarks/datagen makes it: 4 int64, 3 float64, 3 dates,
+# 3 short strings (data uint8[cap, W] + validity + int32 lengths)
+_LINEITEM = ([dt.INT64] * 4 + [dt.FLOAT64] * 3 + [dt.STRING] * 2 +
+             [dt.DATE] * 3 + [dt.STRING])
+
+
+def _autotuned_rows(schema: dt.Schema, monkeypatch) -> int:
+    """tuned_batch_rows as the chip run sees it: a 16 GB device."""
+    from spark_rapids_tpu import config as cfg
+    from spark_rapids_tpu.plan import stage_compiler as sc
+    conf = cfg.TpuConf()
+    monkeypatch.setattr(
+        sc, "_device_budget_bytes",
+        lambda: int(HBM_BYTES * float(conf.get(cfg.ALLOC_FRACTION))))
+    sc.reset_tuning_cache()
+    try:
+        return sc.tuned_batch_rows(conf, schema)
+    finally:
+        sc.reset_tuning_cache()
+
+
+@pytest.mark.parametrize("rows", [1 << 20, "autotuned"])
+def test_scan_unpack_of_lineitem_compiles(one_chip, monkeypatch, rows):
+    """bytes -> int64 / float64 / date / string columns: the one
+    direction of 64-bit bitcast the TPU's x64 rewrite implements."""
+    from spark_rapids_tpu.columnar.batch import (_staging_spec,
+                                                 _unpack_program)
+    schema = dt.Schema([dt.Field(f"c{i}", t)
+                        for i, t in enumerate(_LINEITEM)])
+    if rows == "autotuned":
+        rows = _autotuned_rows(schema, monkeypatch)
+        assert rows >= 1 << 21           # the chip-sized branch, not CPU's
+    metas = [(a.dtype, a.shape) for t in _LINEITEM
+             for a in _column_structs(t, rows, None)]
+    spec, total = _staging_spec(metas)
+    program = _unpack_program(spec, total)._fn       # the jitted unpack
+    buf = jax.ShapeDtypeStruct((total,), jnp.uint8, sharding=one_chip)
+    compiled = program.lower(buf).compile()
+    assert _fits_hbm(compiled) >= total
+
+
+# ---------------------------------------------------------------------------
+# Aggregation: the two MXU paths that are off on the CPU backend
+# ---------------------------------------------------------------------------
+
+def test_dense_mxu_groupby_stage_compiles(one_chip):
+    """filter -> project -> dense-range one-hot-matmul group-by, the
+    stage bench.py and __graft_entry__ build, at the autotuner's ceiling
+    (1 << 23 rows; int64 keys, float64 values)."""
+    from spark_rapids_tpu.ops import aggregates as agg_k
+    cap, k_slots = 1 << 23, 1152
+
+    def fused(keys, key_valid, vals, val_valid, flags, num_rows):
+        live = jnp.arange(cap) < num_rows
+        keep = live & flags & val_valid & (vals > 0)
+        proj = Column(dt.FLOAT64, vals * 2.0 + 1.0, val_valid)
+        rmin = jnp.min(jnp.where(keep & key_valid, keys,
+                                 jnp.iinfo(jnp.int64).max))
+        out_keys, out_aggs, n_groups = agg_k.groupby_dense(
+            Column(dt.INT64, keys, key_valid),
+            [agg_k.AggSpec("sum", proj), agg_k.AggSpec("count", proj),
+             agg_k.AggSpec("avg", proj)],
+            num_rows, k_slots, rmin, extra_mask=keep)
+        return (out_keys[0].data, out_aggs[0].data, out_aggs[1].data,
+                out_aggs[2].data, n_groups)
+
+    def s(npdt, shape=(cap,)):
+        return jax.ShapeDtypeStruct(shape, npdt, sharding=one_chip)
+    compiled = _compile(fused, s(jnp.int64), s(jnp.bool_), s(jnp.float64),
+                        s(jnp.bool_), s(jnp.bool_), s(jnp.int32, ()))
+    _fits_hbm(compiled)
+
+
+def test_sort_groupby_with_matmul_reductions_compiles(one_chip):
+    """q1's shape: two string keys -> lexsort -> segment ids -> every
+    aggregate through the matmul segment reductions that
+    ``agg.matmul.enabled=auto`` turns on only off the CPU."""
+    from spark_rapids_tpu.ops import aggregates as agg_k
+    from spark_rapids_tpu.ops import kernels as K
+    cap, kb = SMALL_SORT, 128
+
+    def q1_kernel(num_rows, *arrays):
+        flag = Column(dt.STRING, *arrays[0:3])
+        status = Column(dt.STRING, *arrays[3:6])
+        qty = Column(dt.INT64, *arrays[6:8])
+        price = Column(dt.FLOAT64, *arrays[8:10])
+        keys = [flag, status]
+        order = K.sort_indices([K.SortKey(c) for c in keys], num_rows, cap)
+        skeys = [K.gather_column(c, order) for c in keys]
+        starts = K.segment_starts_from_sorted_keys(skeys, num_rows, cap)
+        seg_ids = K.segment_ids(starts)
+        live = jnp.arange(cap) < num_rows
+        outs, on_mxu = [], 0
+        for op, col in (("sum", qty), ("sum", price), ("avg", price),
+                        ("count", qty), ("count_star", None)):
+            spec = agg_k.AggSpec(
+                op, None if col is None else K.gather_column(col, order))
+            # per-spec mixing, as TpuHashAggregateExec._finish_sortmm does
+            if agg_k._matmul_supported(spec):
+                on_mxu += 1
+                agg = agg_k.segment_aggregate_matmul(spec, seg_ids, live, kb)
+            else:
+                agg = agg_k.segment_aggregate(spec, seg_ids, live, cap,
+                                              num_segments=kb)
+            outs.append(agg.data)
+        assert on_mxu >= 4               # only the bigint sum scatters
+        return tuple(outs) + (jnp.sum(starts),)
+
+    structs = [jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)]
+    for t in (dt.STRING, dt.STRING, dt.INT64, dt.FLOAT64):
+        structs += _column_structs(t, cap, one_chip)
+    _compile(q1_kernel, *structs)
+
+
+# ---------------------------------------------------------------------------
+# Join, sort, hash partitioning
+# ---------------------------------------------------------------------------
+
+def test_sort_merge_join_kernels_compile(one_chip):
+    """join_match (sort the build side, binary-search the stream side)
+    and join_gather (expand the matches) on int64 keys."""
+    from spark_rapids_tpu.ops import joins as J
+    cap = SMALL_SORT
+
+    def join(n_build, n_stream, bk, bv, sk, sv, pay, payv):
+        build, stream = Column(dt.INT64, bk, bv), Column(dt.INT64, sk, sv)
+        m = J.join_match([build], n_build, [stream], n_stream, cap)
+        s_out, b_out, total = J.join_gather(
+            m, [stream, Column(dt.FLOAT64, pay, payv)], [build], 2 * cap)
+        return (s_out[0].data, s_out[1].data, b_out[0].data, total)
+
+    def s(npdt, shape=(cap,)):
+        return jax.ShapeDtypeStruct(shape, npdt, sharding=one_chip)
+    _compile(join, s(jnp.int32, ()), s(jnp.int32, ()),
+             s(jnp.int64), s(jnp.bool_), s(jnp.int64), s(jnp.bool_),
+             s(jnp.float64), s(jnp.bool_))
+
+
+def test_lexsort_over_int64_and_float64_keys_compiles(one_chip):
+    """ORDER BY revenue DESC, orderdate: the orderable-word encoding
+    keeps floats AS floats (no f64 bitcast) and sign-flips ints."""
+    from spark_rapids_tpu.ops import kernels as K
+    cap = SMALL_SORT
+
+    def order_by(num_rows, f, fv, i, iv):
+        keys = [K.SortKey(Column(dt.FLOAT64, f, fv), False, False),
+                K.SortKey(Column(dt.INT64, i, iv), True, True)]
+        return K.sort_indices(keys, num_rows, cap)
+
+    def s(npdt, shape=(cap,)):
+        return jax.ShapeDtypeStruct(shape, npdt, sharding=one_chip)
+    _compile(order_by, s(jnp.int32, ()), s(jnp.float64), s(jnp.bool_),
+             s(jnp.int64), s(jnp.bool_))
+
+
+def test_f64_to_int_bitcast_is_what_the_chip_refuses(one_chip):
+    """The hazard itself, pinned: should a later compiler accept it, this
+    fails and the arithmetic ``_float64_bits`` can go."""
+    x = jax.ShapeDtypeStruct((1024,), jnp.float64, sharding=one_chip)
+    with pytest.raises(Exception, match="X64"):
+        _compile(lambda v: jax.lax.bitcast_convert_type(v, jnp.int64), x)
+
+
+def test_murmur3_partitioning_of_int64_and_float64_keys_compiles(one_chip):
+    """Hash partitioning of a (bigint, double, string) key at a chip-sized
+    batch: the double hashes its IEEE bits, taken by arithmetic."""
+    from spark_rapids_tpu.ops.hashing import murmur3_batch
+    cap = 1 << 20
+
+    def pids(*arrays):
+        cols = [Column(dt.INT64, *arrays[0:2]),
+                Column(dt.FLOAT64, *arrays[2:4]),
+                Column(dt.STRING, *arrays[4:7])]
+        return jnp.mod(murmur3_batch(cols, cap), 8)
+
+    structs = []
+    for t in (dt.INT64, dt.FLOAT64, dt.STRING):
+        structs += _column_structs(t, cap, one_chip)
+    _fits_hbm(_compile(pids, *structs))
+
+
+def test_float64_bits_match_numpy_on_the_cpu():
+    """The arithmetic the chip compiles is bit-exact where f64 is IEEE."""
+    from spark_rapids_tpu.ops.hashing import _float64_bits
+    rng = np.random.default_rng(0)
+    x = np.concatenate([
+        rng.integers(-2 ** 63, 2 ** 63 - 1, 20000).view(np.float64),
+        rng.normal(0, 1e6, 2000),
+        [0.0, 1.0, -1.0, 0.5, 0.07, 123456.78, np.inf, -np.inf, np.nan,
+         2.2250738585072014e-308, 1.7976931348623157e308]])
+    x = x[~((np.abs(x) < 2.0 ** -1022) & (x != 0))]     # XLA flushes these
+    want = x.view(np.int64).copy()
+    want[np.isnan(x)] = 0x7FF8_0000_0000_0000           # canonical NaN
+    got = np.asarray(jax.jit(_float64_bits)(jnp.asarray(x)))
+    np.testing.assert_array_equal(got, want)
+
+
+# ---------------------------------------------------------------------------
+# Four chips: the SPMD stages, one program across the 2x2 mesh
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("stage", ["groupby", "copartition", "sort"])
+def test_mesh_stage_compiles_with_an_all_to_all(four_chips, stage):
+    """Each mesh pipeline is ONE program whose exchange is an all-to-all
+    over ICI, sharded over the four chips, within each chip's HBM."""
+    from spark_rapids_tpu.parallel import mesh as M
+    mesh, sharded = four_chips
+    cap = SMALL_SORT // 4                # receive windows are 4 x cap
+    dtypes = [dt.INT64, dt.FLOAT64, dt.STRING]
+    if stage == "groupby":
+        fn = M.distributed_groupby_fn(mesh, [dt.INT64, dt.STRING],
+                                      [dt.FLOAT64, dt.INT64],
+                                      ["sum", "avg"], cap)
+        dtypes = [dt.INT64, dt.STRING, dt.FLOAT64, dt.INT64]
+    elif stage == "copartition":
+        fn = M.copartition_exchange_fn(mesh, dtypes, [0], cap)
+    else:
+        fn = M.distributed_sort_fn(mesh, dtypes, [1, 0], (False, True),
+                                   (False, True), cap)
+    structs = [a for t in dtypes
+               for a in _column_structs(t, cap, sharded, lead=(4,))]
+    structs.append(jax.ShapeDtypeStruct((4,), jnp.int32, sharding=sharded))
+    compiled = fn.lower(*structs).compile()
+    assert "all-to-all" in compiled.as_text()
+    _fits_hbm(compiled)                      # memory_analysis is per device

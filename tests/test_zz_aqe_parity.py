@@ -37,21 +37,32 @@ def _cells_equal(a, b) -> bool:
     return a == b
 
 
-@pytest.mark.parametrize("suite,qname", _CASES,
-                         ids=[f"{s}/{n}" for s, n in _CASES])
-def test_adaptive_on_off_parity(corpus, suite, qname):
-    session, tables = corpus
-    qfn = Q.QUERIES[qname] if suite == "tpch" else DS.TPCDS_QUERIES[qname]
-    on = qfn(tables[suite]).collect_batch().fetch_to_host().rows()
-    session.conf.set("spark.rapids.tpu.sql.adaptive.enabled", "false")
-    try:
-        off = qfn(tables[suite]).collect_batch().fetch_to_host().rows()
-    finally:
-        session.conf.set("spark.rapids.tpu.sql.adaptive.enabled", "true")
-    assert len(on) == len(off), (len(on), len(off))
-    # row order is part of parity for ordered queries; float cells compare
-    # to aggregation tolerance (a coalesced/split stage may legally change
-    # float reduction order at ~1e-7 rel)
-    for i, (ra, rb) in enumerate(zip(on, off)):
-        assert len(ra) == len(rb) and all(
-            _cells_equal(a, b) for a, b in zip(ra, rb)), (i, ra, rb)
+def corpus_test(cases):
+    """The parametrised corpus test over ``cases`` — a factory, so the
+    ``test_zz_aqe_parity_s1`` / ``_s2`` files can each run a third of
+    the corpus: ``--dist loadfile`` balances whole files, and 60 queries
+    in one file pinned a single worker for ten minutes at the run's tail."""
+    @pytest.mark.parametrize("suite,qname", cases,
+                             ids=[f"{s}/{n}" for s, n in cases])
+    def test_adaptive_on_off_parity(corpus, suite, qname):
+        session, tables = corpus
+        qfn = Q.QUERIES[qname] if suite == "tpch" else DS.TPCDS_QUERIES[qname]
+        on = qfn(tables[suite]).collect_batch().fetch_to_host().rows()
+        session.conf.set("spark.rapids.tpu.sql.adaptive.enabled", "false")
+        try:
+            off = qfn(tables[suite]).collect_batch().fetch_to_host().rows()
+        finally:
+            session.conf.set("spark.rapids.tpu.sql.adaptive.enabled", "true")
+        assert len(on) == len(off), (len(on), len(off))
+        # row order is part of parity for ordered queries; float cells compare
+        # to aggregation tolerance (a coalesced/split stage may legally change
+        # float reduction order at ~1e-7 rel)
+        for i, (ra, rb) in enumerate(zip(on, off)):
+            assert len(ra) == len(rb) and all(
+                _cells_equal(a, b) for a, b in zip(ra, rb)), (i, ra, rb)
+    return test_adaptive_on_off_parity
+
+
+test_adaptive_on_off_parity = corpus_test(_CASES[0::3])
+
+

@@ -36,14 +36,25 @@ def corpus():
     ledger.install("record")
 
 
-@pytest.mark.parametrize("suite,qname", _CASES,
-                         ids=[f"{s}/{n}" for s, n in _CASES])
-def test_corpus_leak_free_under_enforce(corpus, suite, qname):
-    session, tables = corpus
-    qfn = Q.QUERIES[qname] if suite == "tpch" else DS.TPCDS_QUERIES[qname]
-    # enforce mode: a leak raises BufferLeakError from inside collect
-    rows = qfn(tables[suite]).collect_batch().fetch_to_host().rows()
-    assert rows is not None
-    led = session._last_ledger
-    assert led is not None, "end-of-query audit must run under enforce"
-    assert led["leakedBuffers"] == 0, led
+def corpus_test(cases):
+    """The parametrised corpus test over ``cases`` — a factory, so the
+    ``test_zz_ledger_corpus_s1`` / ``_s2`` files can each run a third of
+    the corpus: ``--dist loadfile`` balances whole files, and 60 queries
+    in one file pinned a single worker for ten minutes at the run's tail."""
+    @pytest.mark.parametrize("suite,qname", cases,
+                             ids=[f"{s}/{n}" for s, n in cases])
+    def test_corpus_leak_free_under_enforce(corpus, suite, qname):
+        session, tables = corpus
+        qfn = Q.QUERIES[qname] if suite == "tpch" else DS.TPCDS_QUERIES[qname]
+        # enforce mode: a leak raises BufferLeakError from inside collect
+        rows = qfn(tables[suite]).collect_batch().fetch_to_host().rows()
+        assert rows is not None
+        led = session._last_ledger
+        assert led is not None, "end-of-query audit must run under enforce"
+        assert led["leakedBuffers"] == 0, led
+    return test_corpus_leak_free_under_enforce
+
+
+test_corpus_leak_free_under_enforce = corpus_test(_CASES[0::3])
+
+
