@@ -1,0 +1,4 @@
+def read(ctx):
+    if not ctx["trace"]:
+        return None
+    return 100.0 * (1.0 - ctx["trace"]["busy_s"] / ctx["trace"]["window_s"])
