@@ -276,8 +276,9 @@ def run(sf: float, queries: Sequence[str], mesh: bool = False,
         from spark_rapids_tpu.exec import compile_cache
         from spark_rapids_tpu.exec.device import DeviceManager
         conf = {"spark.rapids.tpu.sql.explain": "NONE",
-                # the managed layer over the ONE xla cache directory: the
-                # signature index is what classifies a build cold vs disk
+                # the managed layer over the ONE xla cache directory (the
+                # signature index and prewarm corpus beside the cache);
+                # cold vs disk in the counts below is XLA's own report
                 "spark.rapids.tpu.sql.compile.cacheDir":
                     compile_cache.xla_cache_dir()}
         if mesh:

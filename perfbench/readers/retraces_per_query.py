@@ -1,0 +1,5 @@
+from .programs_per_query import mean_total
+
+
+def read(ctx):
+    return mean_total(ctx, ("traces",))

@@ -11,9 +11,11 @@ versus how many calls ran, and flags kernels whose compile count tracks
 their call count (the compiling-once-per-batch-shape smell).
 
 Wired into the one funnel every fused program goes through
-(``plan/physical._fused_fn`` and per-exec ``FusedStage`` jits); the bench
-runner reports per-query deltas (``report``/``snapshot``/``delta``) next
-to the sync and semaphore metrics. Gated by
+(``plan/physical._fused_fn`` and the program caches beside it, each
+handing out an ``exec/compile_cache.Program``); the bench runner reports
+per-query deltas (``report``/``snapshot``/``delta``) next to the sync and
+semaphore metrics, and every query carries its own ``programs`` map
+(``last_query_metrics()``). Gated by
 ``spark.rapids.tpu.sql.analysis.recompileAudit`` (default on — the cost
 is a dict increment per fused-program call).
 """
@@ -31,17 +33,25 @@ FLAG_MIN_COMPILES = 8
 
 _lock = named_lock("analysis.recompile._lock")
 # name -> {keys: set, compiles: int, calls: int, coldCompiles: int,
-# diskHits: int, compileS: float}. ``compiles`` counts EVERY cache-miss
-# build (a same-key recompile after the fused cache evicts is real churn
-# and must show), ``keys`` counts distinct shapes. ``coldCompiles`` vs
-# ``diskHits`` splits builds by the persistent-cache classification
-# (exec/compile_cache.classify): a disk hit loads the executable from
-# the on-disk XLA cache instead of recompiling, so a warm restart with
-# ``compile.cacheDir`` set should show coldCompiles == 0 for repeated
-# shapes. ``compileS`` accumulates first-call (compile-dominated) wall
-# seconds per family.
+# diskHits: int, compileS: float}. ``compiles`` counts EVERY program-cache
+# miss (a same-key rebuild after the fused cache evicts is real churn
+# and must show), ``keys`` counts distinct shapes, ``calls`` counts
+# dispatches (exec/compile_cache.Program). The rest is XLA's own report,
+# heard through ``jax.monitoring`` (exec/compile_cache._on_duration) and
+# charged to the family whose program was being called: ``coldCompiles``
+# backend compilations, ``diskHits`` loads from the persistent cache (a
+# warm restart with a cache dir should show coldCompiles == 0 for
+# repeated shapes), ``compileS`` every second paid to rebuild — trace,
+# lowering, backend compile and load. One jitted program re-traces per
+# argument shape, so cold + disk can exceed ``compiles``.
 _kernels: Dict[str, Dict[str, Any]] = {}
 _enabled_cache: Optional[bool] = None
+
+#: one family's entry in a query's ``programs`` map
+#: (``last_query_metrics()["programs"]``, docs/observability.md §9)
+_PROGRAM_ZERO = {"dispatches": 0, "traces": 0, "traceS": 0.0, "lowerS": 0.0,
+                 "compiles": 0, "compileS": 0.0, "cacheLoads": 0,
+                 "loadS": 0.0}
 
 
 def _enabled() -> bool:
@@ -84,19 +94,16 @@ def _ent(kernel: str) -> Dict[str, Any]:
                                 "compileS": 0.0})
 
 
-def note_compile(kernel: str, key: Any, kind: str = "cold") -> None:
-    """Record a cache miss: a program built (new shape OR a same-key
-    rebuild after eviction — both are paid compile time). ``kind`` is
-    the persistent-cache classification (``cold`` build vs ``disk``
-    hit, exec/compile_cache.classify)."""
+def note_compile(kernel: str, key: Any) -> None:
+    """Record a program-cache miss: a program built (new shape OR a
+    same-key rebuild after eviction). What the build costs arrives with
+    its first call, from XLA (:func:`note_rebuild`)."""
     if not _enabled():
         return
     with _lock:
         ent = _ent(kernel)
         ent["keys"].add(key)
         ent["compiles"] += 1
-        ent["calls"] += 1
-        ent["diskHits" if kind == "disk" else "coldCompiles"] += 1
     # charge the innermost open exec's metrics bag so EXPLAIN ANALYZE
     # shows which plan node paid the compile (exec/metrics attribution)
     from ..exec.metrics import attribute
@@ -107,21 +114,82 @@ def note_compile(kernel: str, key: Any, kind: str = "cold") -> None:
     flight_record("recompile", kernel)
 
 
-def note_call(kernel: str) -> None:
-    """Record a cache hit (a call that reused a compiled program)."""
-    if not _enabled():
+def note_call(kernel: str,
+              query_programs: Optional[Dict[str, Dict[str, Any]]] = None
+              ) -> None:
+    """Record one dispatch of a family's program: the audit's ``calls``
+    and, where a query is recording, its ``programs`` map — one lock for
+    both (exec/compile_cache.Program calls this per program call)."""
+    audit = _enabled()
+    if not audit and query_programs is None:
         return
     with _lock:
-        _ent(kernel)["calls"] += 1
+        if audit:
+            _ent(kernel)["calls"] += 1
+        if query_programs is not None:
+            _program_ent(query_programs, kernel)["dispatches"] += 1
 
 
-def note_compile_time(kernel: str, seconds: float) -> None:
-    """Accumulate one built program's first-call (compile-dominated)
-    wall seconds onto its family (exec/compile_cache.TimedFirstCall)."""
-    if not _enabled():
+def _program_ent(programs: Dict[str, Dict[str, Any]], family: str):
+    ent = programs.get(family)
+    if ent is None:
+        ent = programs[family] = dict(_PROGRAM_ZERO)
+    return ent
+
+
+def note_rebuild(family: str, fields, seconds: float, funnel: bool,
+                 query_programs: Optional[Dict[str, Dict[str, Any]]] = None
+                 ) -> None:
+    """One of XLA's compile events (exec/compile_cache._on_duration):
+    ``fields`` is the (count, seconds) pair of a ``programs`` entry it
+    feeds — (traces, traceS), (None, lowerS), (compiles, compileS) or
+    (cacheLoads, loadS). ``funnel`` families (a :class:`Program` was
+    open) also feed the process-wide audit; eager ops only the query."""
+    count_field, seconds_field = fields
+    audit = funnel and _enabled()
+    if not audit and query_programs is None:
         return
     with _lock:
-        _ent(kernel)["compileS"] += float(seconds)
+        if audit:
+            ent = _ent(family)
+            ent["compileS"] += float(seconds)
+            if count_field == "compiles":
+                ent["coldCompiles"] += 1
+            elif count_field == "cacheLoads":
+                ent["diskHits"] += 1
+        if query_programs is not None:
+            ent = _program_ent(query_programs, family)
+            ent[seconds_field] += float(seconds)
+            if count_field is not None:
+                ent[count_field] += 1
+
+
+def programs_report(programs: Dict[str, Dict[str, Any]]
+                    ) -> Dict[str, Dict[str, Any]]:
+    """A query's ``programs`` map as it is reported: a copy, seconds
+    rounded, families in name order."""
+    with _lock:
+        return {k: {f: (round(v, 6) if isinstance(v, float) else v)
+                    for f, v in ent.items()}
+                for k, ent in sorted(programs.items())}
+
+
+def recompiles_of(programs: Dict[str, Dict[str, Any]]
+                  ) -> Dict[str, Dict[str, Any]]:
+    """A query's ``programs`` map in the shape of :func:`delta` (what a
+    query listener's ``QueryExecution.recompiles`` holds): funnel
+    families only, those that built anything or were called."""
+    out: Dict[str, Dict[str, Any]] = {}
+    for k, p in programs_report(programs).items():
+        if k.startswith("<eager>:"):
+            continue
+        out[k] = {"compiles": p["compiles"] + p["cacheLoads"],
+                  "calls": p["dispatches"],
+                  "coldCompiles": p["compiles"],
+                  "diskHits": p["cacheLoads"],
+                  "compileS": round(p["traceS"] + p["lowerS"] +
+                                    p["compileS"] + p["loadS"], 4)}
+    return out
 
 
 def report() -> Dict[str, Dict[str, int]]:

@@ -274,11 +274,15 @@ class DataFrame:
         info — plan-cache hit/miss, result-cache key — on the session
         (plan/plan_cache.py, docs/plan_cache.md)."""
         import time
-        t0 = time.perf_counter()
-        plan = self._analyzed()
         from ..exec.spill import BufferCatalog
+        from ..exec.tracing import trace_span
         from ..plan import plan_cache as pc
-        exec_plan, serving = pc.plan_for(self.session, plan)
+        t0 = time.perf_counter()
+        with trace_span("plan"):
+            with trace_span("analyze"):
+                plan = self._analyzed()
+            with trace_span("plan_cache"):
+                exec_plan, serving = pc.plan_for(self.session, plan)
         self.session._last_plan_time_s = time.perf_counter() - t0
         self.session._last_exec_plan = exec_plan
         self.session._last_serving = serving
@@ -329,43 +333,57 @@ class DataFrame:
         return self
 
     def collect_batch(self):
+        from ..exec.tracing import QueryRecording
         from ..plan import plan_cache as pc
+        # the query's recorders open BEFORE planning: the root span
+        # ``query`` covers plan + execute (docs/observability.md §9)
+        recording = QueryRecording().open()
         try:
-            exec_plan = self._execute()
-        except BaseException:
-            # plan_for may have CLAIMED a cache entry before a later
-            # step of _execute raised (result-key snapshot, baseline):
-            # release it or the entry reads busy forever. A stale
-            # serving dict from a previous query is harmless — its
-            # planEntry was already popped by that query's release.
-            pc.release_plan_entry(pc.thread_serving())
-            raise
-        serving = pc.thread_serving() or {}
-        try:
-            hit = pc.serve_result_hit(self.session, serving)
-            if hit is not None:
-                # exact-repeat short circuit: no execution at all — the
-                # stored HOST batch serves (no spans/metrics/listeners
-                # for this collect; EXPLAIN ANALYZE marks the hit)
-                return hit
-            return self._collect_planned(exec_plan, serving)
+            try:
+                exec_plan = self._execute()
+            except BaseException:
+                # plan_for may have CLAIMED a cache entry before a later
+                # step of _execute raised (result-key snapshot,
+                # baseline): release it or the entry reads busy forever.
+                # A stale serving dict from a previous query is harmless
+                # — its planEntry was already popped by that query's
+                # release.
+                pc.release_plan_entry(pc.thread_serving())
+                raise
+            serving = pc.thread_serving() or {}
+            try:
+                hit = pc.serve_result_hit(self.session, serving)
+                if hit is not None:
+                    # exact-repeat short circuit: no execution at all —
+                    # the stored HOST batch serves (no spans/metrics/
+                    # listeners for this collect; EXPLAIN ANALYZE marks
+                    # the hit)
+                    return hit
+                return self._collect_planned(exec_plan, serving, recording)
+            finally:
+                # the exec tree claimed from the plan cache is free for
+                # the next execution (concurrent collects on a busy entry
+                # plan fresh trees, PlanEntry.try_begin_execution)
+                pc.release_plan_entry(serving)
         finally:
-            # the exec tree claimed from the plan cache is free for the
-            # next execution (concurrent collects on a busy entry plan
-            # fresh trees, plan_cache.PlanEntry.try_begin_execution)
-            pc.release_plan_entry(serving)
+            recording.close()
 
-    def _collect_planned(self, exec_plan, serving):
+    def _collect_planned(self, exec_plan, serving, recording=None):
+        """Execute a planned query. ``recording`` is the caller's open
+        :class:`QueryRecording` (``collect_batch`` opens it before
+        planning); the prepared-statement fast path has none and gets
+        one here."""
         import time
         from ..exec import query_context as qc
-        from ..exec.tracing import SpanRecorder, SyncCounter
+        from ..exec.tracing import QueryRecording
         from ..plan import plan_cache as pc
+        if recording is None:
+            recording = QueryRecording().open()
+        sc, spans = recording.sync, recording.spans
         listeners = bool(self.session._query_listeners)
         if listeners:
-            # snapshots only when someone is listening: the deltas cost a
-            # dict copy per query
-            from ..analysis import lockdep, recompile
-            rc0 = recompile.snapshot()
+            # snapshot only when someone is listening
+            from ..analysis import lockdep
             lk0 = lockdep.stats()
         # the query-lifecycle identity (docs/observability.md §8): ONE
         # query id minted at collect time, ambient for the execution so
@@ -412,9 +430,14 @@ class DataFrame:
         try:
             with qc.query_scope(ctx):
                 try:
-                    with SyncCounter() as sc, SpanRecorder() as spans:
+                    try:
                         spans.query_id = qid
                         out = exec_plan.execute_collect()
+                        # the result's fetch_to_host is the caller's to
+                        # run: its span joins this query's recorder
+                        out.recording = recording
+                    finally:
+                        recording.close()
                 except BaseException as e:
                     # post-mortem for failures OUTSIDE task bodies
                     # (planner-side execute, concat, exchange setup): dump
@@ -454,13 +477,15 @@ class DataFrame:
             # tests can export the Chrome-trace timeline of this query
             self.session._last_span_recorder = spans
             if listeners:
+                from ..analysis import recompile
                 from .session import QueryExecution
                 ov = self.session._last_overrides
                 self.session._notify_query_listeners(QueryExecution(
                     self.session, exec_plan,
                     self.session._last_sync_report,
                     self.session._last_span_report,
-                    recompile.delta(rc0), lockdep.stats_delta(lk0),
+                    recompile.recompiles_of(spans.programs),
+                    lockdep.stats_delta(lk0),
                     violations=getattr(ov, "last_violations", ()) if ov
                     else ()))
             rkey = serving.get("resultKey")
@@ -521,32 +546,36 @@ class DataFrame:
         program swaps in (docs/compile.md §5). Streaming results are
         never stored in the result cache (an exact-repeat hit is still
         SERVED, as a single batch)."""
+        from ..exec.tracing import QueryRecording
         from ..plan import plan_cache as pc
+        recording = QueryRecording().open()     # before planning
         try:
-            exec_plan = self._execute()
-        except BaseException:
-            pc.release_plan_entry(pc.thread_serving())
-            raise
-        serving = pc.thread_serving() or {}
-        try:
-            hit = pc.serve_result_hit(self.session, serving)
-            if hit is not None:
-                self.session._last_first_row_s = 0.0
-                yield hit
-                return
-            for batch in self._collect_iter_planned(exec_plan, serving):
-                yield batch
+            try:
+                exec_plan = self._execute()
+            except BaseException:
+                pc.release_plan_entry(pc.thread_serving())
+                raise
+            serving = pc.thread_serving() or {}
+            try:
+                hit = pc.serve_result_hit(self.session, serving)
+                if hit is not None:
+                    self.session._last_first_row_s = 0.0
+                    yield hit
+                    return
+                for batch in self._collect_iter_planned(exec_plan, serving,
+                                                        recording):
+                    yield batch
+            finally:
+                pc.release_plan_entry(serving)
         finally:
-            pc.release_plan_entry(serving)
+            recording.close()
 
-    def _collect_iter_planned(self, exec_plan, serving):
+    def _collect_iter_planned(self, exec_plan, serving, recording):
         import time
         from ..exec import query_context as qc
-        from ..exec.tracing import SpanRecorder, SyncCounter
         listeners = bool(self.session._query_listeners)
         if listeners:
-            from ..analysis import lockdep, recompile
-            rc0 = recompile.snapshot()
+            from ..analysis import lockdep
             lk0 = lockdep.stats()
         # reserved contexts win here too (the materializing collect's
         # adoption rule, above)
@@ -576,11 +605,11 @@ class DataFrame:
             pass
         self.session._last_first_row_s = None
         first_row_s = None
-        sc = spans = None
+        sc, spans = recording.sync, recording.spans
         t0 = time.perf_counter()
         try:
             with qc.query_scope(ctx):
-                with SyncCounter() as sc, SpanRecorder() as spans:
+                try:
                     spans.query_id = qid
                     try:
                         for batch in exec_plan.execute_collect_iter():  # lint: cancel-ok body polls check_cancel per delivered batch
@@ -597,6 +626,8 @@ class DataFrame:
                         from ..service.telemetry import dump_on_error
                         dump_on_error(e)
                         raise
+                finally:
+                    recording.close()
         finally:
             # runs on exhaustion, failure AND early close: the lifecycle
             # bookkeeping must not depend on the consumer finishing
@@ -620,19 +651,20 @@ class DataFrame:
                         "first yielded batch").observe(first_row_s)
             except Exception:
                 pass
-            if spans is not None:
-                self.session._last_sync_report = sc.report()
-                self.session._last_span_report = spans.report()
-                self.session._last_span_recorder = spans
+            self.session._last_sync_report = sc.report()
+            self.session._last_span_report = spans.report()
+            self.session._last_span_recorder = spans
             if listeners:
                 try:
+                    from ..analysis import recompile
                     from .session import QueryExecution
                     ov = self.session._last_overrides
                     self.session._notify_query_listeners(QueryExecution(
                         self.session, exec_plan,
                         self.session._last_sync_report,
                         self.session._last_span_report,
-                        recompile.delta(rc0), lockdep.stats_delta(lk0),
+                        recompile.recompiles_of(spans.programs),
+                        lockdep.stats_delta(lk0),
                         violations=getattr(ov, "last_violations", ())
                         if ov else ()))
                 except Exception:

@@ -148,7 +148,9 @@ class QueryExecution:
         self.plan = plan                   # executed TpuExec tree
         self.sync = sync                   # SyncCounter.report()
         self.spans = spans                 # SpanRecorder.report()
-        self.recompiles = recompiles       # recompile.delta over the query
+        # per family, in recompile.delta's shape, from this query's own
+        # ``programs`` map (analysis/recompile.recompiles_of)
+        self.recompiles = recompiles
         self.locks = locks                 # lockdep stats delta
         self.violations = list(violations)  # contract diags at capture
         self._metrics_tree = None
@@ -231,6 +233,9 @@ class TpuSession:
         # loads the fused-program signature index; degrades gracefully
         from ..exec import compile_cache
         compile_cache.configure(self.conf)
+        # XLA's own report of every trace, lowering, compile and cache
+        # load, charged to the program, span and operator that paid it
+        compile_cache.install_compile_listener()
         # recovery knobs + fault-injection plan prime EAGERLY (the
         # lockdep pattern: a lazy conf read inside a failing partition
         # drain could recurse into the conf-registry lock)
@@ -572,6 +577,11 @@ class TpuSession:
         from ..exec.spill import BufferCatalog
         cat = BufferCatalog.get()
         base_dev, base_host = getattr(self, "_mem_baseline", (0, 0))
+        # read from the recorder itself where it is still reachable: the
+        # caller's fetch_to_host of the result adds its span after the
+        # collect returned
+        rec = getattr(self, "_last_span_recorder", None)
+        from ..analysis import recompile
         return {
             "operators": [
                 {"depth": d, "operator": name, "metrics": m}
@@ -590,7 +600,15 @@ class TpuSession:
             # per-span wall-clock breakdown (self time, nesting excluded):
             # names where executeTimeS went — concurrent partition tasks
             # can legitimately sum past the wall clock
-            "spans": getattr(self, "_last_span_report", {}),
+            "spans": rec.report() if rec is not None
+            else getattr(self, "_last_span_report", {}),
+            # every program this query dispatched or rebuilt, by kernel
+            # family (``<eager>:<op>`` for a jnp op outside the program
+            # funnel): dispatches, and XLA's own count and seconds of
+            # traces, lowerings, backend compiles and persistent-cache
+            # loads (docs/observability.md §9)
+            "programs": recompile.programs_report(rec.programs)
+            if rec is not None else {},
             # driver-side planning (analyze + overrides) wall time and the
             # execute_collect wall (device work + transfers + syncs): with
             # the per-operator timers these account for the query's wall

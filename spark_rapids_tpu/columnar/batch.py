@@ -37,8 +37,11 @@ class ColumnarBatch:
     # the donation site) — the arrays are DEAD and any further read
     # through the funnels below diagnoses as use-after-donate instead of
     # surfacing jax's bare "Array has been deleted"
+    # ``recording``: on a collect's RESULT batch, the query's recorders
+    # (exec/tracing.QueryRecording) — the caller's fetch_to_host then
+    # records its span under the same query id
     __slots__ = ("schema", "columns", "_num_rows", "origin", "shared",
-                 "params", "donated")
+                 "params", "donated", "recording")
 
     def __init__(self, schema: dt.Schema, columns: List[Column], num_rows: int):
         assert len(schema) == len(columns), "schema/column arity mismatch"
@@ -50,6 +53,7 @@ class ColumnarBatch:
         self.shared = False
         self.params = ()
         self.donated = None
+        self.recording = None
         if isinstance(num_rows, (int, np.integer)):
             self._num_rows = int(num_rows)
         else:
@@ -275,7 +279,6 @@ class ColumnarBatch:
         round-trip per array — which dominates on high-latency links).
         Returns a batch whose columns are numpy-backed, sliced to
         ``num_rows``."""
-        import jax
         if self.donated is not None:
             from ..analysis import ledger
             ledger.check_batch_access(self)
@@ -285,6 +288,13 @@ class ColumnarBatch:
         if all(isinstance(c.data, np.ndarray) for c in self.columns):
             self.num_rows
             return self
+        if self.recording is None:
+            return self._fetch_device_columns()
+        with self.recording.resumed("fetch_to_host"):
+            return self._fetch_device_columns()
+
+    def _fetch_device_columns(self) -> "ColumnarBatch":
+        import jax
         if not isinstance(self.num_rows_raw, int) and \
                 self.capacity <= (1 << 14):
             # device-resident count + small batch: ONE transfer carries the
@@ -399,6 +409,7 @@ def _unpack_program(spec, pos):
     import jax
     import jax.lax as lax
     from ..exec import compile_cache as _cc
+    from ..exec.tracing import shared_stage
     # donate the staging buffer: the unpack is its only consumer, and at
     # one full batch of bytes it is exactly the transient the HBM
     # watermark blames on scans (baked into the program -> keyed)
@@ -409,6 +420,7 @@ def _unpack_program(spec, pos):
         if len(_UNPACK_CACHE) > 256:
             _UNPACK_CACHE.clear()
 
+        @shared_stage("scan_unpack")
         def unpack(b):
             outs = []
             for dstr, shape, off, nbytes in spec:
@@ -425,12 +437,9 @@ def _unpack_program(spec, pos):
             return tuple(outs)
         # audited + persisted like every _fused_fn program (the naked-jit
         # rule: no compile escapes the recompile/compile-cache funnel)
-        _kind, wrap = _cc.note_build(("scan_unpack",) + key, "scan_unpack")
+        wrap = _cc.note_build(("scan_unpack",) + key, "scan_unpack")
         fn = _UNPACK_CACHE[key] = wrap(
             jax.jit(unpack, donate_argnums=donate))  # lint: naked-jit-ok scan unpack cache: audited via compile_cache.note_build above
-    else:
-        from ..analysis import recompile as _recompile
-        _recompile.note_call("scan_unpack")
     return fn
 
 

@@ -30,10 +30,15 @@ funnel (``plan/physical.py``), not side effects:
   owned by a catalog entry that re-reads them (``ColumnarBatch.origin``
   / ``.shared``).
 
-First-call wall time of every freshly-built program is metered
-(compile-dominated on every real backend) into the recompile audit, the
-``tpu_compile_seconds{kind}`` telemetry histogram, and the innermost
-open exec's ``compileSeconds`` metric.
+Every program that reaches the device through a program cache is wrapped
+in a :class:`Program` (docs/observability.md §9): compiled under the
+stable name of its kernel family, counted per dispatch, and — under
+``tracing.enabled`` — bracketed by a ``program:<family>`` profiler span.
+What XLA rebuilds (trace, lowering, backend compile, persistent-cache
+load) is heard from ``jax.monitoring`` (:func:`install_compile_listener`)
+and charged to the family open on the thread, the innermost open span,
+the innermost open exec's ``compileSeconds`` and the
+``tpu_compile_seconds{kind}`` telemetry histogram.
 """
 
 from __future__ import annotations
@@ -42,11 +47,15 @@ import hashlib
 import json
 import logging
 import os
+import re
+import threading
 import time
 import warnings
 from typing import Any, Optional, Set
 
+from ..analysis import recompile
 from ..analysis.lockdep import named_lock
+from . import metrics as em, tracing
 
 # Donating a buffer whose shape/layout XLA cannot reuse for an output
 # still FREES it the moment the program ingests it — that eager free IS
@@ -224,13 +233,12 @@ def sig_hash(key: Any) -> str:
     return hashlib.sha256(repr(key).encode()).hexdigest()
 
 
-def classify(key: Any) -> str:
-    """``disk`` when this signature was built against the active cache
-    dir by a previous process (XLA serves the executable from disk),
-    ``cold`` otherwise (including when no cache dir is configured)."""
-    if _cache_dir is None:
-        return "cold"
-    return "disk" if sig_hash(key) in _index else "cold"
+def seen_on_disk(key: Any) -> bool:
+    """Whether a previous process built this signature against the active
+    cache dir, so that XLA will most likely load the executable instead of
+    compiling it. A FORECAST for the compile pool's routing only: what a
+    build turned out to be is XLA's own report (:func:`_on_duration`)."""
+    return _cache_dir is not None and sig_hash(key) in _index
 
 
 def record(key: Any, kernel: str) -> None:
@@ -370,79 +378,186 @@ def jit_map_guard() -> None:
         pass
 
 
-def note_compile_seconds(kernel: str, seconds: float, kind: str) -> None:
-    """Meter one program's first-call wall seconds: recompile audit
-    (per-family ``compileS``), the ``tpu_compile_seconds{kind}``
-    histogram, and the innermost open exec's ``compileSeconds``."""
-    from ..analysis import recompile
-    recompile.note_compile_time(kernel, seconds)
-    from . import metrics as em
+# ---------------------------------------------------------------------------
+# The program boundary: names, dispatch counts, spans, compile events
+# ---------------------------------------------------------------------------
+
+#: what is open on THIS thread: ``family`` of the program being called
+#: (None outside any), ``cache_hit`` when jax has just reported a load
+#: from the persistent cache (its backend-compile duration follows),
+#: ``tracing`` how many jaxpr traces are open (a jitted function traces
+#: the jitted jnp helpers it calls inside its own trace)
+_tls = threading.local()
+
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+#: duration event -> (count field, seconds field) of a ``programs`` entry
+_EVENT_FIELDS = {_TRACE_EVENT: ("traces", "traceS"),
+                 _LOWER_EVENT: (None, "lowerS"),
+                 _BACKEND_EVENT: ("compiles", "compileS")}
+_LOAD_FIELDS = ("cacheLoads", "loadS")
+_listener_installed = False
+
+
+def program_name(family: str) -> str:
+    """The name a family's programs compile under (the profiler's
+    ``XLA Modules`` line shows ``jit_<name>``): the family with every
+    non-identifier character turned to ``_``. A function of the cache
+    key's string tags alone — no shape, literal or ``id()`` — so it is
+    the same in every process and the persistent cache keeps hitting."""
+    return re.sub(r"\W", "_", family)
+
+
+def open_family() -> Optional[str]:
+    """Family of the program being called on this thread, if any."""
+    return getattr(_tls, "family", None)
+
+
+def _eager_family(fun_name) -> str:
+    """``<eager>:<op>`` for a compile event no :class:`Program` was open
+    for (a jnp op dispatched on its own). jax names the traced function
+    ``sort`` and its module ``jit(sort)``: both land on one key."""
+    name = str(fun_name or "?")
+    if name.startswith("jit(") and name.endswith(")"):
+        name = name[4:-1]
+    return "<eager>:" + name
+
+
+def _on_event(event: str, **_kw) -> None:
+    if event == _CACHE_HIT_EVENT:
+        _tls.cache_hit = True
+
+
+def _on_scalar(event: str, _value, **_kw) -> None:
+    # jax reports the START of each timed region as a scalar
+    if event == _TRACE_EVENT:
+        _tls.tracing = getattr(_tls, "tracing", 0) + 1
+
+
+def _on_duration(event: str, seconds: float, **kw) -> None:
+    fields = _EVENT_FIELDS.get(event)
+    if fields is None:
+        return
+    family = open_family()
+    if event == _TRACE_EVENT:
+        # only the outermost trace counts: the seconds of the helpers
+        # traced inside it are part of its own
+        depth = _tls.tracing = max(getattr(_tls, "tracing", 1) - 1, 0)
+        if depth:
+            return
+        if family is not None and \
+                kw.get("fun_name") != program_name(family):
+            # a helper traced while the program lowers (a sort's
+            # comparator, a scan's body): its seconds, not a re-trace
+            fields = (None, fields[1])
+    kind = None
+    if event == _BACKEND_EVENT:
+        # jax times a load from the persistent cache under the same
+        # event, after a cache_hits event on the same thread
+        kind = "disk" if getattr(_tls, "cache_hit", False) else "cold"
+        _tls.cache_hit = False
+        if kind == "disk":
+            fields = _LOAD_FIELDS
+    rec = tracing.SpanRecorder.active
+    recompile.note_rebuild(
+        family or _eager_family(kw.get("fun_name")), fields, seconds,
+        funnel=family is not None,
+        query_programs=rec.programs if rec is not None else None)
+    if rec is not None:
+        rec.note_rebuild(seconds)
     em.attribute("compileSeconds", seconds)
-    try:
-        from ..service.telemetry import MetricsRegistry
-        MetricsRegistry.get().histogram(
-            "tpu_compile_seconds",
-            "first-call wall seconds of freshly built fused programs "
-            "(compile-dominated), by cold build vs persistent-cache disk "
-            "hit", kind=kind).observe(seconds)
-    except Exception:
-        pass         # telemetry must never fail a compile
+    if kind is not None:
+        try:
+            from ..service.telemetry import MetricsRegistry
+            MetricsRegistry.get().histogram(
+                "tpu_compile_seconds",
+                "backend seconds of each XLA build, by cold compile vs "
+                "load from the persistent cache", kind=kind).observe(seconds)
+        except Exception:
+            pass         # telemetry must never fail a compile
 
 
-class TimedFirstCall:
-    """Wraps a freshly-built jitted program so its FIRST invocation —
-    the one that pays tracing + XLA compilation (or the disk-cache
-    load) — is timed and metered. Later calls pay one attribute check."""
+def install_compile_listener() -> None:
+    """Hear every trace, lowering, backend compile and persistent-cache
+    load from jax itself (session bootstrap; installs once a process)."""
+    global _listener_installed
+    with _lock:
+        if _listener_installed:
+            return
+        _listener_installed = True
+    import jax.monitoring
+    jax.monitoring.register_event_listener(_on_event)
+    jax.monitoring.register_scalar_listener(_on_scalar)
+    jax.monitoring.register_event_duration_secs_listener(_on_duration)
 
-    __slots__ = ("_fn", "_kernel", "_kind", "_timed")
 
-    def __init__(self, fn, kernel: str, kind: str):
+class Program:
+    """One compiled program as its cache hands it out: the jitted
+    callable named after its family, and the ONE place a call of it is
+    seen — a dispatch count (the audit's ``calls`` and the active
+    query's ``programs`` map, one lock), the family left open on the
+    thread for the compile listener, and under ``tracing.enabled`` a
+    ``program:<family>`` profiler span carrying the query id and the
+    innermost open exec."""
+
+    __slots__ = ("_fn", "_family", "_called")
+
+    def __init__(self, fn, family: str):
+        inner = getattr(fn, "__wrapped__", None)
+        if inner is not None:
+            # jax reads the name when it traces, so this is in time
+            try:
+                inner.__name__ = inner.__qualname__ = program_name(family)
+            except (AttributeError, TypeError):
+                pass
         self._fn = fn
-        self._kernel = kernel
-        self._kind = kind
-        self._timed = False
+        self._family = family
+        self._called = False
 
-    def __call__(self, *args, **kwargs):
-        if self._timed:
-            return self._fn(*args, **kwargs)
-        jit_map_guard()     # relieve map pressure BEFORE the compile
+    def _first_call(self, args):
+        """Crash forensics round the call that compiles: relieve JIT map
+        pressure first, and under ``SRT_COMPILE_TRACE`` leave a BEGIN line
+        naming the program (the last one names a compile that never
+        returned; maps = /proc/self/maps entries)."""
+        jit_map_guard()
         trace = os.environ.get("SRT_COMPILE_TRACE")
         if trace:
-            # crash-forensics breadcrumb: the last line names the program
-            # whose first call (the XLA compile) never returned; maps =
-            # /proc/self/maps entries (JIT mmap exhaustion shows here)
-            try:
-                with open("/proc/self/maps") as mf:
-                    nmaps = sum(1 for _ in mf)
-            except OSError:
-                nmaps = -1
             with open(trace, "a") as f:
-                f.write(f"BEGIN {time.time():.1f} {self._kind} "
-                        f"{self._kernel} maps={nmaps} "
+                f.write(f"BEGIN {time.time():.1f} {self._family} "
+                        f"maps={_map_count()} "
                         f"args={[getattr(a, 'shape', a) for a in args]}\n")
-        t0 = time.perf_counter()
-        out = self._fn(*args, **kwargs)
-        self._timed = True
-        note_compile_seconds(self._kernel, time.perf_counter() - t0,
-                             self._kind)
+        return trace
+
+    def __call__(self, *args, **kwargs):
+        rec = tracing.SpanRecorder.active
+        recompile.note_call(
+            self._family, rec.programs if rec is not None else None)
+        trace = None if self._called else self._first_call(args)
+        prev = open_family()
+        _tls.family = self._family
+        try:
+            if tracing._tracing_on():
+                with tracing.program_annotation(self._family, rec):
+                    out = self._fn(*args, **kwargs)
+            else:
+                out = self._fn(*args, **kwargs)
+        finally:
+            _tls.family = prev
         if trace:
             with open(trace, "a") as f:
-                f.write(f"END {time.time():.1f} {self._kernel}\n")
+                f.write(f"END {time.time():.1f} {self._family}\n")
+        self._called = True
         return out
-
-
-def timed(fn, kernel: str, kind: str):
-    return TimedFirstCall(fn, kernel, kind)
 
 
 def note_build(key: Any, kernel: str):
     """One-call integration for program caches OUTSIDE the ``_fused_fn``
     funnel (mesh SPMD stages, the scan unpack cache, the shuffle split
-    cache): classify the build against the persistent index, account it
-    in the recompile audit, persist the signature, and return
-    ``(kind, wrap)`` where ``wrap(fn)`` adds first-call timing."""
-    from ..analysis import recompile
-    kind = classify(key)
-    recompile.note_compile(kernel, key, kind=kind)
+    cache): account the build in the recompile audit, persist the
+    signature, and return ``wrap`` where ``wrap(fn)`` is the
+    :class:`Program` of the family."""
+    recompile.note_compile(kernel, key)
     record(key, kernel)
-    return kind, (lambda fn: TimedFirstCall(fn, kernel, kind))
+    return lambda fn: Program(fn, kernel)

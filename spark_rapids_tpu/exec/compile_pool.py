@@ -23,8 +23,8 @@ build path byte-for-byte unchanged — that is what keeps the repeat-
 compiles-nothing gates (tests/test_zz_recompile_gate.py) meaningful.
 
 Every pool build goes through the SAME ``_fused_fn`` funnel as a
-synchronous build (plan/physical.py): classify cold-vs-disk, recompile
-audit, signature-index record, first-call timing. The pool worker then
+synchronous build (plan/physical.py): recompile audit, signature-index
+record, the named and counted ``Program``. The pool worker then
 warm-calls the jitted program with zero-filled dummies captured on the
 submitting thread (``jnp.zeros_like`` preserves shape/dtype/weak-type,
 so the warm call's jit signature exactly matches the real call) — the
@@ -159,7 +159,7 @@ def routable(key) -> bool:
     if not _enabled or _shutdown:
         return False
     from . import compile_cache as _cc
-    if _cc.classify(key) != "cold":
+    if _cc.seen_on_disk(key):
         return False
     if qc.streaming_active():
         return True
@@ -299,9 +299,9 @@ def _run_job(job: "_Job") -> None:
     t0 = time.perf_counter()
     try:
         from ..plan.physical import _fused_fn
-        # the SAME funnel as a synchronous build: classify, recompile
-        # audit, signature record, first-call timing — then the warm
-        # call actually pays the XLA compile here, on the pool thread
+        # the SAME funnel as a synchronous build: recompile audit,
+        # signature record, the named Program — then the warm call
+        # actually pays the XLA compile here, on the pool thread
         fn = _fused_fn(job.key, job.builder)
         fn(*job.warm_args)
     except BaseException as e:

@@ -6,9 +6,14 @@ semaphore acquire (GpuSemaphore.scala:107), agg batches (aggregate.scala:435),
 shuffle write (RapidsShuffleInternalManager.scala:91).
 
 TPU analog: ``jax.profiler.TraceAnnotation`` spans show up in xprof/
-TensorBoard traces; ``start_profiler_server`` exposes the live profiler.
-Disabled (no-op, zero overhead beyond one attr check) unless
+TensorBoard traces, on the same clock as the device ops. Disabled (no-op,
+zero overhead beyond one attr check) unless
 ``spark.rapids.tpu.sql.tracing.enabled`` is on.
+
+INSIDE a device program the layers are named by ``jax.named_scope``: the
+plan operator outermost, then one of :data:`STAGES` (:func:`stage`).
+Scopes exist only while jax traces and change HLO metadata alone; a
+profile's ``XLA Ops`` then read ``jit(<family>)/<operator>/<stage>/...``.
 
 Beyond the per-name self-time totals, ``SpanRecorder`` optionally records
 every span's begin/end with its thread (conf
@@ -20,6 +25,7 @@ or ui.perfetto.dev (see docs/observability.md).
 
 from __future__ import annotations
 
+import functools
 from contextlib import contextmanager
 from typing import Any, Dict, List, Optional
 
@@ -39,7 +45,7 @@ def _tracing_on() -> bool:
     global _enabled
     if _enabled is None:
         from .. import config as cfg
-        _enabled = bool(cfg.TpuConf().get(cfg.TRACING_ENABLED))
+        _enabled = bool(_effective_conf().get(cfg.TRACING_ENABLED))
     return _enabled
 
 
@@ -58,6 +64,69 @@ def reset_cache() -> None:
     global _enabled, _timeline
     _enabled = None
     _timeline = None
+
+
+#: the kernel stages a device program is divided into, each a
+#: ``jax.named_scope`` inside its operator's scope. A stage with two
+#: implementations has two names, so that moving work from one to the
+#: other shows as seconds leaving one name and arriving at the other.
+STAGES = (
+    "scan_unpack", "filter", "project", "key_encode", "lexsort", "gather",
+    "segment_starts", "segment_sum_scatter", "segment_sum_matmul",
+    "segment_sum_dense", "segment_minmax", "reduce", "join_probe",
+    "join_gather", "compact", "concat", "shuffle_split")
+
+
+def stage(name: str, operator: Optional[str] = None):
+    """Decorator: the kernel runs inside ``jax.named_scope(name)``, one of
+    :data:`STAGES` — and, given ``operator``, inside that scope first.
+    Costs a context-manager entry where the kernel runs eagerly and
+    nothing in a compiled program."""
+    assert name in STAGES, name
+
+    def deco(fn):
+        @functools.wraps(fn)
+        def scoped(*args, **kwargs):
+            import jax
+            with jax.named_scope(f"{operator}/{name}" if operator else name):
+                return fn(*args, **kwargs)
+        return scoped
+    return deco
+
+
+def shared_stage(name: str):
+    """:func:`stage` for the whole of a program that several operators
+    share (the scan unpack, concat, the shuffle split): its ops read
+    ``shared/<stage>``."""
+    return stage(name, operator="shared")
+
+
+def operator_scope(node):
+    """``jax.named_scope`` of a plan operator (its class name, or the
+    name itself): the outer scope of what it contributes to a program."""
+    import jax
+    return jax.named_scope(node if isinstance(node, str)
+                           else type(node).__name__)
+
+
+def _annotation(name: str, rec: Optional["SpanRecorder"], **kw):
+    """A profiler annotation that carries the active query's id."""
+    import jax
+    qid = rec.query_id if rec is not None else None
+    if qid is not None:
+        kw["query"] = qid
+    return jax.profiler.TraceAnnotation(name, **kw)
+
+
+def program_annotation(family: str, rec: Optional["SpanRecorder"]):
+    """``program:<family>`` round one program call (only under
+    ``tracing.enabled``): the host's tracing, cache load and enqueue lie
+    in the profile beside the device ops the call launched."""
+    from .metrics import current
+    bag = current()
+    op = getattr(bag, "owner", None) if bag is not None else None
+    return _annotation("program:" + family, rec,
+                       **({"op": op} if op else {}))
 
 
 def _telemetry_span(name: str, begin: float, elapsed: float,
@@ -99,8 +168,7 @@ def trace_span(name: str, metrics=None, metric_key: Optional[str] = None):
     try:
         with exec_scope(metrics):
             if _tracing_on():
-                import jax
-                with jax.profiler.TraceAnnotation(name):
+                with _annotation(name, rec):
                     yield
             else:
                 yield
@@ -141,7 +209,18 @@ class SpanRecorder:
         self._mu = named_lock("exec.tracing.SpanRecorder._mu")
         self._tls = threading.local()
         self._timeline = _timeline_on() if timeline is None else timeline
-        self._events: List[tuple] = []     # (name, begin, dur, tid, tname)
+        # (name, begin, dur, tid, tname, parent span name or None)
+        self._events: List[tuple] = []
+        # per-span compile events: name -> [count, seconds] of what XLA
+        # rebuilt (trace, lowering, compile, cache load) while the span
+        # was the innermost open one (exec/compile_cache._on_duration)
+        self._rebuilds: Dict[str, list] = {}
+        # family -> counters of every program this query dispatched or
+        # rebuilt; written under analysis/recompile's lock
+        # (recompile.note_call / note_rebuild), reported by
+        # last_query_metrics()["programs"]
+        self.programs: Dict[str, Dict[str, Any]] = {}
+        self._root: Optional[str] = None   # the driving thread's open root
         self._t0: Optional[float] = None   # entered wall-clock origin
         self._wall: Optional[float] = None
         # the query id this recorder's spans belong to (set by the
@@ -174,8 +253,14 @@ class SpanRecorder:
         # the frame carries its span name so the sync counter can
         # attribute device->host readbacks to the innermost open span
         # (the syncs-per-span breakdown the bench runner reports)
-        frame = {"name": name, "child_s": 0.0}
-        self._stack().append(frame)
+        st = self._stack()
+        # the span that caused this one: the enclosing span on this
+        # thread, else (a pool thread's first span) the query's root
+        parent = st[-1]["name"] if st else self._root
+        if not st and self._root is None:
+            self._root = name  # lint: unguarded-ok set once by the driving thread's first span, before any task thread runs
+        frame = {"name": name, "child_s": 0.0, "parent": parent}
+        st.append(frame)
         return frame
 
     def current_span(self):
@@ -206,12 +291,21 @@ class SpanRecorder:
         if self._timeline and begin is not None:
             import threading
             t = threading.current_thread()
-            ev = (name, begin, elapsed, t.ident, t.name)
+            ev = (name, begin, elapsed, t.ident, t.name, frame["parent"])
         with self._mu:
             self._self_s[name] += self_s
             self._count[name] += 1
             if ev is not None:
                 self._events.append(ev)
+
+    def note_rebuild(self, seconds: float) -> None:
+        """Charge one compile event of XLA's to the innermost span open
+        on this thread (``<no-span>`` outside any)."""
+        name = self.current_span() or "<no-span>"
+        with self._mu:
+            ent = self._rebuilds.setdefault(name, [0, 0.0])
+            ent[0] += 1
+            ent[1] += seconds
 
     def add(self, name, seconds):
         """Account an externally-timed interval as a leaf span (semaphore
@@ -223,7 +317,7 @@ class SpanRecorder:
             import time
             t = threading.current_thread()
             ev = (name, time.perf_counter() - seconds, seconds,
-                  t.ident, t.name)
+                  t.ident, t.name, self.current_span() or self._root)
         with self._mu:
             self._self_s[name] += seconds
             self._count[name] += 1
@@ -244,13 +338,19 @@ class SpanRecorder:
         """name -> {selfS, count}, most-expensive first, plus two reserved
         scalar entries: ``wallS`` (the recorder's wall clock) and
         ``concurrency`` (sum of self-time over wall — pool threads
-        legitimately push this past 1.0; ~1.0 means serial execution)."""
+        legitimately push this past 1.0; ~1.0 means serial execution).
+        A span under which XLA rebuilt anything also carries
+        ``rebuilds`` / ``rebuildS`` (compile events and their seconds)."""
         with self._mu:
             out: Dict[str, Any] = {
                 name: {"selfS": round(s, 4), "count": self._count[name]}
                 for name, s in sorted(self._self_s.items(),
                                       key=lambda kv: -kv[1])}
             total_self = sum(self._self_s.values())
+            for name, (n, secs) in self._rebuilds.items():
+                ent = out.setdefault(name, {"selfS": 0.0, "count": 0})
+                ent["rebuilds"] = n
+                ent["rebuildS"] = round(secs, 6)
         wall = self.wall_s()
         out["wallS"] = round(wall, 4)
         out["concurrency"] = round(total_self / wall, 2) if wall > 0 else 0.0
@@ -274,16 +374,21 @@ class SpanRecorder:
         # would merge a dead shuffle-conn thread's spans into whichever
         # later thread inherited its ident
         track_of: Dict[tuple, int] = {}
-        for name, begin, dur, tid, tname in events:
+        for name, begin, dur, tid, tname, parent in events:
             track = track_of.setdefault((tid, tname), len(track_of) + 1)
             ev = {
                 "ph": "X", "cat": "span", "name": name, "pid": 0,
                 "tid": track, "ts": round((begin - base) * 1e6, 1),
                 "dur": round(dur * 1e6, 1)}
+            args = {}
             if self.query_id is not None:
                 # per-event query attribution: the merged multi-worker
                 # timeline filters/joins spans on this
-                ev["args"] = {"query": self.query_id}
+                args["query"] = self.query_id
+            if parent is not None:
+                args["parent"] = parent
+            if args:
+                ev["args"] = args
             out.append(ev)
         for (_tid, tname), track in sorted(track_of.items(),
                                            key=lambda kv: kv[1]):
@@ -357,10 +462,53 @@ def record_span(name: str, seconds: float) -> None:
         rec.add(name, seconds)
 
 
-def start_profiler_server(port: int = 9012) -> None:
-    """Expose the live jax profiler (xprof capture target)."""
-    import jax
-    jax.profiler.start_server(port)
+class QueryRecording:
+    """The recorders of ONE collect: a :class:`SyncCounter` and a
+    :class:`SpanRecorder` with the root span ``query`` open. Opened
+    before planning and closed where execution ends (the reports are
+    read after that); :meth:`resumed` re-opens the span side for the
+    result's ``fetch_to_host``, which the caller runs afterwards."""
+
+    def __init__(self):
+        self.sync = SyncCounter()
+        self.spans = SpanRecorder()
+        self._open = None
+
+    def open(self) -> "QueryRecording":
+        import contextlib
+        with contextlib.ExitStack() as st:
+            st.enter_context(self.sync)
+            st.enter_context(self.spans)
+            st.enter_context(trace_span("query"))
+            self._open = st.pop_all()
+        return self
+
+    def close(self) -> None:
+        """Idempotent; an exception in flight marks the root span."""
+        import sys
+        st, self._open = self._open, None
+        if st is not None:
+            st.__exit__(*sys.exc_info())
+
+    @contextmanager
+    def resumed(self, name: str):
+        """A span of the same query after its recorder closed: recorded
+        with the query's id, its time added to the recorder's wall."""
+        import time
+        rec = self.spans
+        if self._open is not None or SpanRecorder.active is not None:
+            with trace_span(name):    # still (or again) inside a query
+                yield
+            return
+        SpanRecorder.active = rec  # lint: unguarded-ok the caller's thread, after its query ended and with no other recorder active
+        t0 = time.perf_counter()
+        try:
+            with trace_span(name):
+                yield
+        finally:
+            SpanRecorder.active = None  # lint: unguarded-ok restores the idle state checked above
+            if rec._wall is not None:
+                rec._wall += time.perf_counter() - t0  # lint: unguarded-ok caller-thread bookkeeping after the query ended
 
 
 # ---------------------------------------------------------------------------
@@ -422,6 +570,11 @@ class SyncCounter:
         self.total = 0
         self.sites: dict = {}
         self.spans: dict = {}      # innermost-span name -> sync count
+        # seconds the host stood blocked in those readbacks: in all, per
+        # site and per innermost span (same keys as the counts)
+        self.wait_s = 0.0
+        self.site_wait_s: dict = {}
+        self.span_wait_s: dict = {}
 
     # -- patch management ---------------------------------------------------
     @classmethod
@@ -434,10 +587,10 @@ class SyncCounter:
         def counting_value(self_arr):
             c = cls._get_active()
             # only count REAL syncs: a cached host value is free
-            if c is not None and \
-                    getattr(self_arr, "_npy_value", None) is None:
-                c._record()
-            return orig.fget(self_arr)
+            if c is None or \
+                    getattr(self_arr, "_npy_value", None) is not None:
+                return orig.fget(self_arr)
+            return c._timed_read(orig.fget, self_arr)
 
         cls._orig_value = orig  # lint: unguarded-ok one-time process-lifetime patch installed from the first entering thread
         jarray.ArrayImpl._value = property(counting_value)
@@ -449,6 +602,24 @@ class SyncCounter:
         from jax._src import array as jarray
         jarray.ArrayImpl._value = cls._orig_value
         cls._orig_value = None  # lint: unguarded-ok test-only restore of the pristine property
+
+    def _timed_read(self, read, arr):
+        """Count the readback, then time the wait for it; under
+        ``tracing.enabled`` the wait is a ``host_sync`` profiler span."""
+        import time
+        site, span = self._record()
+        t0 = time.perf_counter()
+        try:
+            if _tracing_on():
+                with _annotation("host_sync", SpanRecorder.active,
+                                 site=site):
+                    return read(arr)
+            return read(arr)
+        finally:
+            waited = time.perf_counter() - t0
+            self.wait_s += waited  # lint: unguarded-ok best-effort counter, see total in _record
+            self.site_wait_s[site] = self.site_wait_s.get(site, 0.0) + waited  # lint: unguarded-ok best-effort counter map, see total in _record
+            self.span_wait_s[span] = self.span_wait_s.get(span, 0.0) + waited  # lint: unguarded-ok best-effort counter map, see total in _record
 
     def _record(self):
         import traceback
@@ -477,6 +648,7 @@ class SyncCounter:
         # ANALYZE shows which plan node paid the round trip
         from .metrics import attribute
         attribute("hostSyncs")
+        return site, span
 
     # -- context ------------------------------------------------------------
     def __enter__(self):
@@ -509,4 +681,9 @@ class SyncCounter:
         spans = sorted(self.spans.items(), key=lambda kv: -kv[1])
         return {"hostSyncs": self.total,
                 "syncSites": dict(ordered[:top]),
-                "syncSpans": dict(spans[:top])}
+                "syncSpans": dict(spans[:top]),
+                "syncWaitS": round(self.wait_s, 6),
+                "syncSiteWaitS": {k: round(self.site_wait_s.get(k, 0.0), 6)
+                                  for k, _ in ordered[:top]},
+                "syncSpanWaitS": {k: round(self.span_wait_s.get(k, 0.0), 6)
+                                  for k, _ in spans[:top]}}

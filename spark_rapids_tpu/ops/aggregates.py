@@ -22,6 +22,7 @@ import jax.numpy as jnp
 
 from ..columnar import dtypes as dt
 from ..columnar.column import Column
+from ..exec.tracing import stage
 from . import kernels as K
 
 
@@ -101,6 +102,17 @@ def segment_aggregate(spec: AggSpec, seg_ids: jnp.ndarray, live: jnp.ndarray,
     live in row space); slots beyond the group count are zeroed+invalid by
     construction because no row contributes to them.
     """
+    # the scatter implementation of a segment reduction, named apart
+    # from the matmul and dense ones (exec/tracing.STAGES)
+    name = "segment_minmax" if spec.op in ("min", "max", "first", "last") \
+        else "segment_sum_scatter"
+    with jax.named_scope(name):
+        return _segment_aggregate(spec, seg_ids, live, capacity,
+                                  num_segments)
+
+
+def _segment_aggregate(spec: AggSpec, seg_ids: jnp.ndarray, live: jnp.ndarray,
+                       capacity: int, num_segments: Optional[int]) -> Column:
     ns = capacity if num_segments is None else num_segments
     op = spec.op
     if op == "count_star":
@@ -206,8 +218,9 @@ def groupby_aggregate(key_cols: Sequence[Column], specs: Sequence[AggSpec],
     n_groups = jnp.sum(starts).astype(jnp.int32)
 
     # group keys: gather the first row of each segment to the front
-    start_perm, _ = K.compaction_indices(starts)
-    group_live = jnp.arange(capacity) < n_groups
+    with jax.named_scope("segment_starts"):
+        start_perm, _ = K.compaction_indices(starts)
+        group_live = jnp.arange(capacity) < n_groups
     out_keys = [K.gather_column(c, start_perm, out_valid=group_live)
                 for c in sorted_keys]
 
@@ -224,6 +237,7 @@ def groupby_aggregate(key_cols: Sequence[Column], specs: Sequence[AggSpec],
     return out_keys, out_aggs, n_groups
 
 
+@stage("reduce")
 def reduce_aggregate(specs: Sequence[AggSpec], num_rows, capacity: int,
                      live_mask: Optional[jnp.ndarray] = None
                      ) -> List[Column]:
@@ -321,6 +335,7 @@ def _matmul_supported(spec: AggSpec) -> bool:
     return False
 
 
+@stage("segment_sum_matmul")
 def segment_aggregate_matmul(spec: AggSpec, seg_ids: jnp.ndarray,
                              live: jnp.ndarray, K: int) -> Column:
     """MXU reduction to K group slots (first K slots of capacity outputs)."""
@@ -379,6 +394,7 @@ def dense_supported_key(col: Column) -> bool:
 F32_SAFE_ABSMAX = 1e33
 
 
+@stage("reduce")
 def dense_key_stats(key_col: Column, num_rows,
                     extra_mask: Optional[jnp.ndarray] = None,
                     float_cols: Sequence[Column] = ()):
@@ -474,6 +490,7 @@ def _recombine_nibble_sums(acc: jnp.ndarray) -> jnp.ndarray:
     return total.astype(jnp.int64)
 
 
+@stage("segment_sum_dense")
 def groupby_dense(key_col: Column, specs: Sequence[AggSpec], num_rows,
                   K_slots: int, rmin,
                   extra_mask: Optional[jnp.ndarray] = None

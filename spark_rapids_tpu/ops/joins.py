@@ -34,6 +34,7 @@ import jax.numpy as jnp
 
 from ..columnar import dtypes as dt
 from ..columnar.column import Column
+from ..exec.tracing import stage
 from . import kernels as K
 
 
@@ -132,6 +133,16 @@ def join_match(build_keys: Sequence[Column], n_build,
             stream_keys[i] = _widen_string(s, width)
     order = K.sort_indices([K.SortKey(c) for c in build_keys], n_build, build_cap)
     sorted_build = [K.gather_column(c, order) for c in build_keys]
+    return _probe_sorted(sorted_build, order, n_build, build_cap,
+                         stream_keys, n_stream, stream_capacity)
+
+
+@stage("join_probe")
+def _probe_sorted(sorted_build: Sequence[Column], order, n_build,
+                  build_cap: int, stream_keys: Sequence[Column], n_stream,
+                  stream_capacity: int) -> JoinMatch:
+    """The probe half of :func:`join_match`: binary-search every stream
+    key in the sorted build keys."""
     b_words, b_usable = _normalize_words(sorted_build)
     s_words, s_usable = _normalize_words(stream_keys)
 
@@ -170,6 +181,7 @@ def _expand_indices(m: JoinMatch, out_capacity: int
     return stream_idx, build_sorted_idx, live
 
 
+@stage("join_gather")
 def join_gather(m: JoinMatch, stream_cols: Sequence[Column],
                 build_cols: Sequence[Column], out_capacity: int,
                 join_type: str = "inner", n_stream=None,
@@ -223,6 +235,7 @@ def join_gather(m: JoinMatch, stream_cols: Sequence[Column],
     return s_out, b_out, m.total_pairs
 
 
+@stage("join_gather")
 def unmatched_build_gather(m: JoinMatch, build_cols: Sequence[Column], n_build
                            ) -> Tuple[List[Column], jnp.ndarray]:
     """Build rows with no stream match, compacted (for FULL OUTER composition).
@@ -238,6 +251,7 @@ def unmatched_build_gather(m: JoinMatch, build_cols: Sequence[Column], n_build
     return out, cnt
 
 
+@stage("join_gather")
 def cross_join_gather(left_cols: Sequence[Column], n_left,
                       right_cols: Sequence[Column], n_right,
                       out_capacity: int

@@ -24,6 +24,7 @@ import numpy as np
 
 from ..columnar import dtypes as dt
 from ..columnar.column import Column
+from ..exec.tracing import stage
 
 _UNSIGNED = {1: jnp.uint8, 2: jnp.uint16, 4: jnp.uint32, 8: jnp.uint64}
 _SIGNBIT = {1: 0x80, 2: 0x8000, 4: 0x8000_0000, 8: 0x8000_0000_0000_0000}
@@ -184,6 +185,14 @@ def sort_indices(keys: Sequence[SortKey], num_rows, capacity: int,
     physically compacting first — compaction's scatter is the slowest
     primitive on TPU, the sort is nearly free.
     """
+    return _lexsort_passes(_encode_keys(keys, num_rows, capacity, live_mask))
+
+
+@stage("key_encode")
+def _encode_keys(keys: Sequence[SortKey], num_rows, capacity: int,
+                 live_mask: Optional[jnp.ndarray]) -> List[jnp.ndarray]:
+    """The keys as packed orderable lanes, most significant first, led by
+    the rank that sends padding (and masked-out) rows last."""
     if live_mask is not None:
         pad_rank = (~live_mask).astype(jnp.uint8)
     else:
@@ -191,7 +200,7 @@ def sort_indices(keys: Sequence[SortKey], num_rows, capacity: int,
     msf: List[Tuple[jnp.ndarray, Optional[int]]] = [(pad_rank, 1)]
     for key in keys:
         msf.extend(_key_arrays_bits(key))
-    return _lexsort_passes(pack_key_bits(msf))
+    return pack_key_bits(msf)
 
 
 def _sort_pass(lane: jnp.ndarray, perm: jnp.ndarray) -> jnp.ndarray:
@@ -200,6 +209,7 @@ def _sort_pass(lane: jnp.ndarray, perm: jnp.ndarray) -> jnp.ndarray:
     return perm
 
 
+@stage("lexsort")
 def _lexsort_passes(lanes_msf: List[jnp.ndarray]) -> jnp.ndarray:
     """Stable int32 permutation ordering rows by most-significant-first key
     lanes — ``jnp.lexsort`` done as least-significant-first PASSES of one
@@ -248,6 +258,7 @@ def _lexsort_passes(lanes_msf: List[jnp.ndarray]) -> jnp.ndarray:
 # Gather / compaction / slicing
 # ---------------------------------------------------------------------------
 
+@stage("gather")
 def gather_column(col: Column, indices: jnp.ndarray,
                   out_valid: Optional[jnp.ndarray] = None) -> Column:
     """Row gather; ``out_valid`` additionally masks output rows (False => null+zero).
@@ -300,6 +311,7 @@ def compaction_indices(keep: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
     return perm, n_keep
 
 
+@stage("compact")
 def compact_columns(cols: Sequence[Column], keep: jnp.ndarray
                     ) -> Tuple[List[Column], jnp.ndarray]:
     """Filter: keep rows where ``keep`` is True, compacted to the front.
@@ -384,6 +396,7 @@ def rebucket_column(col: Column, num_rows: int, new_capacity: int) -> Column:
 # Segment utilities (groupby/window building blocks)
 # ---------------------------------------------------------------------------
 
+@stage("segment_starts")
 def segment_starts_from_sorted_keys(key_cols: Sequence[Column], num_rows,
                                     capacity: int) -> jnp.ndarray:
     """Bool[cap]: True where row i starts a new group in key-sorted data.
@@ -416,6 +429,7 @@ def segment_starts_from_sorted_keys(key_cols: Sequence[Column], num_rows,
     return is_start | (live & (idx > 0) & changed)
 
 
+@stage("segment_starts")
 def segment_ids(starts: jnp.ndarray) -> jnp.ndarray:
     """Int32[cap] group id per row from group-start flags (0-based; padding gets last id+)."""
     return (jnp.cumsum(starts.astype(jnp.int32)) - 1).astype(jnp.int32)

@@ -99,19 +99,14 @@ def _cached_fn(key: tuple, builder):
     fn = _FN_CACHE.get(key)
     if fn is None:
         # mesh SPMD compiles ride the same audit + persistent-cache
-        # funnel as the _fused_fn programs (analysis/recompile counts
-        # cold builds vs disk hits, first-call seconds metered): no
-        # compile escapes the recompile audit
+        # funnel as the _fused_fn programs (named after their family,
+        # dispatches and XLA's compile events counted): no compile
+        # escapes the recompile audit
         from ..exec import compile_cache as _cc
         kernel = f"mesh/{key[0]}" if key and isinstance(key[0], str) \
             else "mesh"
-        _kind, wrap = _cc.note_build(("mesh",) + key, kernel)
-        fn = _FN_CACHE[key] = wrap(builder())
-    else:
-        from ..analysis import recompile as _recompile
-        _recompile.note_call(
-            f"mesh/{key[0]}" if key and isinstance(key[0], str)
-            else "mesh")
+        fn = _FN_CACHE[key] = _cc.note_build(("mesh",) + key,
+                                             kernel)(builder())
     return fn
 
 

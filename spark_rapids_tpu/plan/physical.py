@@ -31,7 +31,7 @@ from ..ops import expressions as ex
 from ..ops import kernels as K
 from ..ops import aggregates as agg_k
 from ..ops import joins as join_k
-from ..exec.tracing import trace_span
+from ..exec.tracing import operator_scope, shared_stage, trace_span
 from . import logical as lp
 
 Partition = Iterator[ColumnarBatch]
@@ -461,6 +461,7 @@ def _concat_fused(schema: dt.Schema, batches: List[ColumnarBatch],
     sig = ("concat", _schema_sig(schema), caps, widths, out_cap)
 
     def build():
+        @shared_stage("concat")
         def fn(*args):
             counts = args[:nb]
             flats = args[nb:]
@@ -584,13 +585,11 @@ def _fused_fn(key: tuple, builder):
             for old in list(_FUSED_CACHE)[:_FUSED_CACHE_MAX // 2]:
                 _FUSED_CACHE.pop(old, None)
         kernel = _recompile.kernel_of(key)
-        # classify against the persistent signature index (a 'disk' build
-        # loads its executable from the on-disk XLA cache instead of
-        # recompiling), meter the first call's compile-dominated wall
-        # seconds, and persist the signature for the next process
-        kind = _cc.classify(key)
-        fn = _FUSED_CACHE[key] = _cc.timed(builder(), kernel, kind)
-        _recompile.note_compile(kernel, key, kind=kind)
+        # the program compiles under its family's name and counts its
+        # own dispatches (exec/compile_cache.Program); the signature is
+        # persisted for the next process
+        fn = _FUSED_CACHE[key] = _cc.Program(builder(), kernel)
+        _recompile.note_compile(kernel, key)
         _cc.record(key, kernel)
     else:
         # LRU touch (dict order = insertion order): eviction drops the
@@ -600,13 +599,12 @@ def _fused_fn(key: tuple, builder):
         # audit then honestly counts.
         if _FUSED_CACHE.pop(key, None) is not None:
             _FUSED_CACHE[key] = fn
-        _recompile.note_call(_recompile.kernel_of(key))
     return fn
 
 
 def fused_cached(key: tuple) -> bool:
     """Whether a program for ``key`` is already resident — WITHOUT the
-    LRU touch or audit note_call of a real :func:`_fused_fn` consult.
+    LRU touch of a real :func:`_fused_fn` consult.
     The async compile pool's swap point: once its build lands here, the
     requesting stage's next batch takes the plain cache-hit path."""
     return key in _FUSED_CACHE
@@ -754,17 +752,20 @@ class FusedStage:
         def run_project(num_rows, *arrays):
             b = ColumnarBatch.from_flat_arrays(self.in_schema, arrays,
                                                num_rows)
-            cols = [ex.materialize(e.eval(b), b) for e in self.exprs]
+            with operator_scope("TpuProjectExec"), jax.named_scope("project"):
+                cols = [ex.materialize(e.eval(b), b) for e in self.exprs]
             return tuple(a for c in cols for a in c.arrays())
 
         def run_filter(num_rows, *arrays):
             b = ColumnarBatch.from_flat_arrays(self.in_schema, arrays,
                                                num_rows)
-            pred = self.exprs[0].eval(b)
-            if isinstance(pred, Scalar):       # constant predicate: eager
-                raise _ScalarPredicate()
-            keep = pred.data & pred.validity & b.row_mask()
-            cols, count = K.compact_columns(b.columns, keep)
+            with operator_scope("TpuFilterExec"):
+                with jax.named_scope("filter"):
+                    pred = self.exprs[0].eval(b)
+                    if isinstance(pred, Scalar):   # constant predicate: eager
+                        raise _ScalarPredicate()
+                    keep = pred.data & pred.validity & b.row_mask()
+                cols, count = K.compact_columns(b.columns, keep)
             return tuple(a for c in cols for a in c.arrays()) + (count,)
 
         return jax.jit(run_project if self.mode == "project"
@@ -789,24 +790,18 @@ class FusedStage:
                     self._ekeys = [_expr_cache_key(e) for e in self.exprs]
                 ekeys = self._ekeys
                 if any(k is None for k in ekeys):
-                    fn = self._build(donate)      # unkeyable: per-exec jit
-                    self._kernel = f"fused_{self.mode}_unkeyable"
+                    # unkeyable: per-exec jit, same Program boundary
+                    from ..exec.compile_cache import Program
+                    kernel = f"fused_{self.mode}_unkeyable"
+                    fn = Program(self._build(donate), kernel)
                     _recompile.note_compile(
-                        self._kernel,
+                        kernel,
                         ("unkeyable", self.mode, id(self), bool(donate)))
                 else:
                     key = (self.mode, _schema_sig(self.in_schema),
                            tuple(ekeys), ("donate", bool(donate)))
-                    self._kernel = _recompile.kernel_of(key)
-                    # _fused_fn accounts this first call (compile or hit)
                     fn = _fused_fn(key, lambda: self._build(donate))
                 self._fns[bool(donate)] = fn
-            else:
-                # later batches bypass the cache consult: count the call
-                # here or `calls` would track stage INSTANCES, not
-                # executions, and flagged()'s compile/call ratio would
-                # fire spuriously for fused project/filter families
-                _recompile.note_call(self._kernel)
             with trace_span(f"fused_{self.mode}"):
                 outs = fn(_dev_count(batch),
                           *batch.flat_arrays(),
@@ -1661,11 +1656,16 @@ class TpuHashAggregateExec(TpuExec):
             n_eff = b.num_rows
             mask = None
             if phase == "update":
+                # the folded operators scope themselves (StageChain)
                 b, mask = node._traced_pre_stage(b)
-                if mask is not None:
-                    import jax.numpy as jnp
-                    n_eff = jnp.sum(mask).astype(jnp.int32)
-                keys, specs = node._build_update_specs(b)
+                import jax
+                with operator_scope(node):
+                    if mask is not None:
+                        import jax.numpy as jnp
+                        with jax.named_scope("filter"):
+                            n_eff = jnp.sum(mask).astype(jnp.int32)
+                    with jax.named_scope("project"):
+                        keys, specs = node._build_update_specs(b)
             else:
                 keys, specs = node._merge_specs(b)
             return keys, specs, n_eff, mask
@@ -1699,6 +1699,7 @@ class TpuHashAggregateExec(TpuExec):
                 return None
             sig = sig + ("pre_stage", skey)
         build_eval = self._build_eval_fn(phase)
+        op = type(self).__name__       # the programs' operator scope
         pschema = self._partial_schema()
         # folded-chain query parameters ride ONLY the update-phase
         # programs (the chain evaluates there); current values append
@@ -1714,9 +1715,10 @@ class TpuHashAggregateExec(TpuExec):
                         b = ColumnarBatch.from_flat_arrays(
                             in_schema, arrays, num_rows)
                         _keys, specs, n_eff, mask = build_eval(b)
-                        aggs = agg_k.reduce_aggregate(specs, n_eff,
-                                                      b.capacity,
-                                                      live_mask=mask)
+                        with operator_scope(op):
+                            aggs = agg_k.reduce_aggregate(specs, n_eff,
+                                                          b.capacity,
+                                                          live_mask=mask)
                         return tuple(a for c in aggs for a in c.arrays())
                     return jax.jit(fn, donate_argnums=donate)
                 fn = _fused_fn(sig + ("reduce", cap,
@@ -1757,9 +1759,11 @@ class TpuHashAggregateExec(TpuExec):
                             s.column for s in specs
                             if s.op in ("sum", "avg") and s.column is not None
                             and s.column.dtype.is_floating]
-                        return agg_k.dense_key_stats(
-                            keys[0], num_rows if mask is not None else n_eff,
-                            extra_mask=mask, float_cols=float_cols)
+                        with operator_scope(op):
+                            return agg_k.dense_key_stats(
+                                keys[0],
+                                num_rows if mask is not None else n_eff,
+                                extra_mask=mask, float_cols=float_cols)
                     return jax.jit(fn)
                 probe = _fused_fn(sig + ("probe", cap), build_probe)
                 with _trace_exec(self):
@@ -1787,6 +1791,7 @@ class TpuHashAggregateExec(TpuExec):
         import jax
         import jax.numpy as jnp
         build_eval = self._build_eval_fn(phase)
+        op = type(self).__name__
         pargs = self._stage_param_args() if phase == "update" else ()
 
         if not _matmul_agg_enabled():
@@ -1803,23 +1808,26 @@ class TpuHashAggregateExec(TpuExec):
                     in_schema, arrays, num_rows)
                 keys, specs, n_eff, mask = build_eval(b)
                 capb = b.capacity
-                order = K.sort_indices(
-                    [K.SortKey(c) for c in keys], n_eff, capb,
-                    live_mask=mask)
-                skeys = [K.gather_column(c, order) for c in keys]
-                starts = K.segment_starts_from_sorted_keys(
-                    skeys, n_eff, capb)
-                parts = [jnp.sum(starts).astype(jnp.float64)]
-                for s in specs:
-                    if s.op in ("sum", "avg") and \
-                            s.column is not None and \
-                            s.column.dtype.is_floating:
-                        c = s.column
-                        a = jnp.where(
-                            c.validity & ~jnp.isnan(c.data),
-                            jnp.abs(c.data), 0.0)
-                        parts.append(jnp.max(a).astype(jnp.float64))
-                return order, starts, n_eff, jnp.stack(parts)
+                with operator_scope(op):
+                    order = K.sort_indices(
+                        [K.SortKey(c) for c in keys], n_eff, capb,
+                        live_mask=mask)
+                    skeys = [K.gather_column(c, order) for c in keys]
+                    starts = K.segment_starts_from_sorted_keys(
+                        skeys, n_eff, capb)
+                    with jax.named_scope("reduce"):
+                        parts = [jnp.sum(starts).astype(jnp.float64)]
+                        for s in specs:
+                            if s.op in ("sum", "avg") and \
+                                    s.column is not None and \
+                                    s.column.dtype.is_floating:
+                                c = s.column
+                                a = jnp.where(
+                                    c.validity & ~jnp.isnan(c.data),
+                                    jnp.abs(c.data), 0.0)
+                                parts.append(jnp.max(a).astype(jnp.float64))
+                        stats = jnp.stack(parts)
+                return order, starts, n_eff, stats
             return jax.jit(fn)
         probe = _fused_fn(sig + ("sort-probe", cap), build_sort_probe)
         with _trace_exec(self):
@@ -1835,14 +1843,16 @@ class TpuHashAggregateExec(TpuExec):
         import jax
         pschema = self._partial_schema()
         donate = _donate_argnums(batch, 1)
+        op = type(self).__name__
 
         def build_sort():
             def fn(num_rows, *arrays):
                 b = ColumnarBatch.from_flat_arrays(in_schema, arrays,
                                                    num_rows)
                 keys, specs, n_eff, mask = build_eval(b)
-                ok, oa, ng = agg_k.groupby_aggregate(
-                    keys, specs, n_eff, b.capacity, live_mask=mask)
+                with operator_scope(op):
+                    ok, oa, ng = agg_k.groupby_aggregate(
+                        keys, specs, n_eff, b.capacity, live_mask=mask)
                 flat = [a for c in ok + oa for a in c.arrays()]
                 return tuple(flat) + (ng,)
             return jax.jit(fn, donate_argnums=donate)
@@ -1909,16 +1919,18 @@ class TpuHashAggregateExec(TpuExec):
         # the dense kernel is this batch's LAST consumer (the probe only
         # read it): donate the columns so HBM frees on ingestion
         donate = _donate_argnums(batch, 2)
+        op = type(self).__name__
 
         def build_dense():
             def fn(num_rows, rmin_d, *arrays):
                 b = ColumnarBatch.from_flat_arrays(
                     in_schema, arrays, num_rows)
                 keys, specs, n_eff, mask = build_eval(b)
-                ok, oa, ng = agg_k.groupby_dense(
-                    keys[0], specs,
-                    num_rows if mask is not None else n_eff, Kb, rmin_d,
-                    extra_mask=mask)
+                with operator_scope(op):
+                    ok, oa, ng = agg_k.groupby_dense(
+                        keys[0], specs,
+                        num_rows if mask is not None else n_eff, Kb, rmin_d,
+                        extra_mask=mask)
                 flat = [a for c in ok + oa for a in c.arrays()]
                 return tuple(flat) + (ng,)
             return jax.jit(fn, donate_argnums=donate)
@@ -1954,19 +1966,27 @@ class TpuHashAggregateExec(TpuExec):
         donate = _donate_argnums(batch, 4)
         if donate:
             donate = (1, 2) + donate
+        op = type(self).__name__
 
         def build_sort_kernel(Kb=Kb, use_mm=use_mm):
             def fn(num_rows, order, starts, n_eff, *arrays):
                 b = ColumnarBatch.from_flat_arrays(
                     in_schema, arrays, num_rows)
                 keys, specs, _n, _mask = build_eval(b)
-                capb = b.capacity
-                live = jnp.arange(capb) < n_eff
-                seg_ids = K.segment_ids(starts)
-                ng = jnp.sum(starts).astype(jnp.int32)
-                start_perm, _cnt = K.compaction_indices(starts)
-                kidx = start_perm[:Kb]
-                glive = jnp.arange(Kb) < ng
+                with operator_scope(op):
+                    ok, oa, ng = sort_kernel(keys, specs, b.capacity, order,
+                                             starts, n_eff)
+                flat = [a for c in ok + oa for a in c.arrays()]
+                return tuple(flat) + (ng,)
+
+            def sort_kernel(keys, specs, capb, order, starts, n_eff):
+                with jax.named_scope("segment_starts"):
+                    live = jnp.arange(capb) < n_eff
+                    seg_ids = K.segment_ids(starts)
+                    ng = jnp.sum(starts).astype(jnp.int32)
+                    start_perm, _cnt = K.compaction_indices(starts)
+                    kidx = start_perm[:Kb]
+                    glive = jnp.arange(Kb) < ng
                 skeys = [K.gather_column(c, order) for c in keys]
                 ok = [K.gather_column(c, kidx, out_valid=glive)
                       for c in skeys]
@@ -1984,8 +2004,7 @@ class TpuHashAggregateExec(TpuExec):
                             sc, seg_ids, live, capb,
                             num_segments=Kb)
                     oa.append(agg_k._mask_to(agg, glive))
-                flat = [a for c in ok + oa for a in c.arrays()]
-                return tuple(flat) + (ng,)
+                return ok, oa, ng
             return jax.jit(fn, donate_argnums=donate)
         fn = _fused_fn(sig + ("sort-mm", cap, Kb, use_mm,
                               ("donate", bool(donate))),
@@ -2088,15 +2107,17 @@ class TpuHashAggregateExec(TpuExec):
                 b = ColumnarBatch.from_flat_arrays(in_schema, arrays,
                                                    num_rows)
                 keys, specs = node._merge_specs(b)
-                if not keys:
-                    aggs = agg_k.reduce_aggregate(specs, num_rows,
-                                                  b.capacity)
-                    out = node._project_results([], aggs, 1)
-                    ng = jnp.int32(1)
-                else:
-                    ok, aggs, ng = agg_k.groupby_aggregate(
-                        keys, specs, num_rows, b.capacity)
-                    out = node._project_results(ok, aggs, ng)
+                with operator_scope(node):
+                    if not keys:
+                        aggs = agg_k.reduce_aggregate(specs, num_rows,
+                                                      b.capacity)
+                        ok, ng = [], jnp.int32(1)
+                    else:
+                        ok, aggs, ng = agg_k.groupby_aggregate(
+                            keys, specs, num_rows, b.capacity)
+                    with jax.named_scope("project"):
+                        out = node._project_results(
+                            ok, aggs, ng if keys else 1)
                 return tuple(out.flat_arrays()) + (ng,)
             return jax.jit(fn, donate_argnums=donate)
 

@@ -705,6 +705,7 @@ def plan_for(session, plan: lp.LogicalPlan):
     the session for EXPLAIN ANALYZE and the result-cache round trip."""
     from .. import config as cfg
     from ..exec.spill import drain_deferred_finalizers
+    from ..exec.tracing import trace_span
     from .overrides import Overrides
     drain_deferred_finalizers()
     st = serving_stats(session)
@@ -737,7 +738,8 @@ def plan_for(session, plan: lp.LogicalPlan):
         if enabled:
             serving["planCache"] = "uncacheable"
         ov = Overrides(session.conf)
-        exec_plan = ov.apply(plan)
+        with trace_span("overrides"):
+            exec_plan = ov.apply(plan)
         session._last_overrides = ov
         st["plansBuilt"] += 1
         return exec_plan, serving
@@ -802,7 +804,8 @@ def plan_for(session, plan: lp.LogicalPlan):
         _inc("tpu_plan_cache_misses_total",
              "parameterized-plan cache misses (full planning pass)")
     ov = Overrides(session.conf)
-    exec_plan = ov.apply(plan)
+    with trace_span("overrides"):
+        exec_plan = ov.apply(plan)
     session._last_overrides = ov
     st["plansBuilt"] += 1
     if not busy:

@@ -40,7 +40,7 @@ from ..columnar.batch import ColumnarBatch
 from ..columnar.column import Scalar
 from ..ops import expressions as ex
 from ..ops import kernels as K
-from ..exec.tracing import trace_span
+from ..exec.tracing import operator_scope, trace_span
 from . import physical as ph
 from .physical import (Partition, TpuExec, _dev_count, _donate_argnums,
                        _donation_consumed, _expr_cache_key, _fused_fn,
@@ -121,24 +121,32 @@ class StageChain:
         no filter — every input row is live). Dead rows keep whatever
         garbage the projections computed for them; consumers mask or
         compact before the values matter."""
+        import jax
         mask = None
         for step in self.steps:
+            # each folded operator under its own scope, as when it ran
+            # as an exec of its own
             if step[0] == "filter":
-                pred = step[1].eval(b)
-                if isinstance(pred, Scalar):
-                    # constant predicate bakes a python bool into the trace:
-                    # permanent per-op fallback, like FusedStage
-                    raise _ScalarPredicate()
-                m = pred.data & pred.validity
-                mask = m if mask is None else (mask & m)
+                with operator_scope("TpuFilterExec"), \
+                        jax.named_scope("filter"):
+                    pred = step[1].eval(b)
+                    if isinstance(pred, Scalar):
+                        # constant predicate bakes a python bool into the
+                        # trace: permanent per-op fallback, like FusedStage
+                        raise _ScalarPredicate()
+                    m = pred.data & pred.validity
+                    mask = m if mask is None else (mask & m)
             else:
                 _tag, exprs, out_schema = step
-                cols = [ex.materialize(e.eval(b), b) for e in exprs]
+                with operator_scope("TpuProjectExec"), \
+                        jax.named_scope("project"):
+                    cols = [ex.materialize(e.eval(b), b) for e in exprs]
                 nb = ColumnarBatch(out_schema, cols, b.num_rows_raw)
                 nb.params = b.params   # later steps' Parameters still read
                 b = nb
         if mask is not None:
-            mask = mask & b.row_mask_raw()
+            with operator_scope("TpuFilterExec"), jax.named_scope("filter"):
+                mask = mask & b.row_mask_raw()
         return b, mask
 
     # -- eager fallback ------------------------------------------------------
@@ -184,7 +192,8 @@ def build_stage_program(chain: StageChain, donate: tuple = ()):
         out, mask = chain.eval_traced(b)
         if not has_filter:
             return tuple(out.flat_arrays())
-        cols, count = K.compact_columns(out.columns, mask)
+        with operator_scope("TpuWholeStageExec"):
+            cols, count = K.compact_columns(out.columns, mask)
         return tuple(a for c in cols for a in c.arrays()) + (count,)
     # lint: naked-jit-ok only ever invoked as a _fused_fn builder (the exec's _build and the compile pool's prewarm replay both route through the funnel)
     return jax.jit(run, donate_argnums=donate)
@@ -282,9 +291,6 @@ class TpuWholeStageExec(TpuExec):
                                                self._stage_args(batch))
                 fn = _fused_fn(key, lambda: self._build(donate))
                 self._fns[bool(donate)] = fn
-            else:
-                # later batches bypass the cache consult (FusedStage note)
-                _recompile.note_call(self._kernel)
             with trace_span("fused_stage"):
                 outs = fn(_dev_count(batch), *batch.flat_arrays(),
                           *ex.param_arg_values(self.chain.params))

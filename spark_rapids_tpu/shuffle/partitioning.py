@@ -43,7 +43,9 @@ def _fused_split_fn(num_partitions: int, cap: int, sig: tuple):
     counts). Rows sort stably by partition id (padding rows last), so
     partition p occupies rows [offsets[p], offsets[p]+counts[p])."""
     import jax
+    from ..exec.tracing import shared_stage
 
+    @shared_stage("shuffle_split")
     def fn(pids, live, *arrays):
         pids = jnp.where(live, pids, num_partitions)      # padding last
         order = jnp.argsort(pids, stable=True)
@@ -66,12 +68,8 @@ def _split_kernel(num_partitions: int, cap: int, arrays: List[jnp.ndarray]):
         # shuffle split compiles ride the recompile audit + persistent
         # compile cache like every _fused_fn program
         from ..exec import compile_cache as _cc
-        _kind, wrap = _cc.note_build(("shuffle_split",) + key,
-                                     "shuffle_split")
+        wrap = _cc.note_build(("shuffle_split",) + key, "shuffle_split")
         fn = _SPLIT_FN_CACHE[key] = wrap(_fused_split_fn(num_partitions, cap, sig))  # lint: unguarded-ok idempotent jit cache: a racing refill rebuilds the same function
-    else:
-        from ..analysis import recompile as _recompile
-        _recompile.note_call("shuffle_split")
     return fn
 
 

@@ -278,3 +278,104 @@ def test_size_class_audit_traces_unbucketed_dims():
     assert recompile.unbucketed_dims(
         ("fam", ("sig",), 1024, (999, 128))) == [999]
     assert recompile.unbucketed_dims(("fam", 512, 8, 2, True)) == []
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 25: the program boundary — names, dispatch counts, XLA's own
+# report of what a build was
+# ---------------------------------------------------------------------------
+
+_BACKEND = "/jax/core/compile/backend_compile_duration"
+_LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_HIT = "/jax/compilation_cache/cache_hits"
+
+
+@pytest.mark.parametrize("key, family, name", [
+    (("agg", "update", "partial", ("k", 1024), 4096, "sort", 8388608),
+     "agg/update/partial/sort", "agg_update_partial_sort"),
+    (("stage", (("a", "float64"),), ("donate", True)), "stage", "stage"),
+    (("mesh", "groupby-v2", 8), "mesh/groupby-v2", "mesh_groupby_v2"),
+    ((7, 9), "anon", "anon"),
+])
+def test_program_name_is_a_function_of_the_keys_tags_alone(key, family,
+                                                           name):
+    """No shape, literal or ``id()`` of the key reaches the name a
+    program compiles under: it is the same in every process."""
+    from spark_rapids_tpu.analysis import recompile
+    from spark_rapids_tpu.exec import compile_cache
+    assert recompile.kernel_of(key) == family
+    assert compile_cache.program_name(family) == name
+    assert name.isidentifier()
+
+
+def test_program_counts_each_call_once_and_leaves_the_breadcrumb(
+        tmp_path, monkeypatch, default_compile_conf):
+    """One count per call, for the audit and for the active query alike;
+    the first call still writes ``SRT_COMPILE_TRACE``'s BEGIN/END pair,
+    later calls none."""
+    import jax
+    import jax.numpy as jnp
+    from spark_rapids_tpu.analysis import recompile
+    from spark_rapids_tpu.exec import compile_cache
+    from spark_rapids_tpu.exec.tracing import SpanRecorder
+    _fresh_session()
+    crumbs = tmp_path / "compile_trace.txt"
+    monkeypatch.setenv("SRT_COMPILE_TRACE", str(crumbs))
+    prog = compile_cache.Program(jax.jit(lambda x: x * 3 + 1),
+                                 "test25/breadcrumb")
+    assert prog._fn.__wrapped__.__name__ == "test25_breadcrumb"
+    base = recompile.snapshot()
+    with SpanRecorder() as rec:
+        for _ in range(3):
+            assert float(prog(jnp.float32(2.0))) == 7.0
+    lines = crumbs.read_text().splitlines()
+    assert [ln.split()[0] for ln in lines] == ["BEGIN", "END"]
+    assert "test25/breadcrumb" in lines[0] and "maps=" in lines[0]
+    ent = recompile.delta(base)["test25/breadcrumb"]
+    assert ent["calls"] == 3 and ent["compiles"] == 0
+    assert ent["coldCompiles"] + ent["diskHits"] == 1     # XLA's count
+    mine = rec.programs["test25/breadcrumb"]
+    assert mine["dispatches"] == 3 and mine["traces"] == 1
+    assert mine["compiles"] + mine["cacheLoads"] == 1
+    assert ent["compileS"] == pytest.approx(
+        mine["traceS"] + mine["lowerS"] + mine["compileS"] + mine["loadS"],
+        abs=1e-3)
+    assert compile_cache.open_family() is None
+
+
+def test_cold_or_disk_is_what_xla_reports(default_compile_conf):
+    """A backend-compile duration after a ``cache_hits`` event on the same
+    thread is a LOAD; without one, a compilation. Each goes to the family
+    whose program is being called, or to ``<eager>:<op>`` — which stays
+    out of the process-wide audit. The side index forecasts, for the
+    compile pool, and classifies nothing."""
+    import jax.monitoring as m
+    from spark_rapids_tpu.analysis import recompile
+    from spark_rapids_tpu.exec import compile_cache
+    from spark_rapids_tpu.exec.tracing import SpanRecorder
+    _fresh_session()
+    assert not hasattr(compile_cache, "classify")
+    assert compile_cache.seen_on_disk(("never", "built")) is False
+
+    def builds(*_):
+        m.record_event_duration_secs(_LOWER, 0.125, fun_name="jit(x)")
+        m.record_event(_HIT)
+        m.record_event_duration_secs(_BACKEND, 0.25, fun_name="jit(x)")
+        m.record_event_duration_secs(_BACKEND, 0.5, fun_name="jit(x)")
+
+    prog = compile_cache.Program(builds, "test25/xla-truth")
+    base = recompile.snapshot()
+    with SpanRecorder() as rec:
+        prog()
+        builds()                      # the same events, no program open
+    ent = recompile.delta(base)["test25/xla-truth"]
+    assert (ent["diskHits"], ent["coldCompiles"], ent["calls"]) == (1, 1, 1)
+    assert ent["compileS"] == pytest.approx(0.875)
+    assert rec.programs["test25/xla-truth"] == {
+        "dispatches": 1, "traces": 0, "traceS": 0.0, "lowerS": 0.125,
+        "compiles": 1, "compileS": 0.5, "cacheLoads": 1, "loadS": 0.25}
+    eager = rec.programs["<eager>:x"]
+    assert (eager["dispatches"], eager["compiles"],
+            eager["cacheLoads"]) == (0, 1, 1)
+    assert "<eager>:x" not in recompile.report()
+    assert rec.report()["<no-span>"]["rebuilds"] == 6

@@ -5,6 +5,8 @@ NVTX ranges (NvtxWithMetrics.scala:27), reproduced as exec-attributed
 metric bags + a text EXPLAIN ANALYZE + trace.json export."""
 
 import json
+import os
+import sys
 import threading
 
 import numpy as np
@@ -332,3 +334,395 @@ def test_preflight_without_a_chip_fails_with_the_probe_error():
     with pytest.raises(RuntimeError, match="not a TPU"):
         from benchmarks.runner import run_benchmark
         run_benchmark(sf=0.0005, query_names=["q6"], iterations=1)
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 25: named programs, scopes inside them, compile events charged
+# from inside the engine, the spans of a whole query
+# ---------------------------------------------------------------------------
+
+_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+_NAMES_CHILD = r"""
+import json, sys
+sys.path.insert(0, sys.argv[1])
+from benchmarks import datagen, queries
+from spark_rapids_tpu.api.session import TpuSession
+from spark_rapids_tpu.api import functions as F
+
+def programs():
+    from spark_rapids_tpu.columnar import batch
+    from spark_rapids_tpu.parallel import mesh
+    from spark_rapids_tpu.plan import physical
+    from spark_rapids_tpu.shuffle import partitioning
+    out = {}
+    for cache in (physical._FUSED_CACHE, batch._UNPACK_CACHE,
+                  partitioning._SPLIT_FN_CACHE, mesh._FN_CACHE):
+        for prog in list(cache.values()):
+            out[prog._family] = prog._fn.__wrapped__.__name__
+    return out
+
+s = TpuSession.builder.config(
+    {"spark.rapids.tpu.sql.explain": "NONE"}).getOrCreate()
+t = datagen.register_tables(s, 0.002)
+for q in (queries.q1, queries.q6, queries.q3):
+    q(t).collect()
+names = programs()
+# one SPMD stage and one shuffle split besides: the mesh group-by
+m = TpuSession.builder.config(
+    {"spark.rapids.tpu.sql.explain": "NONE",
+     "spark.rapids.tpu.sql.mesh.enabled": "true",
+     "spark.rapids.tpu.sql.shuffle.partitions": 4}).getOrCreate()
+df = m.createDataFrame({"k": [i % 7 for i in range(2000)],
+                        "v": [float(i) for i in range(2000)]})
+df.groupBy("k").agg(F.sum("v").alias("s")).collect()
+df.repartition(4, "k").collect()
+names.update(programs())
+print(json.dumps(names, sort_keys=True))
+"""
+
+
+def _names_child(hash_seed):
+    import subprocess
+    env = {**os.environ, "JAX_PLATFORMS": "cpu", "PYTHONHASHSEED": hash_seed,
+           "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
+           "JAX_ENABLE_COMPILATION_CACHE": "false"}
+    return subprocess.Popen(
+        [sys.executable, "-c", _NAMES_CHILD, _ROOT], env=env, cwd=_ROOT,
+        stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True)
+
+
+def test_every_program_compiles_under_its_family_name_in_every_process():
+    """q1, q6 and q3 (plus one mesh stage and one shuffle split): every
+    program a cache hands out is named after its kernel family — never
+    the builder's ``fn`` / ``run`` / ``unpack`` — and a process with
+    another hash seed arrives at the same names (the persistent compile
+    cache keys on them)."""
+    import re
+    children = [_names_child("1"), _names_child("2")]
+    outs = []
+    for c in children:
+        out, err = c.communicate(timeout=600)
+        assert c.returncode == 0, err[-2000:]
+        outs.append(json.loads(out.strip().splitlines()[-1]))
+    first, second = outs
+    assert first == second
+    assert len(first) >= 6, first
+    assert "scan_unpack" in first and any(
+        f.startswith("mesh/") for f in first), sorted(first)
+    for family, name in first.items():
+        assert name not in ("fn", "run", "unpack", "per_worker",
+                            "run_project", "run_filter"), (family, name)
+        assert name == re.sub(r"\W", "_", family)
+        assert re.fullmatch(r"[A-Za-z_]\w*", name), name
+
+
+def _tiny_q1(session, rows=3000):
+    from benchmarks import datagen, queries
+    t = datagen.register_tables(session, rows / datagen.LINEITEM_PER_SF)
+    return queries.q1(t)
+
+
+def _group_by_program_text(conf):
+    """The lowered text (with debug info) of q1's group-by update
+    programs, as the session under ``conf`` builds them."""
+    from spark_rapids_tpu.plan import physical as ph
+    calls = {}
+    orig = ph._fused_fn
+
+    def recording(key, builder):
+        prog = orig(key, builder)
+
+        def call(*args):
+            if key not in calls:
+                import jax
+                calls[key] = (prog, [
+                    jax.ShapeDtypeStruct(a.shape, a.dtype)
+                    if hasattr(a, "shape") else a for a in args])
+            return prog(*args)
+        return call
+
+    ph._fused_fn = recording
+    try:
+        _tiny_q1(_session(**conf)).collect()
+    finally:
+        ph._fused_fn = orig
+    texts = {}
+    for key, (prog, structs) in calls.items():
+        if prog._family.startswith("agg/update"):
+            texts[prog._family] = prog._fn.lower(*structs).as_text(
+                debug_info=True)
+    assert texts, sorted(p._family for p, _ in calls.values())
+    return texts
+
+
+@pytest.mark.parametrize("matmul, present, absent", [
+    ("false", ("lexsort", "gather", "segment_starts",
+               "segment_sum_scatter"), ("segment_sum_matmul",)),
+    # what ``auto`` picks on an accelerator; forced, on this CPU
+    ("true", ("lexsort", "gather", "segment_starts",
+              "segment_sum_matmul"), ()),
+])
+def test_group_by_program_names_its_stages_under_the_operator(
+        monkeypatch, matmul, present, absent):
+    """q1's group-by: every stage of the vocabulary it runs lies under
+    ``TpuHashAggregateExec`` in the lowered program's op names, and the
+    scatter and the matmul segment sums carry DIFFERENT names (the conf
+    is read from the environment: PERF.md section 7 row 2)."""
+    from spark_rapids_tpu.exec.tracing import STAGES
+    monkeypatch.setenv(
+        "SPARK_RAPIDS_TPU_CONF__SPARK__RAPIDS__TPU__SQL__AGG__MATMUL"
+        "__ENABLED", matmul)
+    text = "\n".join(_group_by_program_text({}).values())
+    for stage in present:
+        assert stage in STAGES
+        assert f"/TpuHashAggregateExec/{stage}/" in text, stage
+    for stage in absent:
+        assert f"/{stage}/" not in text, stage
+    # the folded filter and projection keep their own operators' scopes
+    assert "/TpuFilterExec/filter/" in text
+    assert "jit(agg_update_" in text
+
+
+class _OutsideListener:
+    """What an independent ``jax.monitoring`` listener counts: top-level
+    traces, backend compilations and loads from the persistent cache."""
+
+    TRACE = "/jax/core/compile/jaxpr_trace_duration"
+    BACKEND = "/jax/core/compile/backend_compile_duration"
+    HIT = "/jax/compilation_cache/cache_hits"
+
+    def __init__(self):
+        self.tls = threading.local()
+        self.traces = self.builds = self.loads = 0
+        self.build_s = 0.0
+
+    def __enter__(self):
+        import jax.monitoring as m
+        m.register_event_listener(self.on_event)
+        m.register_scalar_listener(self.on_scalar)
+        m.register_event_duration_secs_listener(self.on_duration)
+        return self
+
+    def __exit__(self, *exc):
+        import jax.monitoring as m
+        m.unregister_event_listener(self.on_event)
+        m.unregister_scalar_listener(self.on_scalar)
+        m.unregister_event_duration_listener(self.on_duration)
+
+    def on_event(self, event, **_):
+        if event == self.HIT:
+            self.tls.hit = True
+
+    def on_scalar(self, event, _value, **_):
+        if event == self.TRACE:
+            self.tls.depth = getattr(self.tls, "depth", 0) + 1
+
+    def on_duration(self, event, seconds, **_):
+        if event == self.TRACE:
+            self.tls.depth -= 1
+            if self.tls.depth == 0:
+                self.traces += 1
+        elif event == self.BACKEND:
+            if getattr(self.tls, "hit", False):
+                self.loads += 1
+            else:
+                self.builds += 1
+                self.build_s += seconds
+            self.tls.hit = False
+
+
+def _program_totals(programs):
+    return {f: sum(p[f] for p in programs.values())
+            for f in ("dispatches", "traces", "compiles", "cacheLoads")}
+
+
+def test_programs_map_agrees_with_an_outside_listener():
+    """``last_query_metrics()["programs"]`` — present with no query
+    listener registered — counts the builds and loads an independent
+    listener counts; its traces are the outside count less the helpers
+    traced while a program lowers; a repeat rebuilds no program of the
+    funnel; an op outside it lands under ``<eager>:``."""
+    s = _session()
+    assert not s._query_listeners
+    rng = np.random.default_rng(25)
+    df = s.createDataFrame(pd.DataFrame({
+        "k": rng.integers(0, 5, 700), "v": rng.normal(0, 1, 700)}))
+    # literals unique to this test: the fused cache is process-wide
+    q = (df.filter(F.col("v") > -7.03125).groupBy("k")
+         .agg(F.sum(F.col("v") * 1.40625).alias("s")).orderBy("k"))
+    with _OutsideListener() as outside:
+        q.collect()
+    programs = s.last_query_metrics()["programs"]
+    totals = _program_totals(programs)
+    assert totals["compiles"] == outside.builds > 0
+    assert totals["cacheLoads"] == outside.loads
+    assert 0 < totals["traces"] <= outside.traces
+    assert totals["dispatches"] >= 2
+    funnel = {k: v for k, v in programs.items()
+              if not k.startswith("<eager>:")}
+    built = {k for k, p in funnel.items() if p["traces"]}
+    assert any(k.startswith("agg/update") for k in built), funnel
+    for family, p in funnel.items():
+        assert p["dispatches"] >= 1, family
+        # one trace, one lowering, one build (or load) per new program;
+        # a program an earlier query built (the scan unpack) costs none
+        assert p["traces"] == p["compiles"] + p["cacheLoads"], (family, p)
+        assert (p["traceS"] > 0 and p["lowerS"] > 0) == (family in built)
+    # the final ORDER BY of a few rows runs as eager jnp ops
+    eager = {k: v for k, v in programs.items() if k.startswith("<eager>:")}
+    assert eager and all(p["dispatches"] == 0 for p in eager.values())
+    compile_s = sum(p["compileS"] for p in programs.values())
+    # (each entry is reported rounded to the microsecond)
+    assert compile_s == pytest.approx(outside.build_s, abs=1e-4)
+    # the same text again: every program is there already
+    with _OutsideListener() as again:
+        q.collect()
+    repeat = s.last_query_metrics()["programs"]
+    # (the repeat reads the table from the device scan cache: no unpack)
+    again_funnel = set(funnel) - {"scan_unpack"}
+    assert {k for k in repeat if not k.startswith("<eager>:")} == \
+        again_funnel
+    assert all(repeat[k]["dispatches"] == funnel[k]["dispatches"]
+               and repeat[k]["traces"] == repeat[k]["compiles"] == 0
+               for k in again_funnel)
+    # what is still rebuilt is named: an eager op of the final sort
+    # (its fori_loop is a fresh function, so a fresh build, every query)
+    assert _program_totals(repeat)["compiles"] == again.builds
+    assert {k for k, p in repeat.items() if p["compiles"]} <= \
+        {"<eager>:scan"}
+
+
+def test_listener_recompiles_come_from_the_query_programs_map():
+    """``QueryExecution.recompiles`` keeps ``recompile.delta``'s shape,
+    filled from the query's own ``programs`` map."""
+    s = _session()
+    got = []
+    s.register_query_listener(got.append)
+    try:
+        df = s.createDataFrame(pd.DataFrame(
+            {"k": [1, 2, 1, 3] * 40, "v": [1.5, 2.5, 3.5, 4.5] * 40}))
+        df.groupBy("k").agg(F.max("v").alias("m")).collect()
+    finally:
+        s.unregister_query_listener(got.append)
+    (qe,) = got
+    programs = s.last_query_metrics()["programs"]
+    assert set(qe.recompiles) == {
+        k for k in programs if not k.startswith("<eager>:")}
+    for family, ent in qe.recompiles.items():
+        p = programs[family]
+        assert set(ent) == {"compiles", "calls", "coldCompiles",
+                            "diskHits", "compileS"}
+        assert ent["calls"] == p["dispatches"]
+        assert ent["compiles"] == p["compiles"] + p["cacheLoads"]
+
+
+def test_spans_cover_the_whole_query_and_name_their_parents():
+    """The root span ``query`` opens before planning; ``plan`` and the
+    result's ``fetch_to_host`` are spans of the same query; every event
+    carries the span that caused it and the query's id; the blocking
+    readbacks are timed."""
+    s = _session(**{"spark.rapids.tpu.sql.tracing.timeline": "true"})
+    try:
+        df = s.createDataFrame(pd.DataFrame(
+            {"k": [1, 2, 1, 3] * 64, "v": [1., 2., 3., 4.] * 64}))
+        batch = df.groupBy("k").agg(F.sum("v").alias("sv")) \
+            .orderBy("k").collect_batch()
+        before = s.last_query_metrics()["spans"]
+        assert "fetch_to_host" not in before
+        assert batch.fetch_to_host().num_rows == 3
+        m = s.last_query_metrics()
+        spans = m["spans"]
+        for name in ("query", "plan", "analyze", "plan_cache",
+                     "overrides", "fetch_to_host"):
+            assert spans[name]["count"] == 1, name
+        assert spans["wallS"] >= before["wallS"]
+        assert m["planTimeS"] > 0
+        rec = s._last_span_recorder
+        qid = s._last_query_id
+        events = [e for e in rec.chrome_trace()["traceEvents"]
+                  if e["ph"] == "X"]
+        by_name = {e["name"]: e for e in events}
+        assert all(e["args"]["query"] == qid for e in events)
+        assert "parent" not in by_name["query"]["args"]
+        assert by_name["plan"]["args"]["parent"] == "query"
+        assert by_name["analyze"]["args"]["parent"] == "plan"
+        assert by_name["overrides"]["args"]["parent"] == "plan_cache"
+        assert by_name["fetch_to_host"]["args"]["parent"] == "query"
+        assert all(e["args"].get("parent") for e in events
+                   if e["name"] != "query")
+        # the compile events were charged to the span they fell under
+        assert sum(v.get("rebuilds", 0) for v in spans.values()
+                   if isinstance(v, dict)) > 0
+        sync = m["sync"]
+        assert sync["hostSyncs"] > 0 and sync["syncWaitS"] > 0
+        assert sum(sync["syncSpanWaitS"].values()) == pytest.approx(
+            sync["syncWaitS"], abs=1e-4)
+        assert set(sync["syncSiteWaitS"]) == set(sync["syncSites"])
+    finally:
+        from spark_rapids_tpu.exec import tracing
+        tracing.reset_cache()
+
+
+def _synced_query(s):
+    """A group-by whose ORDER BY reads a row count back: programs, spans
+    and at least one blocking readback."""
+    df = s.createDataFrame(pd.DataFrame(
+        {"k": [1, 2, 1, 3] * 64, "v": [1., 2., 3., 4.] * 64}))
+    return df.groupBy("k").agg(F.sum("v").alias("sv")).orderBy("k")
+
+
+def _clean_tracing_env(monkeypatch):
+    monkeypatch.delenv(
+        "SPARK_RAPIDS_TPU_CONF__SPARK__RAPIDS__TPU__SQL__TRACING__ENABLED",
+        raising=False)
+
+
+def test_tracing_off_constructs_no_profiler_annotation(monkeypatch):
+    """With ``tracing.enabled`` false a query builds not one
+    ``TraceAnnotation``: programs, spans and readbacks cost no profiler
+    call."""
+    import jax
+    _clean_tracing_env(monkeypatch)
+
+    def refuse(*a, **k):
+        raise AssertionError(f"TraceAnnotation{a} with tracing off")
+
+    s = _session()
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", refuse)
+    assert len(_synced_query(s).collect()) == 3
+    assert s.last_query_metrics()["sync"]["hostSyncs"] > 0
+
+
+def test_session_conf_turns_the_annotations_on(monkeypatch):
+    """``.config({"...tracing.enabled": "true"})`` with a clean
+    environment is honoured: spans, program calls and readbacks are
+    profiler annotations that carry the query's id (the root span and
+    ``plan`` open before the plan, and so the id, exist)."""
+    import contextlib
+    import jax
+    from spark_rapids_tpu.exec import tracing
+    _clean_tracing_env(monkeypatch)
+    seen = []
+
+    def annotation(name, **kw):
+        seen.append((name, kw))
+        return contextlib.nullcontext()
+
+    s = _session(**{"spark.rapids.tpu.sql.tracing.enabled": "true"})
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", annotation)
+    try:
+        _synced_query(s).collect()
+        qid = s._last_query_id
+    finally:
+        _session()                      # a default session: tracing off
+        tracing.reset_cache()
+    names = [n for n, _ in seen]
+    assert names[0] == "query" and "plan" in names
+    assert "fetch_to_host" in names and "host_sync" in names
+    programs = [(n, kw) for n, kw in seen if n.startswith("program:")]
+    assert programs and all(kw["query"] == qid for _, kw in programs)
+    assert any(kw.get("op", "").startswith("Tpu") for _, kw in programs)
+    late = names.index("plan") + 1
+    assert all(kw.get("query") == qid for n, kw in seen[late:]
+               if n not in ("analyze", "plan_cache", "overrides"))

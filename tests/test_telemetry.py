@@ -160,12 +160,31 @@ def test_watermark_peak_monotonic_and_operator_attribution():
     tel.reset_watermarks()
 
 
-def test_q3_join_drives_device_watermark_with_attribution():
+@pytest.fixture
+def empty_catalog():
+    """The spill catalog is process-wide, and the batches of an earlier
+    file's LAST session stay registered until a new session takes its
+    place (its views die, their finalizers drain). Left to this test's
+    own first query, that release came AFTER the watermark reset: the
+    first update, outside any exec, stood at the leftovers' height, the
+    join's own registrations never passed it, and no operator owned the
+    peak (tests/test_pipeline_window.py before this file, in one
+    process: 354 816 bytes left, peak 336 384 with no operator). Let go
+    of the leftovers first."""
+    import gc
+    from spark_rapids_tpu.exec.spill import drain_deferred_finalizers
+    _session()
+    gc.collect()
+    drain_deferred_finalizers()
+    yield
+    tel.reset_watermarks()
+
+
+def test_q3_join_drives_device_watermark_with_attribution(empty_catalog):
     """End to end under the q3-shaped 3-way join: batch registration in
     the spill catalog moves the device watermark, the peak is monotone
     vs current, and the peak carries an operator attribution (the open
     exec scope at registration time)."""
-    tel.reset_watermarks()
     s = _session(**{"spark.rapids.tpu.sql.reader.batchSizeRows": 1024})
     _q3_tables(s)
     rows = s.sql(T_Q3).collect()
