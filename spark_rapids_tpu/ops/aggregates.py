@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from ..columnar import dtypes as dt
-from ..columnar.column import Column
+from ..columnar.column import Column, build_column
 from ..exec.tracing import stage
 from . import kernels as K
 
@@ -53,25 +53,94 @@ def result_dtype(op: str, in_dtype: Optional[dt.DType]) -> dt.DType:
 # ---------------------------------------------------------------------------
 # Segment reductions (update phase)
 # ---------------------------------------------------------------------------
+#
+# What the chip showed (TPU v5e, one 8 Mi-row batch; PERF.md sections 5-6):
+# ``jax.ops.segment_sum`` is an index sort plus a scatter-add, and a 64-bit
+# scatter-ADD (float64 and int64 are carried as 32-bit lanes) serialises at
+# ~120 ns a row: 0.76-1.07 s a column, however few slots are filled. An
+# int32 one takes 73 ms and a unique-index scatter 41 ms. A reduction does
+# not serialise, so where the groups are few each group's value is a masked
+# reduction over the whole batch, in the column's own dtype.
 
-def _seg_sum(data, seg_ids, num_segments):
-    return jax.ops.segment_sum(data, seg_ids, num_segments=num_segments)
+#: Most groups for which a segment reduction is per-group masked reductions
+#: (one pass over the rows for each group) and not a scatter. One pass over
+#: 8 Mi rows reads 0.14 ms a group in float64, 0.10 in int32 and 0.06 in
+#: int64 on a v5e, so 128 groups cost 18 / 12.5 / 7 ms where the scatter
+#: costs 800 / 74-83 ms whatever the count (builder's chip run, PR 26); the
+#: curves cross near 700 groups for int32 and 5 000 for float64.
+FEW_GROUPS_MAX = 128
 
 
-def _seg_min(data, seg_ids, num_segments):
-    return jax.ops.segment_min(data, seg_ids, num_segments=num_segments)
+class _Segs(NamedTuple):
+    """Where the rows of a batch reduce to: ``ids`` is int32[capacity], the
+    group of each row in ANY row order; ``num`` the output slots (static);
+    ``n_groups`` a device count of the groups present where the caller has
+    one and it is known to be <= ``num`` (slots beyond it are left empty)."""
+    ids: jnp.ndarray
+    num: int
+    n_groups: Optional[jnp.ndarray] = None
 
 
-def _seg_max(data, seg_ids, num_segments):
-    return jax.ops.segment_max(data, seg_ids, num_segments=num_segments)
+_SCATTER = {"sum": jax.ops.segment_sum, "min": jax.ops.segment_min,
+            "max": jax.ops.segment_max}
+# dtype: jnp.sum alone would widen an int32 count, segment_sum does not
+_REDUCE = {"sum": lambda x: jnp.sum(x, dtype=x.dtype), "min": jnp.min,
+           "max": jnp.max}
+
+
+def _identity(kind: str, dtype):
+    """What ``jax.ops.segment_<kind>`` leaves in a segment with no row."""
+    if kind == "sum":
+        return jnp.zeros((), dtype)
+    top = kind == "min"
+    if jnp.issubdtype(dtype, jnp.floating):
+        return jnp.asarray(jnp.inf if top else -jnp.inf, dtype)
+    info = jnp.iinfo(dtype)
+    return jnp.asarray(info.max if top else info.min, dtype)
+
+
+def _masked_segment_reduce(kind: str, data, segs: _Segs):
+    """``segs.num`` slots, slot g = reduce(where(ids == g, data, identity)):
+    no scatter, exact in ``data``'s dtype (a tree of adds, not a chain), rows
+    in any order. ``where`` and not a product, so a NaN or an inf stays in
+    its own group. A loop of one pass over the rows per group, over the
+    groups PRESENT where the count is on the device, so nothing of
+    ``num`` x rows is ever held."""
+    ident = _identity(kind, data.dtype)
+    slots = jnp.arange(segs.num, dtype=jnp.int32)
+
+    def one(g, acc):
+        r = _REDUCE[kind](jnp.where(segs.ids == g, data, ident))
+        return jnp.where(slots == g, r, acc)
+
+    return jax.lax.fori_loop(
+        0, segs.num if segs.n_groups is None else segs.n_groups, one,
+        jnp.full((segs.num,), ident))
+
+
+def _seg_reduce(kind: str, data, segs: _Segs):
+    if segs.num <= FEW_GROUPS_MAX:
+        return _masked_segment_reduce(kind, data, segs)
+    return _SCATTER[kind](data, segs.ids, num_segments=segs.num)
+
+
+def _seg_sum(data, segs: _Segs):
+    return _seg_reduce("sum", data, segs)
+
+
+def _seg_min(data, segs: _Segs):
+    return _seg_reduce("min", data, segs)
+
+
+def _seg_max(data, segs: _Segs):
+    return _seg_reduce("max", data, segs)
 
 
 def _masked(data, mask, fill):
     return jnp.where(mask, data, jnp.asarray(fill, data.dtype))
 
 
-def _string_ordinal_minmax(col: Column, contrib, seg_ids, num_segments: int,
-                           want_min: bool):
+def _string_ordinal_minmax(col: Column, contrib, segs: _Segs, want_min: bool):
     """Min/max for strings: reduce over the *row index* ordered by the encoded
     string key, then gather the winning row's bytes."""
     cap = col.capacity
@@ -84,8 +153,7 @@ def _string_ordinal_minmax(col: Column, contrib, seg_ids, num_segments: int,
         jnp.arange(cap, dtype=jnp.int32))
     sentinel = jnp.int32(cap) if want_min else jnp.int32(-1)
     r = jnp.where(contrib, rank, sentinel)
-    red = _seg_min(r, seg_ids, num_segments) if want_min else \
-        _seg_max(r, seg_ids, num_segments)
+    red = _seg_min(r, segs) if want_min else _seg_max(r, segs)
     has = red != sentinel
     win_rank = jnp.where(has, red, 0)
     # rank -> row index
@@ -94,69 +162,77 @@ def _string_ordinal_minmax(col: Column, contrib, seg_ids, num_segments: int,
 
 
 def segment_aggregate(spec: AggSpec, seg_ids: jnp.ndarray, live: jnp.ndarray,
-                      capacity: int,
-                      num_segments: Optional[int] = None) -> Column:
+                      capacity: int, num_segments: Optional[int] = None,
+                      n_groups=None) -> Column:
     """Update-phase aggregation: reduce each segment of input rows to one output
     row per group id. Output column has ``num_segments`` slots (group g at
     slot g; defaults to ``capacity`` for the sort-based path where segment ids
     live in row space); slots beyond the group count are zeroed+invalid by
     construction because no row contributes to them.
+
+    At ``num_segments`` <= ``FEW_GROUPS_MAX`` no scatter is emitted: every
+    reduction is per-group masked reductions, over the groups present where
+    ``n_groups`` (a device count, <= ``num_segments``) says how many.
     """
-    # the scatter implementation of a segment reduction, named apart
-    # from the matmul and dense ones (exec/tracing.STAGES)
-    name = "segment_minmax" if spec.op in ("min", "max", "first", "last") \
-        else "segment_sum_scatter"
-    with jax.named_scope(name):
-        return _segment_aggregate(spec, seg_ids, live, capacity,
-                                  num_segments)
-
-
-def _segment_aggregate(spec: AggSpec, seg_ids: jnp.ndarray, live: jnp.ndarray,
-                       capacity: int, num_segments: Optional[int]) -> Column:
     ns = capacity if num_segments is None else num_segments
+    # the implementations of a segment reduction, named apart
+    # (exec/tracing.STAGES)
+    if spec.op in ("min", "max", "first", "last"):
+        name = "segment_minmax"
+    elif ns <= FEW_GROUPS_MAX:
+        name = "segment_sum_masked"
+    else:
+        name = "segment_sum_scatter"
+    with jax.named_scope(name):
+        return _segment_aggregate(spec, _Segs(seg_ids, ns, n_groups), live,
+                                  capacity)
+
+
+def _segment_aggregate(spec: AggSpec, segs: _Segs, live: jnp.ndarray,
+                       capacity: int) -> Column:
     op = spec.op
     if op == "count_star":
-        data = _seg_sum(live.astype(jnp.int64), seg_ids, ns)
-        valid = _seg_sum(live.astype(jnp.int32), seg_ids, ns) > 0
+        data = _seg_sum(live.astype(jnp.int64), segs)
+        valid = _seg_sum(live.astype(jnp.int32), segs) > 0
         return Column(dt.INT64, data, valid)
 
     col = spec.column
     contrib = live & col.validity
     if op == "count":
-        data = _seg_sum(contrib.astype(jnp.int64), seg_ids, ns)
-        valid = _seg_sum(live.astype(jnp.int32), seg_ids, ns) > 0
+        data = _seg_sum(contrib.astype(jnp.int64), segs)
+        valid = _seg_sum(live.astype(jnp.int32), segs) > 0
         return Column(dt.INT64, data, valid)
 
-    group_has = _seg_sum(contrib.astype(jnp.int32), seg_ids, ns) > 0
+    group_has = _seg_sum(contrib.astype(jnp.int32), segs) > 0
 
     if op == "sum":
         out_t = _sum_dtype(col.dtype)
         d = _masked(col.data.astype(out_t.numpy_dtype), contrib, 0)
-        data = _seg_sum(d, seg_ids, ns)
+        data = _seg_sum(d, segs)
         return Column(out_t, _masked(data, group_has, 0), group_has)
 
     if op == "avg":
         d = _masked(col.data.astype(jnp.float64), contrib, 0.0)
-        s = _seg_sum(d, seg_ids, ns)
-        c = _seg_sum(contrib.astype(jnp.float64), seg_ids, ns)
+        s = _seg_sum(d, segs)
+        c = _seg_sum(contrib.astype(jnp.float64), segs)
         data = jnp.where(group_has, s / jnp.maximum(c, 1.0), 0.0)
         return Column(dt.FLOAT64, data, group_has)
 
     if op in ("min", "max"):
         if col.dtype == dt.STRING:
-            win_row, has = _string_ordinal_minmax(col, contrib, seg_ids, ns,
+            win_row, has = _string_ordinal_minmax(col, contrib, segs,
                                                   want_min=(op == "min"))
             out = K.gather_column(col, win_row, out_valid=has)
             return out
         if col.dtype.is_floating:
             # Spark total order: NaN largest. Use +/-inf fill, restore NaN via flags.
             is_nan = jnp.isnan(col.data) & contrib
-            seg_nan = _seg_sum(is_nan.astype(jnp.int32), seg_ids, ns) > 0
+            seg_nan = _seg_sum(is_nan.astype(jnp.int32), segs) > 0
             seg_non_nan = _seg_sum((contrib & ~is_nan).astype(jnp.int32),
-                                   seg_ids, ns) > 0
+                                   segs) > 0
             fill = jnp.inf if op == "min" else -jnp.inf
             d = _masked(col.data, contrib & ~is_nan, fill)
-            red = (_seg_min if op == "min" else _seg_max)(d, seg_ids, ns)
+            red = (_seg_min if op == "min" else _seg_max)(d, segs)
             if op == "min":
                 data = jnp.where(seg_non_nan, red, jnp.nan)  # all-NaN group -> NaN
             else:
@@ -165,25 +241,25 @@ def _segment_aggregate(spec: AggSpec, seg_ids: jnp.ndarray, live: jnp.ndarray,
             return Column(col.dtype, data, group_has)
         if col.dtype == dt.BOOL:
             d = _masked(col.data.astype(jnp.int32), contrib, 1 if op == "min" else 0)
-            red = (_seg_min if op == "min" else _seg_max)(d, seg_ids, ns)
+            red = (_seg_min if op == "min" else _seg_max)(d, segs)
             data = (red > 0) & group_has
             return Column(dt.BOOL, data, group_has)
         info = jnp.iinfo(col.data.dtype)
         fill = info.max if op == "min" else info.min
         d = _masked(col.data, contrib, fill)
-        red = (_seg_min if op == "min" else _seg_max)(d, seg_ids, ns)
+        red = (_seg_min if op == "min" else _seg_max)(d, segs)
         return Column(col.dtype, _masked(red, group_has, 0), group_has)
 
     if op in ("first", "last"):
         idx = jnp.arange(capacity, dtype=jnp.int32)
         pick_from = contrib if spec.ignore_nulls else live
-        grp_has = _seg_sum(pick_from.astype(jnp.int32), seg_ids, ns) > 0
+        grp_has = _seg_sum(pick_from.astype(jnp.int32), segs) > 0
         if op == "first":
             r = jnp.where(pick_from, idx, capacity)
-            win = _seg_min(r, seg_ids, ns)
+            win = _seg_min(r, segs)
         else:
             r = jnp.where(pick_from, idx, -1)
-            win = _seg_max(r, seg_ids, ns)
+            win = _seg_max(r, segs)
         win = jnp.clip(win, 0, capacity - 1)
         return K.gather_column(col, win, out_valid=grp_has)
 
@@ -224,16 +300,34 @@ def groupby_aggregate(key_cols: Sequence[Column], specs: Sequence[AggSpec],
     out_keys = [K.gather_column(c, start_perm, out_valid=group_live)
                 for c in sorted_keys]
 
+    # The choice the data makes, on the device: few groups take no scatter
+    # (the 64-bit scatter-add serialises on a TPU, see the top of the file).
+    # One ``cond`` per aggregate, each on the same count: one round the whole
+    # loop keeps every sorted input alive at once (1.2 GB of temporaries at
+    # 8 Mi rows where this takes 0.55 GB, as the parent did), and the gather
+    # stays outside it (inside a branch it ran 35% slower on the chip).
+    few = min(capacity, FEW_GROUPS_MAX)
+
+    def reduce_into(s: AggSpec, num_segments: int, known_groups=None):
+        agg = segment_aggregate(s, seg_ids, live, capacity, num_segments,
+                                known_groups)
+        # mask agg slots beyond the group count (paranoia: segment ids of
+        # padding rows alias the last group, which is a real group, so data
+        # is fine; but enforce the padding invariant explicitly)
+        return _mask_to(_pad_slots(agg, capacity), group_live).arrays()
+
     out_aggs: List[Column] = []
     for spec in specs:
         s = spec
         if spec.column is not None:
             s = spec._replace(column=K.gather_column(spec.column, order))
-        agg = segment_aggregate(s, seg_ids, live, capacity)
-        # mask agg slots beyond the group count (paranoia: segment ids of padding
-        # rows alias the last group, which is a real group, so data is fine; but
-        # enforce the padding invariant explicitly)
-        out_aggs.append(_mask_to(agg, group_live))
+        if few == capacity:         # the smallest bucket: nothing to choose
+            arrays = reduce_into(s, few, n_groups)
+        else:
+            arrays = jax.lax.cond(n_groups <= few,
+                                  lambda: reduce_into(s, few, n_groups),
+                                  lambda: reduce_into(s, capacity))
+        out_aggs.append(build_column(_agg_dtype(spec), arrays)[0])
     return out_keys, out_aggs, n_groups
 
 
@@ -247,10 +341,10 @@ def reduce_aggregate(specs: Sequence[AggSpec], num_rows, capacity: int,
     Empty input: count = 0, everything else NULL (aggregate.scala:487-505
     empty-input reduction semantics). ``live_mask`` replaces the prefix
     row mask for folded-filter inputs (no compaction needed at all here).
-    Internally this is ``segment_aggregate`` with ONE segment — a 1-slot
-    segment reduction lowers to a plain masked reduce, not the
-    full-capacity segment machinery the sort path needs (which cost
-    ~100 ms per 1M-row batch here, ~100x the actual reduction).
+    Internally this is ``segment_aggregate`` with ONE segment, which is a
+    masked reduce and no scatter (``FEW_GROUPS_MAX``): ``jax.ops.segment_sum``
+    into one slot is, on the TPU, a scatter-add of every row into that slot
+    (929 ms of q6's 1004 ms busy at SF1; 7 ms as a reduce).
     """
     seg_ids = jnp.zeros(capacity, dtype=jnp.int32)
     live = live_mask if live_mask is not None \
@@ -259,16 +353,8 @@ def reduce_aggregate(specs: Sequence[AggSpec], num_rows, capacity: int,
     out: List[Column] = []
     one = jnp.arange(out_cap) < 1
     for spec in specs:
-        agg = segment_aggregate(spec, seg_ids, live, capacity,
-                                num_segments=1)
-        pad = out_cap - 1
-        if agg.dtype.var_width:
-            agg = Column(agg.dtype, jnp.pad(agg.data, ((0, pad), (0, 0))),
-                         jnp.pad(agg.validity, (0, pad)),
-                         jnp.pad(agg.lengths, (0, pad)))
-        else:
-            agg = Column(agg.dtype, jnp.pad(agg.data, (0, pad)),
-                         jnp.pad(agg.validity, (0, pad)))
+        agg = _pad_slots(segment_aggregate(spec, seg_ids, live, capacity,
+                                           num_segments=1), out_cap)
         if spec.op in ("count", "count_star"):
             # count of empty input is 0 (valid), not NULL
             data = jnp.where(one, agg.data, 0)
@@ -282,14 +368,20 @@ def reduce_aggregate(specs: Sequence[AggSpec], num_rows, capacity: int,
 # MXU fast path: one-hot matmul segment reductions (TPU-native)
 # ---------------------------------------------------------------------------
 #
-# Scatter-based segment_sum is the slowest primitive on TPU (random HBM
-# writes); the systolic array is the fastest. For bounded group counts the
-# reduction is a matmul: sum_g = one_hot(seg_ids, K)^T @ values, generated
-# on the fly and fed to the MXU. float64 values ride a hi/lo float32 split
-# with chunked float64 accumulation (~1e-5 rel — inside the reference's own
-# benchmark epsilon, BenchUtils.compareResults epsilon=1e-4, and the spirit
-# of its variableFloatAgg conf). Counts are exact (integer sums < 2^24 per
-# chunk are exact in f32, chunk totals accumulate in f64).
+# The scatter-ADD behind ``jax.ops.segment_sum`` serialises on the TPU (0.8 s
+# for one float64 column of 8 Mi rows whatever the slot count, 74-83 ms for
+# an int32 one; see the top of the file); the systolic array is the fastest
+# unit. For bounded group counts the reduction is a matmul: sum_g =
+# one_hot(seg_ids, K)^T @ values, generated on the fly and fed to the MXU.
+# float64 values ride a hi/lo float32 split with chunked float64
+# accumulation: NOT exact — 1.1e-07-2.3e-07 relative on q1's sums at SF1 on
+# the chip (PERF.md section 2), inside the reference's own benchmark epsilon
+# (BenchUtils.compareResults epsilon=1e-4) and the spirit of its
+# variableFloatAgg conf, outside TPC-H's $100. Where sums must be exact and
+# the groups are few, the masked reductions above are both exact and faster
+# (q1: 0.006 s against this path's whole group-by at 4.0 s). Counts are
+# exact (integer sums < 2^24 per chunk are exact in f32, chunk totals
+# accumulate in f64).
 
 MATMUL_MAX_GROUPS = 4096
 _MM_CHUNK = 1 << 17
@@ -378,7 +470,8 @@ def segment_aggregate_matmul(spec: AggSpec, seg_ids: jnp.ndarray,
 # dispatch checks and falls back). Integer sums are bit-exact: 16 nibble
 # planes per i64, each plane's per-chunk f32 sum <= 15 * 2^17 < 2^24,
 # recombined with shifts in i64 (wraparound = Spark bigint overflow).
-# min/max/first/last use K-sized segment scatters (cheap at dense K).
+# min/max/first/last are K-slot segment reductions: masked ones up to
+# FEW_GROUPS_MAX slots, K-sized scatters beyond.
 
 DENSE_MAX_SLOTS = 4096
 _DENSE_CHUNK = 1 << 17
@@ -538,8 +631,8 @@ def groupby_dense(key_col: Column, specs: Sequence[AggSpec], num_rows,
         contrib = live & col.validity
         cid = id(col.data)
         if op in ("min", "max", "first", "last"):
-            # scatter segment reductions are cheap at dense K; reuse the
-            # canonical Spark semantics (NaN total order, sentinels, nulls)
+            # K-slot segment reductions; reuse the canonical Spark
+            # semantics (NaN total order, sentinels, nulls)
             plans.append(("done", segment_aggregate(spec, seg, live, cap,
                                                     num_segments=K_slots)))
             continue
@@ -756,6 +849,21 @@ def groupby_aggregate_fast(key_cols: Sequence[Column], specs: Sequence[AggSpec],
         agg = segment_aggregate(s, seg_ids, live, capacity)
         out_aggs.append(_mask_to(agg, group_live))
     return out_keys, out_aggs, n_groups
+
+
+def _agg_dtype(spec: AggSpec) -> dt.DType:
+    return result_dtype(spec.op,
+                        None if spec.column is None else spec.column.dtype)
+
+
+def _pad_slots(col: Column, capacity: int) -> Column:
+    """``col`` grown to ``capacity`` slots, the new ones zeroed and invalid."""
+    pad = capacity - col.capacity
+    if pad == 0:
+        return col
+    arrays = [jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1))
+              for a in col.arrays()]
+    return build_column(col.dtype, arrays)[0]
 
 
 def _mask_to(col: Column, mask: jnp.ndarray) -> Column:

@@ -1281,7 +1281,8 @@ class TpuHashAggregateExec(TpuExec):
 
     CONTRACT = exec_contract(schema="defined", partitioning="defined",
                              extras=("agg_distribution",))
-    METRICS = exec_metrics("computeAggTime")
+    METRICS = exec_metrics("computeAggTime", "aggFewGroupBatches",
+                           "aggScatterBatches")
 
     def __init__(self, child: TpuExec, grouping: List[ex.Expression],
                  aggregate_exprs: List[ex.Expression], mode: str = "complete",
@@ -1459,11 +1460,9 @@ class TpuHashAggregateExec(TpuExec):
             failed — _fused_finish then re-reads and its handler degrades
             this one batch to the eager path)."""
             pb = self._fused_finish(tok, stats)
-            if pb is not None and pb.capacity > agg_k.DENSE_MAX_SLOTS:
-                pb = self._shrink_partial(pb)
             if pb is None:
-                pb = self._update_partial_eager(batch)
-            return pb
+                return self._update_partial_eager(batch)
+            return self._shrink_fused(tok, pb)
 
         depth = max(1, int(cfg.TpuConf().get(cfg.AGG_PIPELINE_DEPTH)))
         # metrics=: the window's batched stat readbacks charge THIS exec's
@@ -1488,8 +1487,9 @@ class TpuHashAggregateExec(TpuExec):
                             lambda v, b=batch, t=tok: finish(b, t, v),
                             tok[-1])
                     else:
-                        # 'done': whole kernel already dispatched, count
-                        # device-resident — nothing to resolve
+                        # 'done' / 'sorted': whole kernel already
+                        # dispatched, count device-resident — nothing to
+                        # resolve
                         ready = win.push(
                             lambda b=batch, t=tok: finish(b, t))
                 for pb in ready:
@@ -1529,13 +1529,6 @@ class TpuHashAggregateExec(TpuExec):
                         op, col, ignore_nulls=leaf.ignore_nulls))
         return keys, specs
 
-    def _update_partial_batch(self, batch: ColumnarBatch) -> ColumnarBatch:
-        """Update-phase aggregation of one input batch into partial form."""
-        fused = self._maybe_fused_phase(batch, "update")
-        if fused is not None:
-            return self._shrink_partial(fused)
-        return self._update_partial_eager(batch)
-
     def _update_partial_eager(self, batch: ColumnarBatch) -> ColumnarBatch:
         """Eager (per-op dispatch) update aggregation — the fallback when
         whole-stage fusion does not apply."""
@@ -1563,6 +1556,22 @@ class TpuHashAggregateExec(TpuExec):
         cols = [K.rebucket_column(c, batch.num_rows, ncap)
                 for c in batch.columns]
         return ColumnarBatch(batch.schema, cols, batch.num_rows)
+
+    def _shrink_fused(self, tok, pb: ColumnarBatch) -> ColumnarBatch:
+        """A fused phase's output, shrunk where it is large (a small one
+        keeps its device-resident count: a shrink would force a blocking
+        readback per cycle). Where the program chose between the masked and
+        the scatter reductions on the device (``groupby_aggregate``), the
+        count the shrink has just read says which it took."""
+        if pb.capacity <= agg_k.DENSE_MAX_SLOTS:
+            return pb
+        pb = self._shrink_partial(pb)
+        if tok[0] == "sorted":
+            if pb.num_rows <= agg_k.FEW_GROUPS_MAX:
+                self.metrics.inc("aggFewGroupBatches")
+            else:
+                self.metrics.inc("aggScatterBatches")
+        return pb
 
     def _apply_pre_stage_eager(self, batch: ColumnarBatch) -> ColumnarBatch:
         """Eager fallback of the folded filter/project chain (fused paths
@@ -1635,7 +1644,8 @@ class TpuHashAggregateExec(TpuExec):
         tok = self._fused_dispatch(batch, phase)
         if tok is None:
             return None
-        return self._fused_finish(tok)
+        pb = self._fused_finish(tok)
+        return None if pb is None else self._shrink_fused(tok, pb)
 
     def _build_eval_fn(self, phase: str):
         # resolves the exec via the thread-local stack, NOT a captured
@@ -1838,8 +1848,10 @@ class TpuHashAggregateExec(TpuExec):
 
     def _dispatch_plain_sort(self, batch: ColumnarBatch, sig, in_schema, cap,
                              build_eval, pargs: tuple = ()):
-        """Whole sort+scatter group-by in ONE dispatch, count left
-        device-resident (no probe, no readback)."""
+        """Whole sort-based group-by in ONE dispatch, count left
+        device-resident (no probe, no readback): ``groupby_aggregate``,
+        which takes the masked or the scatter reductions by the group count
+        it finds. Token ``sorted``, so that ``_shrink_fused`` can say which."""
         import jax
         pschema = self._partial_schema()
         donate = _donate_argnums(batch, 1)
@@ -1863,7 +1875,7 @@ class TpuHashAggregateExec(TpuExec):
         _note_donated(batch, donate)
         pb = ColumnarBatch.from_flat_arrays(pschema, list(outs[:-1]),
                                             outs[-1])
-        return ("done", pb)
+        return ("sorted", pb)
 
     def _fused_finish(self, tok,
                       stats=None) -> Optional[ColumnarBatch]:
@@ -1874,7 +1886,7 @@ class TpuHashAggregateExec(TpuExec):
         the retained batch)."""
         try:
             kind = tok[0]
-            if kind == "done":
+            if kind in ("done", "sorted"):
                 return tok[1]
             if kind == "dense":
                 pb = self._finish_dense(tok, stats)
@@ -1959,7 +1971,8 @@ class TpuHashAggregateExec(TpuExec):
         f32_safe = bool(all(a <= agg_k.F32_SAFE_ABSMAX for a in stats[1:]))
         Kb = _bucket(max(n_groups, 1))
         # per-spec mixing below: matmul where supported (count, float
-        # sum/avg), scatter-at-Kb otherwise (min/max, int sums)
+        # sum/avg); otherwise (min/max, int sums) Kb-slot reductions, masked
+        # ones up to FEW_GROUPS_MAX slots and a scatter beyond
         use_mm = Kb <= agg_k.MATMUL_MAX_GROUPS and f32_safe
         # last consumer of the batch columns AND of the probe's order/
         # starts arrays (args 1-2): donate them together
@@ -2002,7 +2015,7 @@ class TpuHashAggregateExec(TpuExec):
                     else:
                         agg = agg_k.segment_aggregate(
                             sc, seg_ids, live, capb,
-                            num_segments=Kb)
+                            num_segments=Kb, n_groups=ng)
                     oa.append(agg_k._mask_to(agg, glive))
                 return ok, oa, ng
             return jax.jit(fn, donate_argnums=donate)
@@ -2043,11 +2056,7 @@ class TpuHashAggregateExec(TpuExec):
         per group (the merge half of the CudfAggregate update/merge pairs)."""
         fused = self._maybe_fused_phase(batch, "merge")
         if fused is not None:
-            # already-small outputs keep their device-resident count — a
-            # shrink would force a blocking readback per merge cycle
-            if fused.capacity <= agg_k.DENSE_MAX_SLOTS:
-                return fused
-            return self._shrink_partial(fused)
+            return fused
         keys, specs = self._merge_specs(batch)
         if not keys:
             aggs = agg_k.reduce_aggregate(specs, batch.num_rows,

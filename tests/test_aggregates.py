@@ -339,3 +339,161 @@ def test_fused_staged_matmul_groupby_matches_exact():
         assert abs(sv - row["sv"]) <= 1e-6 * max(1, abs(row["sv"]))
         assert abs(av - row["av"]) <= 1e-6 * max(1, abs(row["av"]))
         assert abs(mv - row["mv"]) <= 1e-12
+
+
+# ---------------------------------------------------------------------------
+# Few groups: masked reductions in place of the scatter (FEW_GROUPS_MAX)
+# ---------------------------------------------------------------------------
+
+import functools  # noqa: E402
+
+from spark_rapids_tpu.ops import aggregates as agg_k  # noqa: E402
+
+_S = agg_k.FEW_GROUPS_MAX
+_FEW_ROWS, _FEW_CAP = 1500, 2048
+# (name, op, input column, ignore_nulls)
+_FEW_SPECS = [
+    ("sum_f64", "sum", "v", True), ("sum_i64", "sum", "i", True),
+    ("sum_bool", "sum", "b", True), ("count", "count", "v", True),
+    ("count_star", "count_star", None, True), ("avg", "avg", "v", True),
+    ("min_f64", "min", "v", True), ("max_f64", "max", "v", True),
+    ("min_i64", "min", "i", True), ("max_i64", "max", "i", True),
+    ("min_bool", "min", "b", True), ("max_bool", "max", "b", True),
+    ("min_str", "min", "s", True), ("first", "first", "v", True),
+    ("last", "last", "i", True), ("first_with_nulls", "first", "v", False),
+]
+
+
+def _few_data(n_groups):
+    """``n_groups`` groups over 1500 rows in no order: a NULL-key group, a
+    group whose values are all NULL, a NaN in one group only and an inf in
+    another only."""
+    rng = np.random.default_rng(1000 + n_groups)
+    gid = rng.integers(0, n_groups, _FEW_ROWS)
+    gid[:n_groups] = np.arange(n_groups)
+    null_key = n_groups - 1 if n_groups > 1 else -1
+    v = rng.uniform(1.0, 100.0, _FEW_ROWS)
+    v_null = rng.random(_FEW_ROWS) < 0.2
+    if n_groups > 3:
+        v_null |= gid == 1                       # an all-NULL group
+        v[np.flatnonzero(gid == 2)[0]] = np.nan
+        v[np.flatnonzero(gid == 3)[0]] = np.inf
+        v_null[np.flatnonzero(gid == 2)[0]] = False
+        v_null[np.flatnonzero(gid == 3)[0]] = False
+    i = rng.integers(-10**12, 10**12, _FEW_ROWS)
+    i_null = rng.random(_FEW_ROWS) < 0.1
+    b = rng.random(_FEW_ROWS) < 0.5
+    s = rng.integers(0, 10**6, _FEW_ROWS)
+    cols = {
+        "k": _col([None if g == null_key else int(g) for g in gid], dt.INT64),
+        "v": _col([None if m else float(x) for m, x in zip(v_null, v)],
+                  dt.FLOAT64),
+        "i": _col([None if m else int(x) for m, x in zip(i_null, i)],
+                  dt.INT64),
+        "b": _col([bool(x) for x in b], dt.BOOL),
+        "s": _col([f"w{x}" for x in s], dt.STRING),
+    }
+    # dead rows between live ones, every group keeping a live row
+    live = rng.random(_FEW_ROWS) < 0.7
+    live[:n_groups] = True
+    return cols, gid, (v, v_null), live
+
+
+@functools.lru_cache(maxsize=None)
+def _few_and_scatter(n_groups, masked):
+    """Every aggregate of ``_FEW_SPECS`` in one group-by, as the code
+    chooses (masked reductions up to FEW_GROUPS_MAX groups) and with the
+    choice taken away (the scatter path, as before PR 26)."""
+    import jax.numpy as jnp
+    cols, gid, (v, v_null), live = _few_data(n_groups)
+    specs = [AggSpec(op, cols[c] if c else None, ignore_nulls=ign)
+             for _n, op, c, ign in _FEW_SPECS]
+    mask = None
+    if masked:
+        mask = jnp.asarray(np.concatenate(
+            [live, np.zeros(_FEW_CAP - _FEW_ROWS, bool)]))
+    else:
+        live = np.ones(_FEW_ROWS, bool)
+
+    def run():
+        keys, aggs, ng = groupby_aggregate([cols["k"]], specs, _FEW_ROWS,
+                                           _FEW_CAP, live_mask=mask)
+        assert int(ng) == n_groups
+        # the whole capacity: what lies beyond the groups counts too
+        return [[np.asarray(a) for a in c.arrays()] for c in keys + aggs]
+
+    chosen = run()
+    old = agg_k.FEW_GROUPS_MAX
+    agg_k.FEW_GROUPS_MAX = 0
+    try:
+        scatter = run()
+    finally:
+        agg_k.FEW_GROUPS_MAX = old
+    # numpy's float64 sums, groups in the output's order (NULL key first)
+    order = ([n_groups - 1] if n_groups > 1 else []) + \
+        list(range(n_groups - 1 if n_groups > 1 else 1))
+    sums = [v[(gid == g) & live & ~v_null].sum() for g in order]
+    return chosen, scatter, np.array(sums)
+
+
+@pytest.mark.parametrize("n_groups, masked", [
+    (1, False), (_S - 1, False), (_S - 1, True), (_S, True), (_S + 1, False)],
+    ids=["1", "S-1", "S-1_live_mask", "S_live_mask", "S+1"])
+@pytest.mark.parametrize(
+    "which", range(len(_FEW_SPECS) + 1),
+    ids=["keys"] + [name for name, *_ in _FEW_SPECS])
+def test_few_groups_equal_the_scatter_path(which, n_groups, masked):
+    """Up to FEW_GROUPS_MAX groups the reductions are masked ones, beyond
+    it the scatter: either way every slot of every output array equals the
+    scatter path's — bit for bit but for float64 sums, which are held to
+    the scatter's chain of adds at 1e-13 and to numpy's sum at 1e-15."""
+    chosen, scatter, np_sums = _few_and_scatter(n_groups, masked)
+    name = "keys" if which == 0 else _FEW_SPECS[which - 1][0]
+    for got, want in zip(chosen[which], scatter[which]):
+        if name in ("sum_f64", "avg") and got.dtype == np.float64:
+            # the scatter adds a group's rows one after another
+            np.testing.assert_allclose(got, want, rtol=1e-13, atol=0)
+        else:
+            np.testing.assert_array_equal(got, want)
+    if name == "sum_f64":
+        data, valid = chosen[which]
+        finite = np.isfinite(np_sums) & (np_sums != 0)
+        assert finite.sum() >= max(1, n_groups * 3 // 4)
+        np.testing.assert_allclose(data[:n_groups][finite], np_sums[finite],
+                                   rtol=1e-15)
+        # the NaN and the inf stayed in their own groups
+        assert np.array_equal(np.isnan(data[:n_groups]), np.isnan(np_sums))
+        assert np.array_equal(np.isinf(data[:n_groups]), np.isinf(np_sums))
+        assert not valid[n_groups:].any() and not data[n_groups:].any()
+
+
+@pytest.mark.parametrize("counted", [False, True],
+                         ids=["every_slot", "groups_present"])
+@pytest.mark.parametrize("dtype", ["float64", "int64", "int32"])
+@pytest.mark.parametrize("kind", ["sum", "min", "max"])
+def test_masked_segment_reduce_takes_rows_in_any_order(kind, dtype, counted):
+    """The helper alone, rows NOT sorted by segment, a NaN and an inf among
+    them: what ``jax.ops.segment_<kind>`` gives, slot for slot — the empty
+    slots too."""
+    import jax
+    import jax.numpy as jnp
+    rng = np.random.default_rng(7)
+    n, present = 3000, _S - 5
+    ids = rng.integers(0, present, n).astype(np.int32)
+    data = rng.uniform(-50.0, 50.0, n)
+    if dtype == "float64" and kind != "sum":
+        data[np.flatnonzero(ids == 3)[0]] = np.inf
+    data = jnp.asarray(data.astype(dtype))
+    segs = agg_k._Segs(jnp.asarray(ids), _S,
+                       jnp.int32(present) if counted else None)
+    got = np.asarray(agg_k._masked_segment_reduce(kind, data, segs))
+    want = np.asarray(getattr(jax.ops, f"segment_{kind}")(
+        data, segs.ids, num_segments=_S))
+    assert got.dtype == want.dtype
+    if dtype == "float64" and kind == "sum":
+        np.testing.assert_allclose(got, want, rtol=1e-13, atol=1e-11)
+        ref = [np.asarray(data)[ids == g].sum() for g in range(present)]
+        np.testing.assert_allclose(got[:present], ref, rtol=1e-13,
+                                   atol=1e-11)
+    else:
+        np.testing.assert_array_equal(got, want)

@@ -198,13 +198,45 @@ def test_sort_groupby_with_matmul_reductions_compiles(one_chip):
                 agg = agg_k.segment_aggregate(spec, seg_ids, live, cap,
                                               num_segments=kb)
             outs.append(agg.data)
-        assert on_mxu >= 4               # only the bigint sum scatters
+        assert on_mxu >= 4     # only the bigint sum is left: masked at kb
         return tuple(outs) + (jnp.sum(starts),)
 
     structs = [jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)]
     for t in (dt.STRING, dt.STRING, dt.INT64, dt.FLOAT64):
         structs += _column_structs(t, cap, one_chip)
     _compile(q1_kernel, *structs)
+
+
+def test_few_group_masked_reductions_compile_with_no_scatter(one_chip):
+    """q1's exact aggregates (float64 sum and avg, bigint sum, count) as
+    the few-group branch of ``groupby_aggregate`` runs them, at the chip
+    run's 8 Mi-row batch: no scatter in what the TPU compiler builds, and
+    nothing of FEW_GROUPS_MAX x rows among its temporaries (128 x 8 Mi
+    float64 would be 8 GB)."""
+    from spark_rapids_tpu.ops import aggregates as agg_k
+    cap = 1 << 23
+
+    def few(seg_ids, n_groups, num_rows, qty, qty_valid, price, price_valid):
+        live = jnp.arange(cap) < num_rows
+        qty = Column(dt.INT64, qty, qty_valid)
+        price = Column(dt.FLOAT64, price, price_valid)
+        outs = []
+        for op, col in (("sum", qty), ("sum", price), ("avg", price),
+                        ("count", qty), ("count_star", None),
+                        ("min", price)):
+            agg = agg_k.segment_aggregate(
+                agg_k.AggSpec(op, col), seg_ids, live, cap,
+                num_segments=agg_k.FEW_GROUPS_MAX, n_groups=n_groups)
+            outs += agg.arrays()
+        return tuple(outs)
+
+    def s(npdt, shape=(cap,)):
+        return jax.ShapeDtypeStruct(shape, npdt, sharding=one_chip)
+    compiled = _compile(few, s(jnp.int32), s(jnp.int32, ()),
+                        s(jnp.int32, ()), s(jnp.int64), s(jnp.bool_),
+                        s(jnp.float64), s(jnp.bool_))
+    assert " scatter(" not in compiled.as_text()      # the HLO op
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * 8 * cap
 
 
 # ---------------------------------------------------------------------------
