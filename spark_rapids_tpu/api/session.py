@@ -609,6 +609,10 @@ class TpuSession:
             # loads (docs/observability.md §9)
             "programs": recompile.programs_report(rec.programs)
             if rec is not None else {},
+            # what the query's SPMD mesh stages moved and how long their
+            # three steps took (exec/tracing.MESH_COUNTERS; all zero for
+            # a query that ran none)
+            "mesh": dict(rec.mesh) if rec is not None else {},
             # driver-side planning (analyze + overrides) wall time and the
             # execute_collect wall (device work + transfers + syncs): with
             # the per-operator timers these account for the query's wall

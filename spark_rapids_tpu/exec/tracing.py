@@ -74,7 +74,19 @@ STAGES = (
     "scan_unpack", "filter", "project", "key_encode", "lexsort", "gather",
     "segment_starts", "segment_sum_scatter", "segment_sum_masked",
     "segment_sum_matmul", "segment_sum_dense", "segment_minmax", "reduce",
-    "join_probe", "join_gather", "compact", "concat", "shuffle_split")
+    "join_probe", "join_gather", "compact", "concat", "shuffle_split",
+    # the steps of an SPMD mesh stage (parallel/mesh.py), outside the
+    # kernels' own stages: ``TpuMeshGroupByExec/partial_agg/lexsort/...``
+    "partial_agg", "bucket", "all_to_all", "flatten", "merge_agg", "sample",
+    "local_sort")
+
+#: what a query's mesh stages count (``last_query_metrics()["mesh"]``,
+#: fed by ``parallel/mesh.run_stage``): executions of an SPMD stage, their
+#: ``all_to_all``s and the operand bytes of those, the bytes copied from
+#: the home device to the workers before a stage and back after it, and
+#: the host-clock seconds of the three steps of a stage
+MESH_COUNTERS = ("stages", "iciExchanges", "iciBytes", "placeBytes",
+                 "gatherBytes", "placeS", "spmdS", "gatherS")
 
 
 def stage(name: str, operator: Optional[str] = None):
@@ -220,6 +232,8 @@ class SpanRecorder:
         # (recompile.note_call / note_rebuild), reported by
         # last_query_metrics()["programs"]
         self.programs: Dict[str, Dict[str, Any]] = {}
+        # this query's :data:`MESH_COUNTERS`
+        self.mesh: Dict[str, Any] = dict.fromkeys(MESH_COUNTERS, 0)
         self._root: Optional[str] = None   # the driving thread's open root
         self._t0: Optional[float] = None   # entered wall-clock origin
         self._wall: Optional[float] = None
@@ -306,6 +320,12 @@ class SpanRecorder:
             ent = self._rebuilds.setdefault(name, [0, 0.0])
             ent[0] += 1
             ent[1] += seconds
+
+    def note_mesh(self, **deltas) -> None:
+        """Add to this query's :data:`MESH_COUNTERS`."""
+        with self._mu:
+            for key, value in deltas.items():
+                self.mesh[key] += value
 
     def add(self, name, seconds):
         """Account an externally-timed interval as a leaf span (semaphore

@@ -117,6 +117,11 @@ class Expression:
 class Literal(Expression):
     """GpuLiteral analog (literals.scala)."""
 
+    #: position among a fused program's appended arguments, for a string
+    #: literal that rides as one (:func:`ordered_params`); ``None``: a
+    #: constant of the program
+    trace_pos: Optional[int] = None
+
     def __init__(self, value: Any, dtype: Optional[dt.DType] = None):
         super().__init__()
         if dtype is None:
@@ -144,6 +149,11 @@ class Literal(Expression):
         return self.value is None
 
     def eval(self, batch: ColumnarBatch) -> Scalar:
+        if self.trace_pos is not None and batch is not None:
+            pv = getattr(batch, "params", ())
+            if self.trace_pos < len(pv):
+                # inside a fused trace: the bytes are a traced argument
+                return Scalar(pv[self.trace_pos], self._dtype)
         return Scalar(self.value, self._dtype)
 
     def __repr__(self):
@@ -244,28 +254,94 @@ class Parameter(Literal):
         return f"Param(:{tag}={self.value!r})"
 
 
-def ordered_params(exprs: Sequence[Expression]) -> List["Parameter"]:
-    """Unique TRACEABLE Parameters across ``exprs`` in slot order, each
-    stamped with its ``trace_pos`` — the canonical appended-argument
-    ordering a fused program and its call sites must agree on.
-    Non-traceable parameters (strings) stay baked; their values ride the
-    structural cache key instead."""
+def _compared_string_literals(e: Expression) -> List["Literal"]:
+    """The plain string literals of ``e`` that a comparison
+    (``compares_strings``) holds against something read from the batch,
+    in traversal order. Their bytes can ride as an argument of the fused
+    program: ``c_mktsegment = 'BUILDING'`` and ``... = 'MACHINERY'`` are
+    then ONE program, where a constant would make one for each (the plan
+    and its cache entry stay per value: the fingerprint keeps it)."""
+    out: List[Literal] = []
+
+    def reads_batch(x: Expression) -> bool:
+        return bool(x.collect(
+            lambda n: isinstance(n, (BoundReference, ColumnRef))))
+
+    def walk(n: Expression) -> None:
+        if getattr(n, "compares_strings", False) and len(n.children) == 2:
+            for lit, other in (n.children, n.children[::-1]):
+                if type(lit) is Literal and lit.dtype == dt.STRING \
+                        and isinstance(lit.value, str) \
+                        and reads_batch(other):
+                    out.append(lit)
+        for c in n.children:
+            walk(c)
+    walk(e)
+    return out
+
+
+def ordered_params(exprs: Sequence[Expression]) -> List["Literal"]:
+    """What a fused program over ``exprs`` takes as appended arguments,
+    each stamped with its ``trace_pos`` — the canonical ordering the
+    program and its call sites must agree on: the unique TRACEABLE
+    Parameters in slot order, then the string literals of comparisons
+    (:func:`_compared_string_literals`). Non-traceable parameters
+    (strings) stay baked; their values ride the structural cache key
+    instead."""
     by_slot: dict = {}
     for e in exprs:
         for p in e.collect(lambda x: isinstance(x, Parameter)):
             if p.traceable():
                 by_slot.setdefault(p.slot, p)
-    out = [by_slot[s] for s in sorted(by_slot)]
+    out: List[Literal] = [by_slot[s] for s in sorted(by_slot)]
+    seen = set()
+    for e in exprs:
+        for lit in _compared_string_literals(e):
+            if id(lit) not in seen:
+                seen.add(id(lit))
+                out.append(lit)
     for i, p in enumerate(out):
         p.trace_pos = i
     return out
 
 
-def param_arg_values(params: Sequence["Parameter"]) -> tuple:
-    """The current binding of each parameter as a dtype-stable numpy
-    scalar — the extra jit arguments appended after a batch's flat
-    arrays. Host-side value boxing, no device sync."""
+def traced_literal_ids(params: Sequence["Literal"]) -> frozenset:
+    """ids of the string literals among :func:`ordered_params`: what a
+    consumer's structural cache key must NOT hold the value of."""
+    return frozenset(id(p) for p in params if type(p) is Literal)
+
+
+#: narrowest width class a string literal travels in as an argument. The
+#: class is the argument's SHAPE, so two literals in different classes are
+#: two programs: with the columns' own minimum (8) TPC-H Q3's 'BUILDING'
+#: (8 bytes) and 'MACHINERY' (9) were. 32 holds every enumerated value of
+#: the TPC-H columns a query compares (the longest, p_type, has 25 bytes);
+#: above it the classes are the columns' powers of two.
+TRACED_LITERAL_MIN_WIDTH = 32
+
+
+def string_literal_array(value: str) -> np.ndarray:
+    """A string literal as ONE argument of a fused program: its UTF-8
+    bytes zero-padded to a width class of at least
+    :data:`TRACED_LITERAL_MIN_WIDTH`, then the byte count as four
+    little-endian bytes (``ops/strings_util.traced_scalar`` reads it)."""
+    from ..columnar.column import bucket
+    raw = np.frombuffer(value.encode("utf-8"), dtype=np.uint8)
+    width = bucket(len(raw), TRACED_LITERAL_MIN_WIDTH)
+    out = np.zeros(width + 4, dtype=np.uint8)
+    out[:len(raw)] = raw
+    out[width:] = np.frombuffer(np.int32(len(raw)).tobytes(), dtype=np.uint8)
+    return out
+
+
+def param_arg_values(params: Sequence["Literal"]) -> tuple:
+    """The current value of each of :func:`ordered_params` as a
+    dtype-stable numpy array — the extra jit arguments appended after a
+    batch's flat arrays: a parameter's binding as a 0-d scalar, a string
+    literal as :func:`string_literal_array`. Host-side value boxing, no
+    device sync."""
     return tuple(
+        string_literal_array(p.value) if type(p) is Literal else
         np.asarray(p.value, dtype=p.dtype.numpy_dtype)  # lint: host-sync-ok boxes a python scalar host-side; no device value involved
         for p in params)
 

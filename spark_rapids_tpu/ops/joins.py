@@ -85,6 +85,9 @@ def _search_bounds(build_words: List[jnp.ndarray], n_build,
     """
     cap = build_words[0].shape[0]
     steps = max(1, (cap - 1).bit_length())
+    # a host row count would be a literal of the loop: one program per
+    # count, built anew for every data set. As an array it is an operand
+    n_build = jnp.asarray(n_build, dtype=jnp.int32)
     lo = jnp.zeros(probe_words[0].shape, dtype=jnp.int32)
     hi = jnp.full(probe_words[0].shape, n_build, dtype=jnp.int32)
 
@@ -100,7 +103,7 @@ def _search_bounds(build_words: List[jnp.ndarray], n_build,
         else:
             go_right = blt | beq                # build <= probe -> search right
         # rows at/after n_build are +infinity, never less-or-equal
-        go_right = go_right & (mid < jnp.asarray(n_build, mid.dtype))
+        go_right = go_right & (mid < n_build)
         lo = jnp.where(active & go_right, mid + 1, lo)
         hi = jnp.where(active & ~go_right, mid, hi)
         return lo, hi

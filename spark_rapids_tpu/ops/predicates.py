@@ -53,6 +53,10 @@ class BinaryComparison(Expression):
         """Spark NaN semantics (NaN = NaN true, NaN greatest); see float_eq/float_lt."""
         raise NotImplementedError
 
+    #: a string literal operand may ride as an argument of a fused program
+    #: (ops/expressions.ordered_params): both string kernels take it traced
+    compares_strings = True
+
     def _string_cmp(self, lv, rv, batch):
         cmp = string_compare(lv, rv, batch.capacity)
         return self._cmp(cmp, jnp.zeros((), jnp.int32))
@@ -157,6 +161,7 @@ class NotEqual(BinaryComparison):
 class EqualNullSafe(Expression):
     """`<=>`: never NULL; NULL <=> NULL is true (GpuEqualNullSafe)."""
     symbol = "<=>"
+    compares_strings = True          # as BinaryComparison
 
     @property
     def dtype(self):

@@ -24,8 +24,20 @@ def scalar_bytes(s: Scalar) -> Tuple[np.ndarray, int]:
     return np.frombuffer(b, dtype=np.uint8), len(b)
 
 
+def traced_scalar(v: StrOperand) -> bool:
+    """A string literal that reached a fused program as an argument
+    (``ops/expressions.string_literal_array``): uint8[W + 4], the bytes
+    zero-padded to W and the byte count as four little-endian bytes."""
+    return isinstance(v, Scalar) and hasattr(v.value, "shape")
+
+
 def operand_arrays(v: StrOperand, capacity: int, width: int):
     """(data[cap|1, W], lengths[cap|1]) as jnp arrays padded to ``width``."""
+    if traced_scalar(v):
+        row, tail = v.value[:-4], v.value[-4:].astype(jnp.int32)
+        n = tail[0] | (tail[1] << 8) | (tail[2] << 16) | (tail[3] << 24)
+        row = jnp.pad(row, (0, width - row.shape[0]))
+        return row[None, :], n[None]
     if isinstance(v, Scalar):
         raw, n = scalar_bytes(v)
         assert n <= width, f"scalar of {n} bytes vs width {width}; use _widths()"
@@ -41,7 +53,9 @@ def operand_arrays(v: StrOperand, capacity: int, width: int):
 def _widths(lv: StrOperand, rv: StrOperand) -> int:
     w = 1
     for v in (lv, rv):
-        if isinstance(v, Scalar):
+        if traced_scalar(v):
+            w = max(w, int(v.value.shape[0]) - 4)
+        elif isinstance(v, Scalar):
             w = max(w, len(scalar_bytes(v)[0]))
         else:
             w = max(w, int(v.data.shape[1]))

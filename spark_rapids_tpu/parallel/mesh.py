@@ -19,8 +19,8 @@ retry, mirroring the reference's UCX-vs-fallback split.
 
 from __future__ import annotations
 
-from functools import partial
-from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
+import time
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -31,6 +31,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..columnar import dtypes as dt
 from ..columnar.batch import ColumnarBatch
 from ..columnar.column import Column, bucket
+from ..exec import tracing
 from ..ops import kernels as K
 from ..ops import aggregates as agg_k
 from ..ops.hashing import murmur3_batch
@@ -139,20 +140,26 @@ def _spread(arrays) -> int:
 
 
 def _place_shards(mesh: Mesh, per_worker: List[List[jnp.ndarray]]
-                  ) -> List[jax.Array]:
+                  ) -> Tuple[List[jax.Array], int]:
     """One global ``[n, ...]`` array per array position, worker w's slice
-    RESIDENT on mesh device w. Each slice is copied straight to its own
+    RESIDENT on mesh device w, and the bytes that had to be copied to
+    another device for it. Each slice is copied straight to its own
     device: a ``jnp.stack`` would first materialize all n shards on the
-    default device and leave the jit to scatter them."""
+    default device and leave the jit to scatter them. The leading axis is
+    added where the slice already lies, so that one program per shape does
+    it and not one per destination."""
     devs = list(mesh.devices.flat)
     sharding = NamedSharding(mesh, P("workers"))
-    out = []
+    out, moved = [], 0
     for i in range(len(per_worker[0])):
-        pieces = [jax.device_put(pw[i], d)[None]
-                  for pw, d in zip(per_worker, devs)]
+        pieces = []
+        for pw, d in zip(per_worker, devs):
+            if d not in pw[i].devices():
+                moved += pw[i].nbytes
+            pieces.append(jax.device_put(pw[i][None], d))
         out.append(jax.make_array_from_single_device_arrays(
             (len(devs),) + pieces[0].shape[1:], sharding, pieces))
-    return out
+    return out, moved
 
 
 def _place_counts(mesh: Mesh, counts: Sequence[int]) -> jax.Array:
@@ -168,20 +175,113 @@ def _call_spmd(kind: str, fn, inputs: Sequence[jax.Array]
 
 
 def _worker_outputs(mesh: Mesh, outs: Sequence[jax.Array]
-                    ) -> List[List[jnp.ndarray]]:
+                    ) -> Tuple[List[List[jnp.ndarray]], int]:
     """Per worker, its slice of every SPMD output, gathered onto the
     default device — the one placement every downstream operator (and
-    every merge of partitions) already assumes. ``o[w]`` would instead
-    all-gather each slice onto EVERY mesh device and run everything
-    downstream replicated."""
+    every merge of partitions) already assumes — and the bytes copied for
+    it. ``o[w]`` would instead all-gather each slice onto EVERY mesh
+    device and run everything downstream replicated."""
     home = jax.local_devices()[0]
     rows: List[List[jnp.ndarray]] = [[None] * len(outs)
                                      for _ in range(mesh.devices.size)]
+    moved = 0
     for j, o in enumerate(outs):
         for sh in o.addressable_shards:
+            if sh.device != home:
+                moved += sh.data.nbytes
             rows[sh.index[0].start or 0][j] = jax.device_put(
                 sh.data, home)[0]
+    return rows, moved
+
+
+# ---------------------------------------------------------------------------
+# One execution of an SPMD stage: place -> SPMD call -> gather, each a
+# child span of the operator's stage span (``mesh_exchange``,
+# ``mesh_groupby``, ``mesh_sort``) and a line of the query's ``mesh``
+# counters (exec/tracing.MESH_COUNTERS, ``last_query_metrics()["mesh"]``)
+# ---------------------------------------------------------------------------
+
+def _note_mesh(**deltas) -> None:
+    rec = tracing.SpanRecorder.active
+    if rec is not None:
+        rec.note_mesh(**deltas)
+
+
+def _step(span: str, seconds_key: str, produce):
+    """One step of a stage: ``produce()`` under the child span, its
+    host-clock seconds into the query's counter. Dispatch is asynchronous,
+    so the seconds of ``mesh_place`` and ``mesh_gather`` are those of the
+    enqueue alone, and the copies complete under whichever readback comes
+    next (a stage's own is inside ``mesh_spmd``) — except under
+    ``tracing.enabled``, the measuring mode, where each step waits for
+    what it produced and its seconds are its own."""
+    t0 = time.perf_counter()
+    with tracing.trace_span(span):
+        out = produce()
+        if tracing._tracing_on():
+            jax.block_until_ready(out)
+    _note_mesh(**{seconds_key: time.perf_counter() - t0})
+    return out
+
+
+def run_stage(kind: str, mesh: Mesh, fn, per_worker: List[List[jnp.ndarray]],
+              counts: Sequence[int], slot_bytes: int,
+              carried: Sequence[jax.Array] = ()
+              ) -> Tuple[Tuple[jax.Array, ...], np.ndarray]:
+    """Place the per-worker arrays and row counts on their devices, call
+    the stage's SPMD program on them (then on ``carried``, arrays already
+    sharded), and read back its last output: the stage's ONE host sync,
+    the per-worker sizes of what it produced. Returns the outputs, still
+    sharded, and those sizes.
+
+    ``slot_bytes``: bytes of one worker's ``all_to_all`` payload at the
+    stage's capacity. Every worker hands the collective n such slots and n
+    int32 counts, and all but its own cross a link, padding and all
+    (shapes are static): ``n * (n - 1)`` slots are what ``iciBytes`` and
+    the process-wide ``plane_totals`` count. They follow the stage's
+    capacity class, not the rows a draw leaves live."""
+    n = int(mesh.devices.size)
+    placed, moved = _step("mesh_place", "placeS",
+                          lambda: _place_shards(mesh, per_worker))
+    inputs = placed + [_place_counts(mesh, counts), *carried]
+
+    def call():
+        outs = _call_spmd(kind, fn, inputs)
+        from ..analysis.sync_audit import allowed_host_transfer
+        with allowed_host_transfer("mesh stage sizing"):
+            return outs, np.asarray(outs[-1])  # lint: host-sync-ok mesh stage boundary: ONE per-worker size readback per SPMD stage
+    t0 = time.perf_counter()
+    outs, sizes = _step("mesh_spmd", "spmdS", call)
+    ici_bytes = n * (n - 1) * (slot_bytes + 4)
+    _note_mesh(stages=1, iciExchanges=1, iciBytes=ici_bytes,
+               placeBytes=moved)
+    # the same exchange in the process totals, next to the host plane's
+    # (shuffle/exchange.note_plane -> tpu_shuffle_gbps{plane=ici})
+    from ..shuffle.exchange import note_plane
+    note_plane("ici", ici_bytes, time.perf_counter() - t0)
+    return outs, sizes
+
+
+def gather_stage(mesh: Mesh, outs: Sequence[jax.Array]
+                 ) -> List[List[jnp.ndarray]]:
+    """Per worker, its slice of each output of a stage on the home device
+    (:func:`_worker_outputs`), as the stage's third step."""
+    rows, moved = _step("mesh_gather", "gatherS",
+                        lambda: _worker_outputs(mesh, outs))
+    _note_mesh(gatherBytes=moved)
     return rows
+
+
+def _slot_bytes(arrays: Sequence[jnp.ndarray]) -> int:
+    return sum(int(a.nbytes) for a in arrays)
+
+
+def _scope(operator: str, stage_name: str):
+    """``<operator>/<stage>`` inside an SPMD program: the operator whose
+    stage the program is, and one of the mesh steps of
+    ``exec/tracing.STAGES`` (the kernels' own stages nest inside)."""
+    assert stage_name in tracing.STAGES, stage_name
+    return jax.named_scope(f"{operator}/{stage_name}")
 
 
 # ---------------------------------------------------------------------------
@@ -252,6 +352,20 @@ def flatten_received(stacked: List[jnp.ndarray], counts: jnp.ndarray,
     return outs, total.astype(jnp.int32)
 
 
+def _route(operator: str, payload: Sequence[jnp.ndarray],
+           pids: jnp.ndarray, live: jnp.ndarray, n: int, cap: int,
+           out_cap: int) -> Tuple[List[jnp.ndarray], jnp.ndarray]:
+    """A stage's exchange, in its three scoped steps: rows bucketed by
+    target worker, one ``all_to_all``, the received slots flattened."""
+    with _scope(operator, "bucket"):
+        stacked, counts = bucket_rows_for_exchange(payload, pids, live, n,
+                                                   cap)
+    with _scope(operator, "all_to_all"):
+        moved, moved_counts = exchange(stacked, counts, "workers")
+    with _scope(operator, "flatten"):
+        return flatten_received(moved, moved_counts, out_cap)
+
+
 # ---------------------------------------------------------------------------
 # Reduce-partition exchange: the ICI data plane of TpuShuffleExchangeExec
 # ---------------------------------------------------------------------------
@@ -282,18 +396,18 @@ def partition_exchange_fn(mesh: Mesh, col_dtypes: Sequence[dt.DType],
         live = jnp.arange(cap) < local_n
         owner = jnp.mod(pids, n)
         payload = list(arrays) + [pids]
-        stacked, counts = bucket_rows_for_exchange(payload, owner, live,
-                                                   n, cap)
-        moved, moved_counts = exchange(stacked, counts, "workers")
-        flat, recv_n = flatten_received(moved, moved_counts, out_cap)
-        recv_pids = flat[-1]
-        recv_live = jnp.arange(out_cap) < recv_n
-        sort_key = jnp.where(recv_live, recv_pids, num_partitions)
-        order = jnp.argsort(sort_key, stable=True)
-        sorted_arrays = [a[order] for a in flat[:-1]]
-        pcounts = jnp.bincount(
-            jnp.clip(sort_key, 0, num_partitions),
-            length=num_partitions + 1)[:num_partitions].astype(jnp.int32)
+        flat, recv_n = _route("TpuShuffleExchangeExec", payload, owner,
+                              live, n, cap, out_cap)
+        with _scope("TpuShuffleExchangeExec", "local_sort"):
+            recv_pids = flat[-1]
+            recv_live = jnp.arange(out_cap) < recv_n
+            sort_key = jnp.where(recv_live, recv_pids, num_partitions)
+            order = jnp.argsort(sort_key, stable=True)
+            sorted_arrays = [a[order] for a in flat[:-1]]
+            pcounts = jnp.bincount(
+                jnp.clip(sort_key, 0, num_partitions),
+                length=num_partitions + 1)[:num_partitions].astype(
+                    jnp.int32)
         return tuple(a[None] for a in sorted_arrays) + (pcounts[None],)
 
     in_specs = tuple([P("workers")] * (n_arrays + 2))
@@ -322,11 +436,9 @@ def run_partition_exchange(mesh: Mesh, batches: List[ColumnarBatch],
         ("pexch", _mesh_key(mesh), tuple(col_dtypes), cap, num_partitions),
         lambda: partition_exchange_fn(mesh, col_dtypes, cap,
                                       num_partitions))
-    outs = _call_spmd("pexch", fn, _place_shards(mesh, per_worker) + [
-        _place_counts(mesh, [b.num_rows for b in batches])])
-    from ..analysis.sync_audit import allowed_host_transfer
-    with allowed_host_transfer("ici exchange sizing"):
-        pcounts = np.asarray(outs[-1])     # ONE readback per exchange
+    outs, pcounts = run_stage("pexch", mesh, fn, per_worker,
+                              [b.num_rows for b in batches],
+                              _slot_bytes(per_worker[0]))
     # query-lifecycle breadcrumb: the mesh exchange's metadata (worker
     # count, partition count, total routed rows) lands in the flight
     # ring stamped with the ambient query id (exec/query_context via the
@@ -337,7 +449,7 @@ def run_partition_exchange(mesh: Mesh, batches: List[ColumnarBatch],
                   {"workers": int(n), "partitions": int(num_partitions),
                    "rows": int(pcounts.sum())})
     results: List[Tuple[List[Column], np.ndarray]] = []
-    for w, arrays in enumerate(_worker_outputs(mesh, outs[:-1])):
+    for w, arrays in enumerate(gather_stage(mesh, outs[:-1])):
         results.append((_rebuild_columns(col_dtypes, arrays), pcounts[w]))
     return results
 
@@ -388,6 +500,51 @@ def output_dtypes(agg_ops: Sequence[str], val_dtypes: Sequence[dt.DType]
     return [agg_k.result_dtype(op, t) for op, t in zip(agg_ops, val_dtypes)]
 
 
+_GROUPBY = "TpuMeshGroupByExec"
+
+
+def _update_specs(plan, val_cols: Sequence[Column]) -> List[agg_k.AggSpec]:
+    """The update-phase aggregates of :func:`_update_plan` over the value
+    columns; a non-float64 input of a float64 sum is widened first."""
+    specs = []
+    for cols_plan, c in zip(plan, val_cols):
+        for (uop, ut) in cols_plan:
+            cc = c
+            if ut == dt.FLOAT64 and c.dtype != dt.FLOAT64 and uop == "sum":
+                cc = Column(dt.FLOAT64, c.data.astype(jnp.float64),
+                            c.validity)
+            specs.append(agg_k.AggSpec(uop, cc))
+    return specs
+
+
+def _finalize_aggs(agg_ops, plan, aggs: Sequence[Column]) -> List[Column]:
+    """Merge-phase partials to the output form: avg divides its sum by its
+    count only here, after the merge."""
+    out_cols: List[Column] = []
+    ai = 0
+    for op, cols_plan in zip(agg_ops, plan):
+        if op == "avg":
+            s, c = aggs[ai], aggs[ai + 1]
+            valid = s.validity & (c.data > 0)
+            data = jnp.where(
+                valid,
+                s.data / jnp.maximum(c.data.astype(jnp.float64), 1.0),
+                0.0)
+            out_cols.append(Column(dt.FLOAT64, data, valid))
+        else:
+            out_cols.append(aggs[ai])
+        ai += len(cols_plan)
+    return out_cols
+
+
+def _groupby_slot_bytes(worker_arrays: Sequence[jnp.ndarray], nk: int,
+                        partial_dtypes: Sequence[dt.DType], cap: int) -> int:
+    """One worker's ``all_to_all`` payload of a group-by stage: its key
+    arrays and, at the same capacity, data and validity of each partial."""
+    return _slot_bytes(worker_arrays[:nk]) + cap * sum(
+        np.dtype(t.numpy_dtype).itemsize + 1 for t in partial_dtypes)
+
+
 def distributed_groupby_fn(mesh: Mesh, key_dtypes: Sequence[dt.DType],
                            val_dtypes: Sequence[dt.DType],
                            agg_ops: Sequence[str], cap: int):
@@ -426,48 +583,27 @@ def distributed_groupby_fn(mesh: Mesh, key_dtypes: Sequence[dt.DType],
         val_cols = _rebuild_columns(val_dtypes, arrays[nk:])
 
         # 1. local partial aggregate (update phase)
-        specs = []
-        for cols_plan, c in zip(plan, val_cols):
-            for (uop, ut) in cols_plan:
-                cc = c
-                if ut == dt.FLOAT64 and c.dtype != dt.FLOAT64 and uop == "sum":
-                    cc = Column(dt.FLOAT64, c.data.astype(jnp.float64),
-                                c.validity)
-                specs.append(agg_k.AggSpec(uop, cc))
-        out_keys, out_aggs, n_groups = agg_k.groupby_aggregate(
-            key_cols, specs, local_n, cap)
+        with _scope(_GROUPBY, "partial_agg"):
+            out_keys, out_aggs, n_groups = agg_k.groupby_aggregate(
+                key_cols, _update_specs(plan, val_cols), local_n, cap)
 
         # 2. bucket groups by hash(key) % n  ->  all_to_all over ICI
-        pids = jnp.mod(jnp.mod(murmur3_batch(out_keys, cap), n) + n, n)
+        with _scope(_GROUPBY, "bucket"):
+            pids = jnp.mod(jnp.mod(murmur3_batch(out_keys, cap), n) + n, n)
         live = jnp.arange(cap) < n_groups
         payload = _column_arrays(out_keys) + _column_arrays(out_aggs)
-        stacked, counts = bucket_rows_for_exchange(payload, pids, live, n, cap)
-        moved, moved_counts = exchange(stacked, counts, "workers")
-        flat, recv_n = flatten_received(moved, moved_counts, out_cap)
+        flat, recv_n = _route(_GROUPBY, payload, pids, live, n, cap, out_cap)
 
-        # 3. merge aggregate over received partials
-        recv_keys = _rebuild_columns(key_dtypes, flat[:nk])
-        recv_aggs = _rebuild_columns(partial_dtypes, flat[nk:])
-        mspecs = [agg_k.AggSpec(mop, c)
-                  for mop, c in zip(merge_ops, recv_aggs)]
-        f_keys, f_aggs, f_groups = agg_k.groupby_aggregate(
-            recv_keys, mspecs, recv_n, out_cap)
-
+        # 3. merge aggregate over received partials, then
         # 4. finalize: divide avg partials post-merge
-        out_cols: List[Column] = []
-        ai = 0
-        for op, cols_plan in zip(agg_ops, plan):
-            if op == "avg":
-                s, c = f_aggs[ai], f_aggs[ai + 1]
-                valid = s.validity & (c.data > 0)
-                data = jnp.where(
-                    valid,
-                    s.data / jnp.maximum(c.data.astype(jnp.float64), 1.0),
-                    0.0)
-                out_cols.append(Column(dt.FLOAT64, data, valid))
-            else:
-                out_cols.append(f_aggs[ai])
-            ai += len(cols_plan)
+        with _scope(_GROUPBY, "merge_agg"):
+            recv_keys = _rebuild_columns(key_dtypes, flat[:nk])
+            recv_aggs = _rebuild_columns(partial_dtypes, flat[nk:])
+            mspecs = [agg_k.AggSpec(mop, c)
+                      for mop, c in zip(merge_ops, recv_aggs)]
+            f_keys, f_aggs, f_groups = agg_k.groupby_aggregate(
+                recv_keys, mspecs, recv_n, out_cap)
+            out_cols = _finalize_aggs(agg_ops, plan, f_aggs)
         out = (_column_arrays(f_keys) + _column_arrays(out_cols) +
                [f_groups])
         return tuple(a[None] for a in out)
@@ -503,11 +639,10 @@ def copartition_exchange_fn(mesh: Mesh, col_dtypes: Sequence[dt.DType],
         cols = _rebuild_columns(col_dtypes, arrays)
         key_cols = [cols[i] for i in key_positions]
         live = jnp.arange(cap) < local_n
-        pids = jnp.mod(jnp.mod(murmur3_batch(key_cols, cap), n) + n, n)
-        payload = _column_arrays(cols)
-        stacked, counts = bucket_rows_for_exchange(payload, pids, live, n, cap)
-        moved, moved_counts = exchange(stacked, counts, "workers")
-        flat, recv_n = flatten_received(moved, moved_counts, out_cap)
+        with _scope("TpuMeshJoinExec", "bucket"):
+            pids = jnp.mod(jnp.mod(murmur3_batch(key_cols, cap), n) + n, n)
+        flat, recv_n = _route("TpuMeshJoinExec", _column_arrays(cols), pids,
+                              live, n, cap, out_cap)
         return tuple(a[None] for a in flat) + (recv_n[None],)
 
     in_specs = tuple([P("workers")] * (n_arrays + 1))
@@ -533,17 +668,15 @@ def _shard_arrays(batches: List[ColumnarBatch], cap: int,
     return per_worker
 
 
-def _exchanged_batches(mesh: Mesh, outs, schema: dt.Schema,
-                       col_dtypes: Sequence[dt.DType]
+def _exchanged_batches(mesh: Mesh, outs, sizes: np.ndarray,
+                       schema: dt.Schema, col_dtypes: Sequence[dt.DType]
                        ) -> List[ColumnarBatch]:
-    """Per-worker result batches of a row-moving SPMD stage whose last
-    output is the per-worker received row count (ONE readback)."""
-    from ..analysis.sync_audit import allowed_host_transfer
-    with allowed_host_transfer("mesh stage sizing"):
-        recv = np.asarray(outs[-1])  # lint: host-sync-ok mesh stage boundary: ONE per-worker row-count readback sizes the output batches
+    """Per-worker result batches of a row-moving SPMD stage: its outputs
+    but the last gathered home, ``sizes`` (:func:`run_stage`) their row
+    counts."""
     return [ColumnarBatch(schema, _rebuild_columns(col_dtypes, arrays),
-                          int(recv[w]))
-            for w, arrays in enumerate(_worker_outputs(mesh, outs[:-1]))]
+                          int(sizes[w]))
+            for w, arrays in enumerate(gather_stage(mesh, outs[:-1]))]
 
 
 def run_copartition_exchange(mesh: Mesh, batches: List[ColumnarBatch],
@@ -559,10 +692,12 @@ def run_copartition_exchange(mesh: Mesh, batches: List[ColumnarBatch],
         ("copart", _mesh_key(mesh), tuple(col_dtypes),
          tuple(key_positions), cap),
         lambda: copartition_exchange_fn(mesh, col_dtypes, key_positions, cap))
-    outs = _call_spmd("copart", fn, _place_shards(
-        mesh, _shard_arrays(batches, cap)) + [
-        _place_counts(mesh, [b.num_rows for b in batches])])
-    return _exchanged_batches(mesh, outs, batches[0].schema, col_dtypes)
+    per_worker = _shard_arrays(batches, cap)
+    outs, sizes = run_stage("copart", mesh, fn, per_worker,
+                            [b.num_rows for b in batches],
+                            _slot_bytes(per_worker[0]))
+    return _exchanged_batches(mesh, outs, sizes, batches[0].schema,
+                              col_dtypes)
 
 
 # ---------------------------------------------------------------------------
@@ -571,6 +706,7 @@ def run_copartition_exchange(mesh: Mesh, batches: List[ColumnarBatch],
 # ---------------------------------------------------------------------------
 
 _SAMPLE_PER_WORKER = 64
+_SORT = "TpuMeshSortExec"
 
 
 def _lex_lt(a_words: List[jnp.ndarray], b_words: List[jnp.ndarray]
@@ -617,11 +753,8 @@ def distributed_sort_fn(mesh: Mesh, col_dtypes: Sequence[dt.DType],
             words.extend(K._key_arrays(K.SortKey(cols[pos], asc, nf)))
         return words
 
-    def per_worker(*arrays_and_count):
-        *arrays, local_n = arrays_and_count
-        arrays = [a[0] for a in arrays]
-        local_n = local_n[0]
-        cols = _rebuild_columns(col_dtypes, arrays)
+    def range_owner(cols: List[Column], local_n) -> jnp.ndarray:
+        """Steps 1-3: the range partition (worker) of every row."""
         words = encode(cols)
 
         # 2. sample s evenly-spaced live rows (invalid when local_n == 0)
@@ -652,22 +785,29 @@ def distributed_sort_fn(mesh: Mesh, col_dtypes: Sequence[dt.DType],
             bw = [jnp.broadcast_to(bwords[w_i], (cap,))
                   for bwords in b_words]
             pid = pid + _lex_lt(bw, words).astype(jnp.int32)
-        pid = jnp.clip(pid, 0, n - 1)
+        return jnp.clip(pid, 0, n - 1)
+
+    def per_worker(*arrays_and_count):
+        *arrays, local_n = arrays_and_count
+        arrays = [a[0] for a in arrays]
+        local_n = local_n[0]
+        cols = _rebuild_columns(col_dtypes, arrays)
+        with _scope(_SORT, "sample"):
+            pid = range_owner(cols, local_n)
 
         # 4. route rows to their range owner
         live = jnp.arange(cap) < local_n
-        payload = _column_arrays(cols)
-        stacked, counts = bucket_rows_for_exchange(payload, pid, live, n, cap)
-        moved, moved_counts = exchange(stacked, counts, "workers")
-        flat, recv_n = flatten_received(moved, moved_counts, out_cap)
+        flat, recv_n = _route(_SORT, _column_arrays(cols), pid, live, n,
+                              cap, out_cap)
 
         # 5. local sort of the received shard
-        recv_cols = _rebuild_columns(col_dtypes, flat)
-        keys = [K.SortKey(recv_cols[pos], asc, nf)
-                for pos, asc, nf in zip(key_positions, ascending,
-                                        nulls_first)]
-        idx = K.sort_indices(keys, recv_n, out_cap)
-        sorted_cols = [K.gather_column(c, idx) for c in recv_cols]
+        with _scope(_SORT, "local_sort"):
+            recv_cols = _rebuild_columns(col_dtypes, flat)
+            keys = [K.SortKey(recv_cols[pos], asc, nf)
+                    for pos, asc, nf in zip(key_positions, ascending,
+                                            nulls_first)]
+            idx = K.sort_indices(keys, recv_n, out_cap)
+            sorted_cols = [K.gather_column(c, idx) for c in recv_cols]
         out = _column_arrays(sorted_cols) + [recv_n]
         return tuple(a[None] for a in out)
 
@@ -692,10 +832,12 @@ def run_distributed_sort(mesh: Mesh, batches: List[ColumnarBatch],
         lambda: distributed_sort_fn(mesh, col_dtypes, key_positions,
                                     tuple(ascending), tuple(nulls_first),
                                     cap))
-    outs = _call_spmd("sort", fn, _place_shards(
-        mesh, _shard_arrays(batches, cap)) + [
-        _place_counts(mesh, [b.num_rows for b in batches])])
-    return _exchanged_batches(mesh, outs, batches[0].schema, col_dtypes)
+    per_worker = _shard_arrays(batches, cap)
+    outs, sizes = run_stage("sort", mesh, fn, per_worker,
+                            [b.num_rows for b in batches],
+                            _slot_bytes(per_worker[0]))
+    return _exchanged_batches(mesh, outs, sizes, batches[0].schema,
+                              col_dtypes)
 
 
 def distributed_groupby_round_fn(mesh: Mesh, key_dtypes, val_dtypes,
@@ -736,26 +878,18 @@ def distributed_groupby_round_fn(mesh: Mesh, key_dtypes, val_dtypes,
         val_cols = _rebuild_columns(val_dtypes, win[nk:])
 
         # 1. partial aggregate of this window
-        specs = []
-        for cols_plan, c in zip(plan, val_cols):
-            for (uop, ut) in cols_plan:
-                cc = c
-                if ut == dt.FLOAT64 and c.dtype != dt.FLOAT64 and \
-                        uop == "sum":
-                    cc = Column(dt.FLOAT64, c.data.astype(jnp.float64),
-                                c.validity)
-                specs.append(agg_k.AggSpec(uop, cc))
-        out_keys, out_aggs, n_groups = agg_k.groupby_aggregate(
-            key_cols, specs, local_n, w_cap)
+        with _scope(_GROUPBY, "partial_agg"):
+            out_keys, out_aggs, n_groups = agg_k.groupby_aggregate(
+                key_cols, _update_specs(plan, val_cols), local_n, w_cap)
 
         # 2. route partials to their owners
-        pids = jnp.mod(jnp.mod(murmur3_batch(out_keys, w_cap), n) + n, n)
+        with _scope(_GROUPBY, "bucket"):
+            pids = jnp.mod(jnp.mod(murmur3_batch(out_keys, w_cap), n) + n,
+                           n)
         live = jnp.arange(w_cap) < n_groups
         payload = _column_arrays(out_keys) + _column_arrays(out_aggs)
-        stacked, counts = bucket_rows_for_exchange(payload, pids, live, n,
-                                                   w_cap)
-        moved, moved_counts = exchange(stacked, counts, "workers")
-        flat, recv_n = flatten_received(moved, moved_counts, recv_cap)
+        flat, recv_n = _route(_GROUPBY, payload, pids, live, n, w_cap,
+                              recv_cap)
 
         # 3. merge received partials INTO the accumulator: concatenate the
         # accumulator block with the received block (both prefix-live in
@@ -770,14 +904,15 @@ def distributed_groupby_round_fn(mesh: Mesh, key_dtypes, val_dtypes,
             return Column(a.dtype,
                           jnp.concatenate([a.data, b.data]),
                           jnp.concatenate([a.validity, b.validity]))
-        m_keys = [cat(a, b) for a, b in zip(acc_keys, recv_keys)]
-        m_aggs = [cat(a, b) for a, b in zip(acc_aggs, recv_aggs)]
-        live_mask = jnp.concatenate([jnp.arange(acc_cap) < acc_n,
-                                     jnp.arange(recv_cap) < recv_n])
-        mspecs = [agg_k.AggSpec(mop, c)
-                  for mop, c in zip(merge_ops, m_aggs)]
-        f_keys, f_aggs, f_groups = agg_k.groupby_aggregate(
-            m_keys, mspecs, mid_cap, mid_cap, live_mask=live_mask)
+        with _scope(_GROUPBY, "merge_agg"):
+            m_keys = [cat(a, b) for a, b in zip(acc_keys, recv_keys)]
+            m_aggs = [cat(a, b) for a, b in zip(acc_aggs, recv_aggs)]
+            live_mask = jnp.concatenate([jnp.arange(acc_cap) < acc_n,
+                                         jnp.arange(recv_cap) < recv_n])
+            mspecs = [agg_k.AggSpec(mop, c)
+                      for mop, c in zip(merge_ops, m_aggs)]
+            f_keys, f_aggs, f_groups = agg_k.groupby_aggregate(
+                m_keys, mspecs, mid_cap, mid_cap, live_mask=live_mask)
 
         # 4. carry: groups compact to the front; the accumulator keeps the
         # first acc_cap slots and f_groups is returned UNclamped so the
@@ -809,20 +944,8 @@ def _finalize_groupby_fn(mesh: Mesh, key_dtypes, val_dtypes, agg_ops,
         acc = args[:-1]
         keys = _rebuild_columns(key_dtypes, acc[:nk])
         aggs = _rebuild_columns(partial_dtypes, acc[nk:])
-        out_cols: List[Column] = []
-        ai = 0
-        for op, cols_plan in zip(agg_ops, plan):
-            if op == "avg":
-                s, c = aggs[ai], aggs[ai + 1]
-                valid = s.validity & (c.data > 0)
-                data = jnp.where(
-                    valid,
-                    s.data / jnp.maximum(c.data.astype(jnp.float64), 1.0),
-                    0.0)
-                out_cols.append(Column(dt.FLOAT64, data, valid))
-            else:
-                out_cols.append(aggs[ai])
-            ai += len(cols_plan)
+        with _scope(_GROUPBY, "merge_agg"):
+            out_cols = _finalize_aggs(agg_ops, plan, aggs)
         out = _column_arrays(keys) + _column_arrays(out_cols)
         return tuple(a[None] for a in out)
 
@@ -881,11 +1004,13 @@ def run_distributed_groupby_streaming(mesh: Mesh,
                 arrs.extend(c.arrays())
             win_arrays.append(arrs)
             counts.append(take)
-        outs = _call_spmd("groupby-round", fn, _place_shards(
-            mesh, win_arrays) + [_place_counts(mesh, counts), *acc, acc_n])
+        outs, overflow = run_stage(
+            "groupby-round", mesh, fn, win_arrays, counts,
+            _groupby_slot_bytes(win_arrays[0], 2 * len(key_idx),
+                                partial_dtypes, w_cap),
+            carried=[*acc, acc_n])
         acc = list(outs[:-1])
         acc_n_dev = outs[-1]
-        overflow = np.asarray(acc_n_dev)
         if (overflow > acc_cap).any():
             raise RuntimeError(
                 f"streaming group-by accumulator overflow: a worker owns "
@@ -902,7 +1027,9 @@ def run_distributed_groupby_streaming(mesh: Mesh,
     agg_out_dtypes = output_dtypes(agg_ops, val_dtypes)
     fields = [dt.Field(f"k{i}", t) for i, t in enumerate(key_dtypes)]
     fields += [dt.Field(f"a{i}", t) for i, t in enumerate(agg_out_dtypes)]
-    return _exchanged_batches(mesh, list(outs) + [acc_n], dt.Schema(fields),
+    return _exchanged_batches(mesh, list(outs) + [acc_n],
+                              np.minimum(overflow, acc_cap),
+                              dt.Schema(fields),
                               list(key_dtypes) + agg_out_dtypes)
 
 
@@ -1028,11 +1155,15 @@ def run_distributed_groupby(mesh: Mesh, batches: List[ColumnarBatch],
          tuple(agg_ops), cap),
         lambda: distributed_groupby_fn(mesh, key_dtypes, val_dtypes,
                                        agg_ops, cap))
-    outs = _call_spmd("groupby", fn, _place_shards(
-        mesh, _shard_arrays(batches, cap, key_idx + val_idx)) + [
-        _place_counts(mesh, [b.num_rows for b in batches])])
+    per_worker = _shard_arrays(batches, cap, key_idx + val_idx)
+    nk = sum(3 if t.var_width else 2 for t in key_dtypes)
+    partial_dtypes = [t for cols in _update_plan(agg_ops, val_dtypes)
+                      for (_op, t) in cols]
+    outs, sizes = run_stage(
+        "groupby", mesh, fn, per_worker, [b.num_rows for b in batches],
+        _groupby_slot_bytes(per_worker[0], nk, partial_dtypes, cap))
     agg_out_dtypes = output_dtypes(agg_ops, val_dtypes)
     fields = [dt.Field(f"k{i}", t) for i, t in enumerate(key_dtypes)]
     fields += [dt.Field(f"a{i}", t) for i, t in enumerate(agg_out_dtypes)]
-    return _exchanged_batches(mesh, outs, dt.Schema(fields),
+    return _exchanged_batches(mesh, outs, sizes, dt.Schema(fields),
                               list(key_dtypes) + agg_out_dtypes)

@@ -17,14 +17,15 @@ from typing import List, Optional, Sequence, Tuple
 
 from ..analysis.contracts import exec_contract
 from ..columnar import dtypes as dt
-from ..columnar.batch import ColumnarBatch
-from ..columnar.column import Column, bucket
+from ..columnar.batch import ColumnarBatch, resolve_counts
+from ..columnar.column import MIN_CAPACITY, bucket
+from ..exec.tasks import run_partition_tasks
 from ..ops import expressions as ex
 from ..ops import kernels as K
 from ..plan import logical as lp
 from ..plan.physical import (Partition, TpuExec, TpuShuffledJoinExec,
-                             accumulate_spillable, bind_refs,
-                             concat_spillable, exec_metrics)
+                             bind_refs, concat_spillable, drain_spillable,
+                             exec_metrics)
 from . import mesh as M
 from ..exec.tracing import trace_span
 
@@ -33,21 +34,64 @@ from ..exec.tracing import trace_span
 MESH_AGG_OPS = ("sum", "count", "count_star", "avg", "min", "max")
 
 
+#: factor between the capacity classes an SPMD stage's input can take
+SHRINK_STEP = 64
+
+
+def stage_capacity(static_cap: int, live_rows: int) -> int:
+    """The capacity class of an SPMD stage's per-worker input: the one its
+    child's batches came in (their static bound: a filter keeps its
+    input's capacity, whatever it selects), divided by :data:`SHRINK_STEP`
+    as often as the live rows then still leave half of it empty.
+
+    Why it shrinks at all: every stage receives into ``n * cap`` rows, so
+    the static bound alone grows n-fold per chained stage (a 2 Mi-row join
+    output under a filter, a group-by and a sort on four workers: 2 Mi,
+    8 Mi, 32 Mi rows a worker for the few thousand that are live). Why in
+    steps this wide: the class is part of every program of the stage and
+    of the ones downstream, and the live rows follow what the query's
+    literals select. A workload whose rows vary by a factor r from draw
+    to draw straddles a boundary of power-of-two classes with probability
+    ~log2(r), of these with ~log2(r)/6: a rebuilt stage (seconds to
+    minutes of compile) is traded for running over at most 128 times the
+    live rows (PERF.md section 6, PR 27, has both sides measured)."""
+    cap = static_cap
+    while cap // SHRINK_STEP >= max(2 * live_rows, MIN_CAPACITY):
+        cap //= SHRINK_STEP
+    return cap
+
+
 def shard_for_mesh(child: TpuExec, n: int) -> List[ColumnarBatch]:
-    """Drain the child and split it into n equal-row shards at one common
-    capacity (uniform shapes are what lets the whole stage trace once).
-    The concat stages through spillable handles; the resulting shards are
-    the per-worker inputs of the fused SPMD stage."""
-    batch = concat_spillable(child.schema,
-                             accumulate_spillable(child.execute()))
+    """Drain the child into n per-worker shards at one common capacity
+    (uniform shapes are what lets the whole stage trace once), chosen by
+    :func:`stage_capacity` from the capacities the child's batches have
+    and ONE readback of their row counts. A child that already comes in n
+    partitions (a mesh stage under a filter or a project) hands worker w
+    its w-th partition as it is; any other is concatenated and cut into n
+    runs of equal rows. The drain stages through spillable handles."""
+    schema = child.schema
+    per_part = run_partition_tasks(
+        child.execute(), lambda _pid, part: drain_spillable(part))
+    if len(per_part) != n:
+        per_part = [[s for part in per_part for s in part]]
+    merged = [concat_spillable(schema, part, by_capacity=True)
+              for part in per_part]
+    resolve_counts(merged)
+    if len(merged) == n:
+        cap = stage_capacity(max(b.capacity for b in merged),
+                             max(b.num_rows for b in merged))
+        return [b if b.capacity == cap else ColumnarBatch(
+            schema, [K.rebucket_column(c, b.num_rows, cap)
+                     for c in b.columns], b.num_rows) for b in merged]
+    batch, = merged
     per = -(-batch.num_rows // n) if batch.num_rows else 0
-    cap = bucket(max(per, 1))
+    cap = stage_capacity(bucket(-(-batch.capacity // n)), per)
     shards = []
     for w in range(n):
         lo = min(w * per, batch.num_rows)
         take = max(0, min(per, batch.num_rows - lo))
         cols = [K.slice_column(c, lo, cap, take) for c in batch.columns]
-        shards.append(ColumnarBatch(batch.schema, cols, take))
+        shards.append(ColumnarBatch(schema, cols, take))
     return shards
 
 
@@ -246,21 +290,13 @@ class TpuMeshJoinExec(TpuShuffledJoinExec):
         for shard in shards:
             extb, positions = _append_eval_columns(shard, part_keys)
             ext.append(extb)
-        co = M.run_copartition_exchange(self.mesh, ext, positions)
+        with trace_span("mesh_exchange", self.metrics, "meshExchangeTime"):
+            co = M.run_copartition_exchange(self.mesh, ext, positions)
         return [ColumnarBatch(child.schema, b.columns[:n_payload], b.num_rows)
                 for b in co]
 
     def execute(self) -> List[Partition]:
-        import time as _time
-        t0 = _time.perf_counter()
-        with trace_span("mesh_exchange", self.metrics, "meshExchangeTime"):
-            l_co = self._copartition(self.children[0], self.part_left_keys)
-            r_co = self._copartition(self.children[1], self.part_right_keys)
-        # the copartition all_to_all IS an ICI shuffle exchange: account
-        # it in the process plane totals next to TpuShuffleExchangeExec
-        # (shuffle/exchange.note_plane -> tpu_shuffle_gbps{plane=ici})
-        from ..shuffle.exchange import note_plane
-        moved = sum(b.device_size_bytes() for b in l_co + r_co)
-        note_plane("ici", moved, _time.perf_counter() - t0)
+        l_co = self._copartition(self.children[0], self.part_left_keys)
+        r_co = self._copartition(self.children[1], self.part_right_keys)
         return [self._join_copart(iter([lb]), iter([rb]))
                 for lb, rb in zip(l_co, r_co)]

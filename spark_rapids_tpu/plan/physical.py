@@ -391,15 +391,20 @@ def accumulate_spillable(parts) -> List["SpillableColumnarBatch"]:
 
 
 def concat_spillable(schema: dt.Schema,
-                     spillables: List["SpillableColumnarBatch"]
-                     ) -> ColumnarBatch:
+                     spillables: List["SpillableColumnarBatch"],
+                     by_capacity: bool = False) -> ColumnarBatch:
     """Materialize accumulated spillables and concatenate, reserving device
-    room for inputs + output first."""
+    room for inputs + output first. ``by_capacity``: the output's capacity
+    class comes from the inputs' capacities, not from their row counts (a
+    class that what a query's literals select cannot move)."""
     total = sum(s.size_bytes for s in spillables)
     _reserve(2 * total)
     batches = [s.get_batch() for s in spillables]
     for s in spillables:
         s.close()
+    if by_capacity and len(batches) > 1:
+        return concat_batches(schema, batches, target_capacity=bucket(
+            sum(b.capacity for b in batches)))
     return concat_batches(schema, batches)
 
 
@@ -661,11 +666,15 @@ def _schema_sig(schema: dt.Schema) -> tuple:
     return tuple(f.dtype.name for f in schema)
 
 
-def _expr_cache_key(e: ex.Expression):
+def _expr_cache_key(e: ex.Expression, traced: frozenset = frozenset()):
     """Structural cache key covering every instance attribute (reprs alone
     are not faithful — e.g. Like's pattern is not in its repr). Returns None
     when an attribute is opaque (unkeyable): the stage then jits per-exec
-    instead of sharing the global cache."""
+    instead of sharing the global cache. ``traced``: ids of the string
+    literals the caller's program takes as arguments
+    (``ex.traced_literal_ids``): their value is no part of the program."""
+    if id(e) in traced:
+        return ("strarg", f"a{e.trace_pos}")
     if isinstance(e, ex.Parameter):
         # a traceable parameter's VALUE is a runtime argument, never part
         # of the compiled program: two plans differing only in bound
@@ -688,7 +697,7 @@ def _expr_cache_key(e: ex.Expression):
         if k == "children":
             continue
         if isinstance(v, ex.Expression):
-            sub = _expr_cache_key(v)
+            sub = _expr_cache_key(v, traced)
             if sub is None:
                 return None
             parts.append((k, sub))
@@ -698,7 +707,7 @@ def _expr_cache_key(e: ex.Expression):
             return None
         parts.append((k, r))
     for c in e.children:
-        sub = _expr_cache_key(c)
+        sub = _expr_cache_key(c, traced)
         if sub is None:
             return None
         parts.append(sub)
@@ -787,7 +796,9 @@ class FusedStage:
             fn = self._fns.get(bool(donate))
             if fn is None:
                 if self._ekeys is None:
-                    self._ekeys = [_expr_cache_key(e) for e in self.exprs]
+                    traced = ex.traced_literal_ids(self._params)
+                    self._ekeys = [_expr_cache_key(e, traced)
+                                   for e in self.exprs]
                 ekeys = self._ekeys
                 if any(k is None for k in ekeys):
                     # unkeyable: per-exec jit, same Program boundary

@@ -95,14 +95,15 @@ class StageChain:
         is unkeyable (the stage then stays on the per-op path — a per-exec
         jit of a multi-op chain would recompile per query)."""
         parts: List[tuple] = []
+        traced = ex.traced_literal_ids(self.params)
         for step in self.steps:
             if step[0] == "filter":
-                k = _expr_cache_key(step[1])
+                k = _expr_cache_key(step[1], traced)
                 if k is None:
                     return None
                 parts.append(("filter", k))
             else:
-                ks = [_expr_cache_key(e) for e in step[1]]
+                ks = [_expr_cache_key(e, traced) for e in step[1]]
                 if any(k is None for k in ks):
                     return None
                 parts.append(("project", tuple(ks),

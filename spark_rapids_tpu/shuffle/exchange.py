@@ -676,7 +676,6 @@ class TpuShuffleExchangeExec(TpuExec):
         from ..parallel.mesh_exec import shard_for_mesh
         mesh = self.mesh
         n = int(mesh.devices.size)
-        t0 = time.perf_counter()
         with trace_span("shuffle_write", self.metrics, "shuffleWriteTime"):
             shards = shard_for_mesh(self.children[0], n)
             moved = 0
@@ -688,8 +687,9 @@ class TpuShuffleExchangeExec(TpuExec):
             pids = [partitioner.partition_ids(s) for s in shards]
             results = self._ici_results = M.run_partition_exchange(
                 mesh, shards, pids, self.num_partitions)
+        # the process plane totals are fed where the stage runs
+        # (parallel/mesh.run_stage), as for every SPMD stage
         self.metrics.inc("iciExchanges")
-        note_plane("ici", moved, time.perf_counter() - t0)
         # stage-boundary statistics from the ONE counts readback that
         # already came home: per-partition rows are the column sums of
         # the [n, num_partitions] counts; bytes are estimated from the

@@ -73,12 +73,26 @@ _LONGEST_FIRST = ("test_zz_recompile_gate", "test_zz_collect_iter",
                   "test_tpcds_queries")
 
 
+#: a file that asserts on process-wide state (the device watermark's peak
+#: and the operator that owns it) and passes only where no earlier file
+#: has left batches registered for a later release to undercut: it opens
+#: the run, on a worker that has run nothing (one of the first six files)
+_ON_A_FRESH_WORKER = ("test_telemetry",)
+
+#: files newer than the schedule the other files are known to pass under:
+#: run after them, so that no older file changes the worker it shares (the
+#: suite holds a pair that must not share one, see tests/perfbench/conftest)
+_LAST = ("test_mesh_counters", "test_string_literal_args")
+
+
 def pytest_collection_modifyitems(config, items):
     """`--dist loadfile` hands files to workers in collection order. Left
     alphabetical, the longest files start last and one worker grinds
     through them for minutes after the other five are done; started
     first, the short files pack around them. (Stable: nothing else moves.)"""
-    items.sort(key=lambda it: it.module.__name__ not in _LONGEST_FIRST)
+    items.sort(key=lambda it: (it.module.__name__ not in _ON_A_FRESH_WORKER,
+                               it.module.__name__ not in _LONGEST_FIRST,
+                               it.module.__name__ in _LAST))
 
 
 def pytest_configure(config):
