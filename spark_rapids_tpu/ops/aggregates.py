@@ -6,8 +6,9 @@ each Spark aggregate decomposes into ``CudfAggregate`` update/merge pairs
 merge-aggregation across batches, aggregate.scala:305-560).
 
 TPU-first design (DESIGN.md §3): no device hash tables. Group-by is sort-based:
-  lexsort rows by the group keys -> segment-start flags -> segment ids ->
-  ``jax.ops.segment_*`` reductions with num_segments = capacity (static shape).
+  lexsort rows by the group keys -> segment-start flags -> segment ids,
+  carried back to the rows where they lie -> segment reductions of the
+  UNSORTED input columns with num_segments = capacity (static shape).
 Group count travels as a device scalar; group keys are the key values at segment
 starts, compacted to the front. SQL null semantics: aggregates skip NULL inputs;
 an all-NULL (or empty) group yields NULL for sum/min/max/avg and 0 for count.
@@ -288,10 +289,13 @@ def groupby_aggregate(key_cols: Sequence[Column], specs: Sequence[AggSpec],
     order = K.sort_indices(sort_keys, num_rows, capacity,
                            live_mask=live_mask)
     sorted_keys = [K.gather_column(c, order) for c in key_cols]
-    live = jnp.arange(capacity) < num_rows
     starts = K.segment_starts_from_sorted_keys(sorted_keys, num_rows, capacity)
-    seg_ids = K.segment_ids(starts)
     n_groups = jnp.sum(starts).astype(jnp.int32)
+    # The ids go to the rows, not each aggregate's input to the ids (0.4 s a
+    # float64 column of 8 Mi rows on a v5e; a reduction takes any row order).
+    # first / last pick by ORIGINAL row: the sort's pick, since it is stable.
+    seg_ids = K.segment_ids_by_row(K.segment_ids(starts), order)
+    live = jnp.arange(capacity) < num_rows if live_mask is None else live_mask
 
     # group keys: gather the first row of each segment to the front
     with jax.named_scope("segment_starts"):
@@ -300,34 +304,30 @@ def groupby_aggregate(key_cols: Sequence[Column], specs: Sequence[AggSpec],
     out_keys = [K.gather_column(c, start_perm, out_valid=group_live)
                 for c in sorted_keys]
 
+    def reduce_all(num_segments: int, known_groups=None):
+        # mask agg slots beyond the group count (paranoia: segment ids of
+        # padding rows alias a real group, so data is fine; but enforce
+        # the padding invariant explicitly)
+        return [_mask_to(_pad_slots(segment_aggregate(
+            s, seg_ids, live, capacity, num_segments, known_groups),
+            capacity), group_live).arrays() for s in specs]
+
     # The choice the data makes, on the device: few groups take no scatter
     # (the 64-bit scatter-add serialises on a TPU, see the top of the file).
-    # One ``cond`` per aggregate, each on the same count: one round the whole
-    # loop keeps every sorted input alive at once (1.2 GB of temporaries at
-    # 8 Mi rows where this takes 0.55 GB, as the parent did), and the gather
-    # stays outside it (inside a branch it ran 35% slower on the chip).
+    # ONE ``cond`` round all the aggregates, no sorted copies being left to
+    # keep alive across it: q1 takes 2.34 s on a v5e where one per aggregate
+    # takes 2.75 (the same key gathers, scheduled worse); the price is the
+    # scatter branch's temporaries, 452 MB for five aggregates of 8 Mi rows
+    # where one per aggregate holds 142 (PERF.md section 6, PR 28).
     few = min(capacity, FEW_GROUPS_MAX)
-
-    def reduce_into(s: AggSpec, num_segments: int, known_groups=None):
-        agg = segment_aggregate(s, seg_ids, live, capacity, num_segments,
-                                known_groups)
-        # mask agg slots beyond the group count (paranoia: segment ids of
-        # padding rows alias the last group, which is a real group, so data
-        # is fine; but enforce the padding invariant explicitly)
-        return _mask_to(_pad_slots(agg, capacity), group_live).arrays()
-
-    out_aggs: List[Column] = []
-    for spec in specs:
-        s = spec
-        if spec.column is not None:
-            s = spec._replace(column=K.gather_column(spec.column, order))
-        if few == capacity:         # the smallest bucket: nothing to choose
-            arrays = reduce_into(s, few, n_groups)
-        else:
-            arrays = jax.lax.cond(n_groups <= few,
-                                  lambda: reduce_into(s, few, n_groups),
-                                  lambda: reduce_into(s, capacity))
-        out_aggs.append(build_column(_agg_dtype(spec), arrays)[0])
+    if few == capacity:             # the smallest bucket: nothing to choose
+        arrays = reduce_all(few, n_groups)
+    else:
+        arrays = jax.lax.cond(n_groups <= few,
+                              lambda: reduce_all(few, n_groups),
+                              lambda: reduce_all(capacity))
+    out_aggs = [build_column(_agg_dtype(s), a)[0]
+                for s, a in zip(specs, arrays)]
     return out_keys, out_aggs, n_groups
 
 

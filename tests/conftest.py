@@ -67,10 +67,21 @@ jax.config.update("jax_enable_compilation_cache", False)
 import pytest  # noqa: E402
 
 
-#: files that are ONE long corpus test each (4-8 minutes, not splittable
-#: without changing what they assert) or the longest golden suite
-_LONGEST_FIRST = ("test_zz_recompile_gate", "test_zz_collect_iter",
-                  "test_tpcds_queries")
+#: the files that cost a worker most, dearest first (seconds of one worker
+#: of six in the sandbox's run of PR 28: 580, 517, 516, 390 ... 113; the
+#: two after the first are ONE long corpus test each, not splittable
+#: without changing what they assert). Started first, the short files pack
+#: around them: 1214 s by that run's durations where the order by number
+#: of tests (below) gives 1461 and took 1525
+_LONGEST_FIRST = (
+    "test_tpcds_queries", "test_zz_collect_iter", "test_zz_recompile_gate",
+    "test_zz_aqe_parity_s1", "test_zz_aqe_parity_s2", "test_sql_tpch",
+    "test_zz_serving_parity", "test_zz_aqe_parity",
+    "test_zz_serving_parity_s2", "test_zz_serving_parity_s1",
+    "test_zz_fusion_parity_s1", "test_zz_fusion_parity_s2",
+    "test_zz_fusion_parity", "test_zz_ledger_corpus_s1",
+    "test_zz_ledger_corpus_s2", "test_zz_ledger_corpus",
+    "test_tpch_queries", "test_distributed_plan")
 
 
 #: a file that asserts on process-wide state (the device watermark's peak
@@ -90,14 +101,24 @@ def pytest_collection_modifyitems(config, items):
     alphabetical, the longest files start last and one worker grinds
     through them for minutes after the other five are done; started
     first, the short files pack around them. (Stable: nothing else moves.)"""
+    def rank(name):
+        return (_LONGEST_FIRST.index(name) if name in _LONGEST_FIRST
+                else len(_LONGEST_FIRST))
     items.sort(key=lambda it: (it.module.__name__ not in _ON_A_FRESH_WORKER,
-                               it.module.__name__ not in _LONGEST_FIRST,
+                               rank(it.module.__name__),
                                it.module.__name__ in _LAST))
 
 
 def pytest_configure(config):
     config.addinivalue_line(
         "markers", "slow: excluded from the tier-1 `-m 'not slow'` gate")
+    # xdist (3.8) re-sorts the files of `--dist loadfile` by their NUMBER
+    # of tests, which undoes the order above: the one-test corpus files,
+    # eight minutes each, then start last and the run waits for them alone
+    # (1470 s were not enough, twice, in PR 28's sandbox), and
+    # test_telemetry lands in the middle of a worker's files.
+    if hasattr(config.option, "loadscopereorder"):
+        config.option.loadscopereorder = False
 
 
 @pytest.fixture(scope="module", autouse=True)

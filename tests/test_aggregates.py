@@ -361,6 +361,10 @@ _FEW_SPECS = [
     ("min_bool", "min", "b", True), ("max_bool", "max", "b", True),
     ("min_str", "min", "s", True), ("first", "first", "v", True),
     ("last", "last", "i", True), ("first_with_nulls", "first", "v", False),
+    # PR 28: the reductions read rows where they lie; first / last pick by
+    # ORIGINAL row over groups of repeated keys and distinct values
+    ("last_with_nulls", "last", "v", False), ("first_i64", "first", "i", True),
+    ("last_f64", "last", "v", True), ("max_str", "max", "s", True),
 ]
 
 
@@ -406,6 +410,8 @@ def _few_and_scatter(n_groups, masked):
     choice taken away (the scatter path, as before PR 26)."""
     import jax.numpy as jnp
     cols, gid, (v, v_null), live = _few_data(n_groups)
+    i_vals = np.array(cols["i"].to_pylist(_FEW_ROWS), dtype=object)
+    i_null = np.array([x is None for x in i_vals])
     specs = [AggSpec(op, cols[c] if c else None, ignore_nulls=ign)
              for _n, op, c, ign in _FEW_SPECS]
     mask = None
@@ -433,12 +439,31 @@ def _few_and_scatter(n_groups, masked):
     order = ([n_groups - 1] if n_groups > 1 else []) + \
         list(range(n_groups - 1 if n_groups > 1 else 1))
     sums = [v[(gid == g) & live & ~v_null].sum() for g in order]
-    return chosen, scatter, np.array(sums)
+
+    def pick(vals, null, last, ignore_nulls):
+        """numpy's first / last: (value, valid) per group, by row index."""
+        out = []
+        for g in order:
+            rows = np.flatnonzero((gid == g) & live &
+                                  (~null if ignore_nulls else True))
+            r = rows[-1 if last else 0] if len(rows) else None
+            out.append((0, False) if r is None or null[r]
+                       else (vals[r], True))
+        return out
+    picks = {"first": pick(v, v_null, False, True),
+             "last": pick(i_vals, i_null, True, True),
+             "first_with_nulls": pick(v, v_null, False, False),
+             "last_with_nulls": pick(v, v_null, True, False),
+             "first_i64": pick(i_vals, i_null, False, True),
+             "last_f64": pick(v, v_null, True, True)}
+    return chosen, scatter, np.array(sums), picks
 
 
 @pytest.mark.parametrize("n_groups, masked", [
-    (1, False), (_S - 1, False), (_S - 1, True), (_S, True), (_S + 1, False)],
-    ids=["1", "S-1", "S-1_live_mask", "S_live_mask", "S+1"])
+    (1, False), (_S - 1, False), (_S - 1, True), (_S, True), (_S + 1, False),
+    (4, True), (_S + 1, True)],
+    ids=["1", "S-1", "S-1_live_mask", "S_live_mask", "S+1", "4_live_mask",
+         "S+1_live_mask"])
 @pytest.mark.parametrize(
     "which", range(len(_FEW_SPECS) + 1),
     ids=["keys"] + [name for name, *_ in _FEW_SPECS])
@@ -447,7 +472,7 @@ def test_few_groups_equal_the_scatter_path(which, n_groups, masked):
     it the scatter: either way every slot of every output array equals the
     scatter path's — bit for bit but for float64 sums, which are held to
     the scatter's chain of adds at 1e-13 and to numpy's sum at 1e-15."""
-    chosen, scatter, np_sums = _few_and_scatter(n_groups, masked)
+    chosen, scatter, np_sums, np_picks = _few_and_scatter(n_groups, masked)
     name = "keys" if which == 0 else _FEW_SPECS[which - 1][0]
     for got, want in zip(chosen[which], scatter[which]):
         if name in ("sum_f64", "avg") and got.dtype == np.float64:
@@ -458,13 +483,54 @@ def test_few_groups_equal_the_scatter_path(which, n_groups, masked):
     if name == "sum_f64":
         data, valid = chosen[which]
         finite = np.isfinite(np_sums) & (np_sums != 0)
-        assert finite.sum() >= max(1, n_groups * 3 // 4)
+        assert finite.sum() >= max(1, (n_groups - 3) * 3 // 4)
         np.testing.assert_allclose(data[:n_groups][finite], np_sums[finite],
                                    rtol=1e-15)
         # the NaN and the inf stayed in their own groups
         assert np.array_equal(np.isnan(data[:n_groups]), np.isnan(np_sums))
         assert np.array_equal(np.isinf(data[:n_groups]), np.isinf(np_sums))
         assert not valid[n_groups:].any() and not data[n_groups:].any()
+    if name in np_picks:
+        data, valid = chosen[which]
+        want_data, want_valid = zip(*np_picks[name])
+        assert list(valid[:n_groups]) == list(want_valid)
+        np.testing.assert_array_equal(      # a NaN may be the pick
+            data[:n_groups][list(want_valid)],
+            np.array([x for x, ok in np_picks[name] if ok], data.dtype))
+
+
+@pytest.mark.parametrize("ignore_nulls", [True, False],
+                         ids=["ignore_nulls", "respect_nulls"])
+@pytest.mark.parametrize("op", ["first", "last"])
+def test_first_last_pick_what_gather_then_reduce_picked(op, ignore_nulls):
+    """Until PR 28 the group-by gathered every input into sort order and
+    picked first / last by SORTED position; it now picks by original row.
+    The two agree because ``sort_indices`` is stable (every pass of
+    ``_lexsort_passes`` is): pinned here against the old form, over groups
+    of repeated keys and distinct values with dead rows between live ones."""
+    import jax.numpy as jnp
+    from spark_rapids_tpu.ops import kernels as K
+    cols, _gid, _v, live = _few_data(7)
+    mask = jnp.asarray(np.concatenate(
+        [live, np.zeros(_FEW_CAP - _FEW_ROWS, bool)]))
+    spec = AggSpec(op, cols["v"], ignore_nulls=ignore_nulls)
+    _keys, (got,), ng = groupby_aggregate([cols["k"]], [spec], _FEW_ROWS,
+                                          _FEW_CAP, live_mask=mask)
+    # the old form: rows to the ids
+    n_live = jnp.sum(mask).astype(jnp.int32)
+    order = K.sort_indices([K.SortKey(cols["k"])], n_live, _FEW_CAP,
+                           live_mask=mask)
+    starts = K.segment_starts_from_sorted_keys(
+        [K.gather_column(cols["k"], order)], n_live, _FEW_CAP)
+    want = agg_k.segment_aggregate(
+        spec._replace(column=K.gather_column(cols["v"], order)),
+        K.segment_ids(starts), jnp.arange(_FEW_CAP) < n_live, _FEW_CAP)
+    g = int(ng)
+    assert g == 7
+    for a, b in zip(got.arrays(), want.arrays()):
+        np.testing.assert_array_equal(np.asarray(a)[:g], np.asarray(b)[:g])
+    picked = [x for x in got.to_pylist(g) if x is not None]
+    assert len(set(picked)) == len(picked) >= 3     # distinct values
 
 
 @pytest.mark.parametrize("counted", [False, True],

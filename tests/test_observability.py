@@ -459,8 +459,9 @@ def _group_by_program_text(conf, query=_tiny_q1):
 
 @pytest.mark.parametrize("matmul, present, absent", [
     # both ways of the choice the device makes by the group count
-    ("false", ("lexsort", "gather", "segment_starts", "segment_sum_masked",
-               "segment_sum_scatter"), ("segment_sum_matmul",)),
+    ("false", ("lexsort", "gather", "segment_starts", "segment_ids_to_rows",
+               "segment_sum_masked", "segment_sum_scatter"),
+     ("segment_sum_matmul",)),
     # what ``auto`` picks on an accelerator; forced, on this CPU
     ("true", ("lexsort", "gather", "segment_starts",
               "segment_sum_matmul"), ()),
@@ -488,6 +489,66 @@ def test_group_by_program_names_its_stages_under_the_operator(
     # the folded filter and projection keep their own operators' scopes
     assert "/TpuFilterExec/filter/" in text
     assert "jit(agg_update_" in text
+
+
+def _ops_by_scope(text, op):
+    """The name (scopes and all) jax gave every ``stablehlo.<op>`` of a
+    lowered text with debug info."""
+    import re
+    locs = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+    lines = text.splitlines()
+    names = []
+    for i, line in enumerate(lines):
+        if f"stablehlo.{op}" not in line:
+            continue
+        if line.endswith("({"):         # an op with a region: its end
+            line = next(after for after in lines[i + 1:]
+                        if after.lstrip().startswith("})"))
+        names.append(locs.get(re.search(r"loc\((#loc\d+)\)$", line)[1], ""))
+    return names
+
+
+def test_group_by_gathers_its_keys_and_no_aggregate_input(monkeypatch):
+    """The reductions of the sort-based group-by read their inputs in ROW
+    order (PR 28): in q1's update program the gathers into sort order are
+    those of the two string keys, with one aggregate or with eight, and
+    the one scatter outside the branches carries the segment ids to the
+    rows. q1's value gathers were 2.9 s of its 5.26 s on the chip."""
+    monkeypatch.setenv(
+        "SPARK_RAPIDS_TPU_CONF__SPARK__RAPIDS__TPU__SQL__AGG__MATMUL"
+        "__ENABLED", "false")
+    aggs = [F.sum("l_quantity"), F.sum("l_extendedprice"),
+            F.avg("l_discount"), F.min("l_tax"), F.max("l_quantity"),
+            F.count("l_tax"), F.first("l_extendedprice"), F.sum("l_tax")]
+
+    def grouped(n_aggs):
+        def query(session):
+            from benchmarks import datagen
+            t = datagen.register_tables(session,
+                                        3000 / datagen.LINEITEM_PER_SF)
+            return t["lineitem"].groupBy("l_returnflag", "l_linestatus").agg(
+                *[a.alias(f"a{i}") for i, a in enumerate(aggs[:n_aggs])])
+        return query
+
+    key_gathers = {}
+    for n_aggs in (1, len(aggs)):
+        text = "\n".join(_group_by_program_text({}, grouped(n_aggs)).values())
+        gathers = _ops_by_scope(text, "gather")
+        outside = [g for g in gathers if "/cond/" not in g
+                   and "/TpuHashAggregateExec/" in g]
+        assert all(g.endswith("/TpuHashAggregateExec/gather/gather")
+                   for g in outside), outside
+        # inside a branch: only first / last fetching each group's pick
+        assert all("/segment_minmax/gather/" in g for g in gathers
+                   if "/cond/" in g), gathers
+        key_gathers[n_aggs] = len(outside)
+        scatters = [s for s in _ops_by_scope(text, "scatter")
+                    if "/cond/" not in s and "/segment_starts/" not in s]
+        assert [s.split("/TpuHashAggregateExec/")[1] for s in scatters] == \
+            ["segment_ids_to_rows/scatter"], scatters
+    # two string keys (bytes, lengths, validity), into sort order and, of
+    # each group's first row, to the front
+    assert key_gathers == {1: 12, len(aggs): 12}
 
 
 def test_grouping_free_reduction_emits_no_scatter(monkeypatch):

@@ -210,13 +210,18 @@ def test_sort_groupby_with_matmul_reductions_compiles(one_chip):
 def test_few_group_masked_reductions_compile_with_no_scatter(one_chip):
     """q1's exact aggregates (float64 sum and avg, bigint sum, count) as
     the few-group branch of ``groupby_aggregate`` runs them, at the chip
-    run's 8 Mi-row batch: no scatter in what the TPU compiler builds, and
-    nothing of FEW_GROUPS_MAX x rows among its temporaries (128 x 8 Mi
-    float64 would be 8 GB)."""
+    run's 8 Mi-row batch and in ROW order (PR 28): the columns come as
+    they lie, the segment ids are carried to the rows. The one scatter the
+    TPU compiler builds is that of the int32 ids — no scatter-add — and
+    nothing of FEW_GROUPS_MAX x rows is among its temporaries (128 x 8 Mi
+    float64 would be 8 GB), nor a sorted copy of a column."""
     from spark_rapids_tpu.ops import aggregates as agg_k
+    from spark_rapids_tpu.ops import kernels as K
     cap = 1 << 23
 
-    def few(seg_ids, n_groups, num_rows, qty, qty_valid, price, price_valid):
+    def few(order, sorted_ids, n_groups, num_rows, qty, qty_valid, price,
+            price_valid):
+        seg_ids = K.segment_ids_by_row(sorted_ids, order)
         live = jnp.arange(cap) < num_rows
         qty = Column(dt.INT64, qty, qty_valid)
         price = Column(dt.FLOAT64, price, price_valid)
@@ -232,11 +237,15 @@ def test_few_group_masked_reductions_compile_with_no_scatter(one_chip):
 
     def s(npdt, shape=(cap,)):
         return jax.ShapeDtypeStruct(shape, npdt, sharding=one_chip)
-    compiled = _compile(few, s(jnp.int32), s(jnp.int32, ()),
+    compiled = _compile(few, s(jnp.int32), s(jnp.int32), s(jnp.int32, ()),
                         s(jnp.int32, ()), s(jnp.int64), s(jnp.bool_),
                         s(jnp.float64), s(jnp.bool_))
-    assert " scatter(" not in compiled.as_text()      # the HLO op
-    assert compiled.memory_analysis().temp_size_in_bytes < 4 * 8 * cap
+    scatters = [line for line in compiled.as_text().splitlines()
+                if " scatter(" in line]                 # the HLO op
+    assert len(scatters) == 1 and "s32[8388608]" in scatters[0], scatters
+    # 136.6 MB; with the four arrays gathered into sort order first, as
+    # before PR 28, 153.8 MB (this compiler, sandbox)
+    assert compiled.memory_analysis().temp_size_in_bytes < 18 * cap
 
 
 # ---------------------------------------------------------------------------
