@@ -15,7 +15,7 @@ against. The rule:
   ``fail`` at >= 25% worse, ``warn`` at >= 10% worse, ``improvement``
   when better, ``ok`` in between, ``no-baseline`` for a first round.
 
-``bench.py``, ``benchmarks/runner.py``, ``benchmarks/replay.py`` and the
+``benchmarks/runner.py``, ``benchmarks/replay.py`` and the
 multichip dryrun all stamp through :func:`stamp`; the verdicts ride the
 artifact JSON so a slow round is visible in the round itself.
 """
@@ -30,50 +30,15 @@ from typing import Dict, List, Optional
 WARN_PCT = 0.10
 FAIL_PCT = 0.25
 
-#: query name of the shuffle-exchange throughput series (GB/s moved
-#: through TpuShuffleExchangeExec, higher is better): stamped by bench.py
-#: inside the ``bench`` kind, so a shuffle-plane regression fails the
-#: same gate a pipeline-throughput regression does (docs/shuffle.md)
-SHUFFLE_GBPS = "shuffle_gbps"
-
-#: compile-time series stamped by bench.py (docs/compile.md): total
-#: first-call compile seconds of the cold engine run (COMPILE_S) and the
-#: wall seconds of a warm-restart child process replaying the same query
-#: against the same compile.cacheDir (WARM_RESTART_S). Both are
-#: lower-is-better INSIDE the otherwise higher-is-better ``bench`` kind —
-#: round_entry records the per-query direction override so the gate
-#: judges them correctly.
-COMPILE_S = "compile_s"
-WARM_RESTART_S = "warm_restart_s"
-
-#: whole-query orchestration series stamped by bench.py (ISSUE 11,
-#: docs/fusion.md): WHOLE_QUERY_GAP is the ratio of the fused-microbench
-#: Mrows/s to the warm engine q6 Mrows/s — the orchestration gap, judged
-#: as a lower-is-better series so the gate fails when whole-query
-#: throughput falls behind kernel throughput again. FUSION_AB_Q6 is the
-#: q6 fusion on/off A/B speedup (>= 1 means stage fusion pays), higher
-#: is better.
-WHOLE_QUERY_GAP = "whole_query_gap"
-FUSION_AB_Q6 = "fusion_ab_q6"
-
-#: serving front-door series stamped by bench.py (ISSUE 12,
-#: docs/plan_cache.md): PLAN_CACHE_PLANS_PER_S is the steady-state rate
+#: serving front-door series (ISSUE 12, docs/plan_cache.md; no harness
+#: stamps them today): PLAN_CACHE_PLANS_PER_S is the steady-state rate
 #: of plan-cache-served q6 executions with ROTATING literals (parse +
 #: analyze + rebind + execute per iteration; higher is better) —
 #: the plans/s the serving tier can sustain; WARM_TRAFFIC_Q6_S is the
 #: wall seconds of that warm literal-rotating traffic window (lower is
-#: better, the serving latency analog of warm_restart_s).
+#: better).
 PLAN_CACHE_PLANS_PER_S = "plan_cache_plans_per_s"
 WARM_TRAFFIC_Q6_S = "warm_traffic_q6_s"
-
-#: chaos-mode series stamped by bench.py (ISSUE 13, docs/resilience.md):
-#: wall seconds of a q6-shaped shuffled run completing UNDER injected
-#: faults (a failed fetch + a poisoned map batch absorbed by stage
-#: retry) with results identical to the fault-free run — lower is
-#: better, so a recovery-time regression fails the gate like any perf
-#: regression. Stamped only when the chaos honesty checks pass
-#: (identical rows, >=1 stage retry, every armed fault fired).
-CHAOS_Q6_RECOVERY_S = "chaos_q6_recovery_s"
 
 #: traffic-replay series stamped by benchmarks/replay.py (ISSUE 15,
 #: docs/service.md §7): REPLAY_QPS is completed queries per second of N
@@ -96,16 +61,6 @@ REPLAY_CHAOS_P99_S = "replay_chaos_p99_s"
 #: oracle-correct rows).
 REPLAY_PREEMPT_P99_S = "replay_preempt_p99_s"
 
-#: adaptive-execution series stamped by bench.py (ISSUE 16, docs/aqe.md):
-#: AQE_SKEW_Q3_S is the warm wall seconds of a deliberately skewed
-#: q3-shaped join+aggregate with the re-planner ON (lower is better);
-#: AQE_AB_Q3 is the AQE on/off wall ratio on that workload (lower is
-#: better; < 1 means adaptive re-planning pays for itself under skew).
-#: Stamped only when the bench's honesty checks pass (identical rows
-#: on/off, every decision rule applied and visible on every surface).
-AQE_SKEW_Q3_S = "aqe_skew_q3_s"
-AQE_AB_Q3 = "aqe_ab_q3"
-
 #: cold-path series stamped by benchmarks/runner.py --prewarm and
 #: benchmarks/replay.py (ISSUE 17, docs/compile.md §5): COLD_Q6_S is the
 #: FRESH-PROCESS wall seconds of q6 served with a warmed compile-cache
@@ -122,11 +77,9 @@ FIRST_ROW_P99_S = "first_row_p99_s"
 #: queries whose direction flips relative to their round's
 #: ``higherIsBetter`` flag (seconds-valued series riding a throughput
 #: round): recorded per entry so old history lines stay judgeable
-INVERTED_QUERIES = frozenset({COMPILE_S, WARM_RESTART_S, WHOLE_QUERY_GAP,
-                              WARM_TRAFFIC_Q6_S, CHAOS_Q6_RECOVERY_S,
+INVERTED_QUERIES = frozenset({WARM_TRAFFIC_Q6_S,
                               REPLAY_P50_S, REPLAY_P99_S,
                               REPLAY_CHAOS_P99_S, REPLAY_PREEMPT_P99_S,
-                              AQE_SKEW_Q3_S, AQE_AB_Q3,
                               COLD_Q6_S, FIRST_ROW_P99_S})
 
 #: default history file (each bench round is a fresh process; the file

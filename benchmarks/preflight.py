@@ -1,7 +1,7 @@
 """Device preflight for measurement entry points.
 
 A number printed under a device metric's name must come from the device.
-So ``bench.py``, ``benchmarks/runner.py`` and ``benchmarks/replay.py`` call
+So ``benchmarks/runner.py`` and ``benchmarks/replay.py`` call
 :func:`require_chip` before anything else, and a run that finds no TPU
 FAILS with the probe's error — there is no CPU fallback and no degraded
 label. The probe is a plain ``jax.devices()`` in the measuring process

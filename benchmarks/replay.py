@@ -52,13 +52,13 @@ import time
 from typing import Dict, List, Optional
 
 #: the default chaos spec for ``--faults default`` (one failed fetch +
-#: one poisoned map batch, the bench_chaos pair, absorbed by stage retry)
+#: one poisoned map batch, absorbed by stage retry)
 DEFAULT_FAULTS = "fetch.fail;task.poison"
 
 
 def _rows_close(a, b, rel_tol=1e-9) -> bool:
-    """Row-wise equality with fp tolerance (the bench.py rule: retries
-    and concurrent scheduling legally reorder float aggregation)."""
+    """Row-wise equality with fp tolerance (retries and concurrent
+    scheduling legally reorder float aggregation)."""
     if len(a) != len(b):
         return False
     for ra, rb in zip(a, b):

@@ -77,7 +77,7 @@ def reset_cache() -> None:
 
 def kernel_of(key: Any) -> str:
     """Kernel family of a fused-cache signature: the top-level string
-    tags joined (``concat``, ``project``, ``agg/update/partial/dense``,
+    tags joined (``concat``, ``project``, ``agg/update/partial/sort``,
     ...) — shapes/schemas live in nested tuples and stay out of the
     family name."""
     if isinstance(key, tuple):

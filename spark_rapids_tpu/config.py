@@ -551,12 +551,6 @@ MESH_STREAM_WINDOW_ROWS = _conf(
     "(WindowedBlockIterator.scala)"
 ).integer_conf.check(lambda v: int(v) >= 1024).create_with_default(1 << 17)
 
-MATMUL_AGG = _conf("spark.rapids.tpu.sql.agg.matmul.enabled").doc(
-    "MXU one-hot-matmul segment reductions for group-by sum/count/avg: "
-    "'auto' (accelerator only), 'true', or 'false'. Float sums differ from "
-    "sequential order at ~1e-5 rel — the variableFloatAgg trade "
-    "(ref: RapidsConf.scala variableFloatAgg)").string_conf.create_with_default("auto")
-
 HASH_OPTIMIZE_SORT = _conf("spark.rapids.tpu.sql.hashOptimizeSort.enabled").doc(
     "Insert a per-partition sort on hash-aggregate/join outputs so "
     "downstream file writes compress better (ref: "
@@ -565,13 +559,11 @@ HASH_OPTIMIZE_SORT = _conf("spark.rapids.tpu.sql.hashOptimizeSort.enabled").doc(
 ).boolean_conf.create_with_default(False)
 
 AGG_PIPELINE_DEPTH = _conf("spark.rapids.tpu.sql.agg.pipelineDepth").doc(
-    "Input batches kept in flight by the streaming aggregation before the "
-    "oldest batch's partial result is landed: probe-stat readbacks overlap "
-    "device compute across this window, hiding the host-sync latency of "
-    "each readback. The oldest half of the "
-    "window lands when it fills, so stat transfers get half a window of "
-    "dispatch work to hide behind. Device residency grows by one input "
-    "batch per slot"
+    "Depth of the streaming aggregation's deferred-scalar window "
+    "(exec/pipeline.PipelineWindow): entries that park a device scalar "
+    "land by halves of this depth, in one batched readback. The "
+    "group-by's one program leaves its count on the device and parks "
+    "none, so each partial lands as it is pushed whatever the depth"
 ).integer_conf.check(lambda v: int(v) >= 1).create_with_default(48)
 
 JOIN_PIPELINE_DEPTH = _conf("spark.rapids.tpu.sql.join.pipelineDepth").doc(

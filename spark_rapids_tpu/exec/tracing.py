@@ -73,8 +73,7 @@ def reset_cache() -> None:
 STAGES = (
     "scan_unpack", "filter", "project", "key_encode", "lexsort", "gather",
     "segment_starts", "segment_ids_to_rows", "segment_sum_scatter",
-    "segment_sum_masked", "segment_sum_matmul", "segment_sum_dense",
-    "segment_minmax", "reduce",
+    "segment_sum_masked", "segment_minmax", "reduce",
     "join_probe", "join_gather", "compact", "concat", "shuffle_split",
     # the steps of an SPMD mesh stage (parallel/mesh.py), outside the
     # kernels' own stages: ``TpuMeshGroupByExec/partial_agg/lexsort/...``

@@ -37,22 +37,6 @@ from . import logical as lp
 Partition = Iterator[ColumnarBatch]
 
 
-def _matmul_agg_enabled() -> bool:
-    """MXU matmul segment reductions: 'auto' enables on accelerator backends
-    only — float agg results differ from sequential sums at ~1e-5 rel (the
-    reference's variableFloatAgg stance); golden-compare tests run on the
-    exact CPU path."""
-    from .. import config as cfg
-    mode = str(cfg.TpuConf().get_key(
-        "spark.rapids.tpu.sql.agg.matmul.enabled", "auto")).lower()
-    if mode in ("true", "1"):
-        return True
-    if mode in ("false", "0"):
-        return False
-    import jax
-    return jax.devices()[0].platform != "cpu"
-
-
 # ---------------------------------------------------------------------------
 # Reference binding (GpuBindReferences / GpuBoundAttribute.scala)
 # ---------------------------------------------------------------------------
@@ -524,7 +508,7 @@ def _concat_fused(schema: dt.Schema, batches: List[ColumnarBatch],
 # expression node is a separate kernel launch, exactly the fusion gap
 # SURVEY.md §3.3 calls out in the reference's per-expression JNI launches).
 # A fused stage traces the WHOLE per-batch computation once per shape:
-# one (or two, for dispatched group-bys) device calls per batch.
+# one device call per batch.
 
 def _fusion_enabled(node) -> bool:
     flag = getattr(node, "_fusion", None)
@@ -843,20 +827,6 @@ class FusedStage:
 
 class _ScalarPredicate(Exception):
     pass
-
-
-def _dense_sig_supported(op: str, t) -> bool:
-    """Dtype-level mirror of aggregates._dense_spec_supported (the fused
-    path decides candidacy statically, before any column exists)."""
-    if op in ("count", "count_star"):
-        return True
-    if t is None:
-        return False
-    if op in ("sum", "avg"):
-        return t.is_integral or t == dt.BOOL or t.is_floating
-    if op in ("min", "max"):
-        return t != dt.STRING
-    return op in ("first", "last")
 
 
 # ---------------------------------------------------------------------------
@@ -1333,7 +1303,6 @@ class TpuHashAggregateExec(TpuExec):
         self.per_partition_final = per_partition_final
         self.grouping_src = grouping
         self.aggregate_exprs = aggregate_exprs
-        self._dense_state = {}   # dense-dispatch memo shared across batches
         # collect aggregate leaves across output expressions
         self.leaves: List[lp.AggregateExpression] = []
         for e in aggregate_exprs:
@@ -1429,15 +1398,13 @@ class TpuHashAggregateExec(TpuExec):
         merge cadence). All state lives in the spill catalog between
         batches, so aggregation residency stays bounded.
 
-        The update phase is PIPELINED on the shared deferred-scalar window
-        (exec/pipeline.PipelineWindow — the same primitive the join stream
-        loop uses): each input batch's fused probe is dispatched
-        immediately, its stats scalar parked on the window, and the kernel
-        half only runs once the window lands it — by then the stat
-        readback has resolved in ONE batched device_get with its
-        half-window peers, so the per-batch device->host round-trip (a
-        host sync each) overlaps compute instead of serializing the
-        stream."""
+        Each batch's partial goes through the shared deferred-scalar
+        window (exec/pipeline.PipelineWindow — the primitive the join
+        stream loop uses). No entry parks a scalar on it today (the whole
+        kernel is dispatched with its count left on the device), so each
+        lands as it is pushed; the one blocking read of the loop,
+        ``_shrink_fused``'s count of a large partial, is what could ride
+        the window's batched readback (ROADMAP, debts)."""
         from .. import config as cfg
         from ..exec.pipeline import PipelineWindow
         from ..exec.spill import SpillableColumnarBatch
@@ -1465,18 +1432,8 @@ class TpuHashAggregateExec(TpuExec):
             if len(pending) >= self.MERGE_FAN_IN:
                 merge_pending()
 
-        def finish(batch: ColumnarBatch, tok, stats=None) -> ColumnarBatch:
-            """Kernel half for one landed batch: ``stats`` is the
-            window-resolved probe readback (None if the batched get
-            failed — _fused_finish then re-reads and its handler degrades
-            this one batch to the eager path)."""
-            pb = self._fused_finish(tok, stats)
-            if pb is None:
-                return self._update_partial_eager(batch)
-            return self._shrink_fused(tok, pb)
-
         depth = max(1, int(cfg.TpuConf().get(cfg.AGG_PIPELINE_DEPTH)))
-        # metrics=: the window's batched stat readbacks charge THIS exec's
+        # metrics=: the window's batched readbacks charge THIS exec's
         # hostSyncs (exec/metrics.exec_scope), not just the span string
         win = PipelineWindow(depth, metrics=self.metrics)
         for batch in batches:
@@ -1492,17 +1449,11 @@ class TpuHashAggregateExec(TpuExec):
                     if tok is None:
                         pb = self._update_partial_eager(batch)
                         ready = win.push(lambda p=pb: p)
-                    elif tok[0] in ("dense", "sortmm"):
-                        # park the probe stats scalar on the window
-                        ready = win.push(
-                            lambda v, b=batch, t=tok: finish(b, t, v),
-                            tok[-1])
                     else:
-                        # 'done' / 'sorted': whole kernel already
-                        # dispatched, count device-resident — nothing to
-                        # resolve
+                        # whole kernel already dispatched, count
+                        # device-resident — nothing to resolve
                         ready = win.push(
-                            lambda b=batch, t=tok: finish(b, t))
+                            lambda t=tok: self._shrink_fused(*t))
                 for pb in ready:
                     bank(pb)
         with trace_span("aggregate", self.metrics, "computeAggTime"):
@@ -1549,11 +1500,17 @@ class TpuHashAggregateExec(TpuExec):
         if not self.grouping:
             aggs = agg_k.reduce_aggregate(specs, batch.num_rows, cap)
             return ColumnarBatch(self._partial_schema(), aggs, 1)
-        out_keys, aggs, n_groups = agg_k.groupby_aggregate_fast(
-            keys, specs, batch.num_rows, cap,
-            allow_matmul=_matmul_agg_enabled(), dense_state=self._dense_state)
+        out_keys, aggs, n_groups = self._groupby_eager(keys, specs, batch)
         return self._shrink_partial(
             ColumnarBatch(self._partial_schema(), out_keys + aggs, n_groups))
+
+    def _groupby_eager(self, keys, specs, batch: ColumnarBatch):
+        """The fused programs' kernel, called op by op on columns already
+        evaluated: what the eager fallback protects against is an
+        expression that will not trace, and the kernel always traces."""
+        out_keys, aggs, ng = agg_k.groupby_aggregate(
+            keys, specs, batch.num_rows, batch.capacity)
+        return out_keys, aggs, int(ng)  # lint: host-sync-ok eager-path group-count sync sizes the output bucket
 
     def _shrink_partial(self, batch: ColumnarBatch) -> ColumnarBatch:
         """Compact a partial batch to bucket(n_groups) capacity: group-by
@@ -1568,16 +1525,21 @@ class TpuHashAggregateExec(TpuExec):
                 for c in batch.columns]
         return ColumnarBatch(batch.schema, cols, batch.num_rows)
 
-    def _shrink_fused(self, tok, pb: ColumnarBatch) -> ColumnarBatch:
-        """A fused phase's output, shrunk where it is large (a small one
-        keeps its device-resident count: a shrink would force a blocking
-        readback per cycle). Where the program chose between the masked and
-        the scatter reductions on the device (``groupby_aggregate``), the
-        count the shrink has just read says which it took."""
-        if pb.capacity <= agg_k.DENSE_MAX_SLOTS:
+    #: A fused phase's output of at most this many slots keeps its capacity
+    #: and its device-resident count.
+    SHRINK_ABOVE_SLOTS = 4096
+
+    def _shrink_fused(self, kind: str, pb: ColumnarBatch) -> ColumnarBatch:
+        """A fused phase's output (``_fused_dispatch``'s token, spread),
+        shrunk where it is large (a small one keeps its device-resident
+        count: a shrink would force a blocking readback per cycle). Where
+        the program chose between the masked and the scatter reductions on
+        the device (``groupby_aggregate``), the count the shrink has just
+        read says which it took."""
+        if pb.capacity <= self.SHRINK_ABOVE_SLOTS:
             return pb
         pb = self._shrink_partial(pb)
-        if tok[0] == "sorted":
+        if kind == "sorted":
             if pb.num_rows <= agg_k.FEW_GROUPS_MAX:
                 self.metrics.inc("aggFewGroupBatches")
             else:
@@ -1609,25 +1571,8 @@ class TpuHashAggregateExec(TpuExec):
             return b, None
         return self.pre_stage.eval_traced(b)
 
-    # -- whole-stage fused group-by (expression eval + kernel in <=2
-    # device programs per batch; see the fusion section above) --------------
-    def _spec_signature(self, phase: str):
-        """Static (op, input dtype) signature of the phase's AggSpec list."""
-        sig = []
-        if phase == "update":
-            for leaf, bound in zip(self.leaves, self.bound_leaf_inputs):
-                t = bound.dtype if bound is not None else None
-                if leaf.op == "avg":
-                    sig += [("sum", dt.FLOAT64), ("count", t)]
-                else:
-                    sig.append((leaf.op, t))
-        else:
-            for leaf in self.leaves:
-                update_types = [ut for (_op, ut) in self._update_cols(leaf)]
-                for op, ut in zip(self._merge_ops(leaf), update_types):
-                    sig.append((op, ut))
-        return tuple(sig)
-
+    # -- whole-stage fused group-by (expression eval + kernel in ONE
+    # device program per batch; see the fusion section above) ---------------
     def _fusion_sig(self, phase: str, in_schema: dt.Schema):
         gk = [_expr_cache_key(g) for g in self.grouping]
         bk = [None if b is None else _expr_cache_key(b)
@@ -1640,24 +1585,6 @@ class TpuHashAggregateExec(TpuExec):
                 tuple((l.op, l.ignore_nulls) for l in self.leaves),
                 _schema_sig(in_schema))
 
-    def _maybe_fused_phase(self, batch: ColumnarBatch,
-                           phase: str) -> Optional[ColumnarBatch]:
-        """Fused group-by phase: an optional dense-stats probe plus ONE
-        fused kernel program per batch (vs one dispatch per op in the eager
-        path — the dominant engine cost). Dispatch mirrors
-        groupby_aggregate_fast: single small-span integral key -> dense MXU
-        one-hot path; otherwise the traced sort+scatter path. Falls back to
-        eager permanently on any trace failure.
-
-        Single-shot form (merge/final phases). The streaming update loop
-        instead calls the `_fused_dispatch` / `_fused_finish` halves
-        directly so several batches' probe round-trips stay in flight."""
-        tok = self._fused_dispatch(batch, phase)
-        if tok is None:
-            return None
-        pb = self._fused_finish(tok)
-        return None if pb is None else self._shrink_fused(tok, pb)
-
     def _build_eval_fn(self, phase: str):
         # resolves the exec via the thread-local stack, NOT a captured
         # self: these closures end up inside globally-cached jitted
@@ -1668,8 +1595,8 @@ class TpuHashAggregateExec(TpuExec):
             # the traced program (update phase only: merge/final consume
             # already-filtered partials); its filters become a LIVE-ROW
             # MASK — physical compaction would cost a scatter, the slowest
-            # TPU primitive, per batch, while the sort and dense kernels
-            # rank/mask dead rows for free. Returns (keys, specs,
+            # TPU primitive, per batch, while the sort kernel ranks/masks
+            # dead rows for free. Returns (keys, specs,
             # effective_row_count, live_mask); kernels must see the
             # POST-filter count or dead rows would join the NULL group,
             # and live_mask is None when the chain has no filter.
@@ -1693,11 +1620,10 @@ class TpuHashAggregateExec(TpuExec):
         return build_eval
 
     def _fused_dispatch(self, batch: ColumnarBatch, phase: str):
-        """First half of the fused phase: dispatch the probe (or, where no
-        probe is needed, the whole kernel) without any blocking sync. The
-        streaming loop parks these on the shared PipelineWindow, which
-        fetches every landing probe's stats in one batched readback.
-        Returns an opaque token for `_fused_finish`, or None -> eager."""
+        """The fused phase: the whole kernel in ONE program, dispatched
+        without any blocking sync (the group count stays on the device).
+        Returns ``("done", partial)`` for a grouping-free reduction,
+        ``("sorted", partial)`` for a group-by, or None -> eager."""
         if getattr(self, "_fusion_broken", False) or not _fusion_enabled(self):
             return None
         if not all(e.tree_fusable() for e in self.grouping) or any(
@@ -1707,7 +1633,6 @@ class TpuHashAggregateExec(TpuExec):
         if self.pre_stage is not None and not self.pre_stage.fusable():
             return None
         import jax
-        import jax.numpy as jnp
 
         in_schema = batch.schema
         cap = batch.capacity
@@ -1752,48 +1677,8 @@ class TpuHashAggregateExec(TpuExec):
                 return ("done", ColumnarBatch.from_flat_arrays(
                     pschema, list(outs), 1))
 
-            if phase != "update" and cap <= (1 << 15):
-                # merge inputs are concatenated partials — small. The plain
-                # fused sort+scatter program handles them in ONE dispatch
-                # with no probe and no host readback (scatter serialization
-                # only bites at scan-batch capacities)
-                return self._dispatch_plain_sort(batch, sig, in_schema, cap,
-                                                 build_eval, pargs)
-
-            spec_sig = self._spec_signature(phase)
-            key_dtype = (self.grouping[0].dtype
-                         if len(self.grouping) == 1 else None)
-            dense_cand = (
-                _matmul_agg_enabled() and
-                self._dense_state.get("enabled", True) and
-                key_dtype in (dt.INT8, dt.INT16, dt.INT32, dt.INT64,
-                              dt.BOOL, dt.DATE, dt.TIMESTAMP) and
-                all(_dense_sig_supported(op, t) for op, t in spec_sig))
-
-            if dense_cand:
-                def build_probe():
-                    def fn(num_rows, *arrays):
-                        b = ColumnarBatch.from_flat_arrays(
-                            in_schema, arrays, num_rows)
-                        keys, specs, n_eff, mask = build_eval(b)
-                        float_cols = [
-                            s.column for s in specs
-                            if s.op in ("sum", "avg") and s.column is not None
-                            and s.column.dtype.is_floating]
-                        with operator_scope(op):
-                            return agg_k.dense_key_stats(
-                                keys[0],
-                                num_rows if mask is not None else n_eff,
-                                extra_mask=mask, float_cols=float_cols)
-                    return jax.jit(fn)
-                probe = _fused_fn(sig + ("probe", cap), build_probe)
-                with _trace_exec(self):
-                    rmin, dec = probe(_dev_count(batch),
-                                      *batch.flat_arrays(), *pargs)
-                return ("dense", batch, phase, sig, in_schema, cap,
-                        rmin, dec)
-
-            return self._dispatch_sort(batch, phase, sig, in_schema, cap)
+            return self._dispatch_plain_sort(batch, sig, in_schema, cap,
+                                             build_eval, pargs)
         except Exception as e:
             if _donation_consumed(batch):
                 raise          # executed-and-donated: no eager re-read
@@ -1803,66 +1688,12 @@ class TpuHashAggregateExec(TpuExec):
             self._fusion_broken = True
             return None
 
-    def _dispatch_sort(self, batch: ColumnarBatch, phase: str, sig, in_schema,
-                       cap):
-        """Sort-path dispatch half. With matmul enabled: a probe computing
-        the sort order + segment starts + group count/absmax stats (the
-        finish half picks the static group bucket from them). Otherwise the
-        whole scatter kernel in one dispatch, count left device-resident."""
-        import jax
-        import jax.numpy as jnp
-        build_eval = self._build_eval_fn(phase)
-        op = type(self).__name__
-        pargs = self._stage_param_args() if phase == "update" else ()
-
-        if not _matmul_agg_enabled():
-            return self._dispatch_plain_sort(batch, sig, in_schema, cap,
-                                             build_eval, pargs)
-
-        # staged sort path: probe (sort + segments + group-count stats) ->
-        # MXU matmul segment kernel with a static group bucket. TPU scatters
-        # serialize (the one-program scatter kernel ran ~850ms/batch on q1);
-        # matmul segment reductions at small Kb are ~10x faster
-        def build_sort_probe():
-            def fn(num_rows, *arrays):
-                b = ColumnarBatch.from_flat_arrays(
-                    in_schema, arrays, num_rows)
-                keys, specs, n_eff, mask = build_eval(b)
-                capb = b.capacity
-                with operator_scope(op):
-                    order = K.sort_indices(
-                        [K.SortKey(c) for c in keys], n_eff, capb,
-                        live_mask=mask)
-                    skeys = [K.gather_column(c, order) for c in keys]
-                    starts = K.segment_starts_from_sorted_keys(
-                        skeys, n_eff, capb)
-                    with jax.named_scope("reduce"):
-                        parts = [jnp.sum(starts).astype(jnp.float64)]
-                        for s in specs:
-                            if s.op in ("sum", "avg") and \
-                                    s.column is not None and \
-                                    s.column.dtype.is_floating:
-                                c = s.column
-                                a = jnp.where(
-                                    c.validity & ~jnp.isnan(c.data),
-                                    jnp.abs(c.data), 0.0)
-                                parts.append(jnp.max(a).astype(jnp.float64))
-                        stats = jnp.stack(parts)
-                return order, starts, n_eff, stats
-            return jax.jit(fn)
-        probe = _fused_fn(sig + ("sort-probe", cap), build_sort_probe)
-        with _trace_exec(self):
-            order, starts, n_eff_dev, dec = probe(
-                _dev_count(batch), *batch.flat_arrays(), *pargs)
-        return ("sortmm", batch, phase, sig, in_schema, cap,
-                order, starts, n_eff_dev, dec)
-
     def _dispatch_plain_sort(self, batch: ColumnarBatch, sig, in_schema, cap,
                              build_eval, pargs: tuple = ()):
         """Whole sort-based group-by in ONE dispatch, count left
-        device-resident (no probe, no readback): ``groupby_aggregate``,
-        which takes the masked or the scatter reductions by the group count
-        it finds. Token ``sorted``, so that ``_shrink_fused`` can say which."""
+        device-resident (no readback): ``groupby_aggregate``, which takes
+        the masked or the scatter reductions by the group count it finds.
+        Token ``sorted``, so that ``_shrink_fused`` can say which."""
         import jax
         pschema = self._partial_schema()
         donate = _donate_argnums(batch, 1)
@@ -1888,160 +1719,6 @@ class TpuHashAggregateExec(TpuExec):
                                             outs[-1])
         return ("sorted", pb)
 
-    def _fused_finish(self, tok,
-                      stats=None) -> Optional[ColumnarBatch]:
-        """Second half of the fused phase: read the probe stats (or take
-        them pre-read — the streaming loop fetches every in-flight batch's
-        stats in ONE batched device_get) and dispatch the kernel. Returns
-        the partial batch, or None when fusion failed (caller goes eager on
-        the retained batch)."""
-        try:
-            kind = tok[0]
-            if kind in ("done", "sorted"):
-                return tok[1]
-            if kind == "dense":
-                pb = self._finish_dense(tok, stats)
-                if pb is not None:
-                    return pb
-                # dense didn't fit this batch: stage it through the sort
-                # path (a blocking probe for THIS batch only; once the span
-                # check disables dense, later batches dispatch sort probes
-                # up front)
-                _, batch, phase, sig, in_schema, cap, _rmin, _dec = tok
-                tok = self._dispatch_sort(batch, phase, sig, in_schema, cap)
-                return self._fused_finish(tok)
-            assert kind == "sortmm", kind
-            return self._finish_sortmm(tok, stats)
-        except Exception as e:
-            if len(tok) > 1 and isinstance(tok[1], ColumnarBatch) and \
-                    _donation_consumed(tok[1]):
-                raise          # executed-and-donated: no eager re-read
-            import logging
-            logging.getLogger("spark_rapids_tpu.fusion").warning(
-                "fused group-by finish fell back to eager: %s", e)
-            self._fusion_broken = True
-            return None
-
-    def _finish_dense(self, tok, stats=None) -> Optional[ColumnarBatch]:
-        import jax
-        import jax.numpy as jnp
-        import numpy as np
-        from ..columnar.column import bucket as _bucket
-        _, batch, phase, sig, in_schema, cap, rmin, dec = tok
-        build_eval = self._build_eval_fn(phase)
-        pschema = self._partial_schema()
-        if stats is None:
-            stats = np.asarray(dec)  # lint: host-sync-ok window-degraded re-read of ONE batch's stats scalar
-        span, absmaxes = stats[0], stats[2:]
-        f32_safe = bool(all(a <= agg_k.F32_SAFE_ABSMAX for a in absmaxes))
-        if span + 2 > agg_k.DENSE_MAX_SLOTS:
-            self._dense_state["enabled"] = False
-        if not (span + 2 <= agg_k.DENSE_MAX_SLOTS and f32_safe):
-            return None
-        Kb = _bucket(int(span) + 2, 128)
-        # the dense kernel is this batch's LAST consumer (the probe only
-        # read it): donate the columns so HBM frees on ingestion
-        donate = _donate_argnums(batch, 2)
-        op = type(self).__name__
-
-        def build_dense():
-            def fn(num_rows, rmin_d, *arrays):
-                b = ColumnarBatch.from_flat_arrays(
-                    in_schema, arrays, num_rows)
-                keys, specs, n_eff, mask = build_eval(b)
-                with operator_scope(op):
-                    ok, oa, ng = agg_k.groupby_dense(
-                        keys[0], specs,
-                        num_rows if mask is not None else n_eff, Kb, rmin_d,
-                        extra_mask=mask)
-                flat = [a for c in ok + oa for a in c.arrays()]
-                return tuple(flat) + (ng,)
-            return jax.jit(fn, donate_argnums=donate)
-        fn = _fused_fn(sig + ("dense", cap, Kb, ("donate", bool(donate))),
-                       build_dense)
-        pargs = self._stage_param_args() if phase == "update" else ()
-        with _trace_exec(self):
-            outs = fn(_dev_count(batch), rmin, *batch.flat_arrays(),
-                      *pargs)
-        _note_donated(batch, donate)
-        return ColumnarBatch.from_flat_arrays(pschema, list(outs[:-1]),
-                                              outs[-1])
-
-    def _finish_sortmm(self, tok, stats=None) -> ColumnarBatch:
-        import jax
-        import jax.numpy as jnp
-        import numpy as np
-        from ..columnar.column import bucket as _bucket
-        (_, batch, phase, sig, in_schema, cap,
-         order, starts, n_eff_dev, dec) = tok
-        build_eval = self._build_eval_fn(phase)
-        pschema = self._partial_schema()
-        if stats is None:
-            stats = np.asarray(dec)  # lint: host-sync-ok window-degraded re-read of ONE batch's stats scalar
-        n_groups = int(stats[0])
-        f32_safe = bool(all(a <= agg_k.F32_SAFE_ABSMAX for a in stats[1:]))
-        Kb = _bucket(max(n_groups, 1))
-        # per-spec mixing below: matmul where supported (count, float
-        # sum/avg); otherwise (min/max, int sums) Kb-slot reductions, masked
-        # ones up to FEW_GROUPS_MAX slots and a scatter beyond
-        use_mm = Kb <= agg_k.MATMUL_MAX_GROUPS and f32_safe
-        # last consumer of the batch columns AND of the probe's order/
-        # starts arrays (args 1-2): donate them together
-        donate = _donate_argnums(batch, 4)
-        if donate:
-            donate = (1, 2) + donate
-        op = type(self).__name__
-
-        def build_sort_kernel(Kb=Kb, use_mm=use_mm):
-            def fn(num_rows, order, starts, n_eff, *arrays):
-                b = ColumnarBatch.from_flat_arrays(
-                    in_schema, arrays, num_rows)
-                keys, specs, _n, _mask = build_eval(b)
-                with operator_scope(op):
-                    ok, oa, ng = sort_kernel(keys, specs, b.capacity, order,
-                                             starts, n_eff)
-                flat = [a for c in ok + oa for a in c.arrays()]
-                return tuple(flat) + (ng,)
-
-            def sort_kernel(keys, specs, capb, order, starts, n_eff):
-                with jax.named_scope("segment_starts"):
-                    live = jnp.arange(capb) < n_eff
-                    seg_ids = K.segment_ids(starts)
-                    ng = jnp.sum(starts).astype(jnp.int32)
-                    start_perm, _cnt = K.compaction_indices(starts)
-                    kidx = start_perm[:Kb]
-                    glive = jnp.arange(Kb) < ng
-                skeys = [K.gather_column(c, order) for c in keys]
-                ok = [K.gather_column(c, kidx, out_valid=glive)
-                      for c in skeys]
-                oa = []
-                for s in specs:
-                    sc = s
-                    if s.column is not None:
-                        sc = s._replace(column=K.gather_column(
-                            s.column, order))
-                    if use_mm and agg_k._matmul_supported(sc):
-                        agg = agg_k.segment_aggregate_matmul(
-                            sc, seg_ids, live, Kb)
-                    else:
-                        agg = agg_k.segment_aggregate(
-                            sc, seg_ids, live, capb,
-                            num_segments=Kb, n_groups=ng)
-                    oa.append(agg_k._mask_to(agg, glive))
-                return ok, oa, ng
-            return jax.jit(fn, donate_argnums=donate)
-        fn = _fused_fn(sig + ("sort-mm", cap, Kb, use_mm,
-                              ("donate", bool(donate))),
-                       build_sort_kernel)
-        pargs = self._stage_param_args() if phase == "update" else ()
-        with _trace_exec(self):
-            outs = fn(_dev_count(batch), order, starts,
-                      n_eff_dev, *batch.flat_arrays(), *pargs)
-        _note_donated(batch, donate)
-        # group count came back with the probe stats — no second readback
-        return ColumnarBatch.from_flat_arrays(pschema, list(outs[:-1]),
-                                              n_groups)
-
     # -- final (merge partials) ---------------------------------------------
     def _merge_ops(self, leaf: lp.AggregateExpression):
         if leaf.op == "avg":
@@ -2065,17 +1742,15 @@ class TpuHashAggregateExec(TpuExec):
     def _merge_to_partial(self, batch: ColumnarBatch) -> ColumnarBatch:
         """Merge-phase aggregation of concatenated partials back to one row
         per group (the merge half of the CudfAggregate update/merge pairs)."""
-        fused = self._maybe_fused_phase(batch, "merge")
-        if fused is not None:
-            return fused
+        tok = self._fused_dispatch(batch, "merge")
+        if tok is not None:
+            return self._shrink_fused(*tok)
         keys, specs = self._merge_specs(batch)
         if not keys:
             aggs = agg_k.reduce_aggregate(specs, batch.num_rows,
                                           batch.capacity)
             return ColumnarBatch(self._partial_schema(), aggs, 1)
-        out_keys, aggs, n_groups = agg_k.groupby_aggregate_fast(
-            keys, specs, batch.num_rows, batch.capacity,
-            allow_matmul=_matmul_agg_enabled(), dense_state=self._dense_state)
+        out_keys, aggs, n_groups = self._groupby_eager(keys, specs, batch)
         return self._shrink_partial(
             ColumnarBatch(self._partial_schema(), out_keys + aggs, n_groups))
 
@@ -2093,10 +1768,8 @@ class TpuHashAggregateExec(TpuExec):
                 n_groups = 1
                 out_keys = []
             else:
-                out_keys, aggs, n_groups = agg_k.groupby_aggregate_fast(
-                    keys, specs, batch.num_rows, batch.capacity,
-                    allow_matmul=_matmul_agg_enabled(),
-                    dense_state=self._dense_state)
+                out_keys, aggs, n_groups = self._groupby_eager(
+                    keys, specs, batch)
         out = self._project_results(out_keys, aggs, n_groups)
         self.metrics.inc("numOutputRows", out.num_rows_raw)
         yield out

@@ -152,193 +152,138 @@ def test_groupby_large_random_vs_pandas():
 
 
 # ---------------------------------------------------------------------------
-# Dense-range MXU group-by
+# One group-by: what the float32 MXU paths' tests guarded (PR 29), asked of
+# ``groupby_aggregate`` and of the operator's two ways into it
 # ---------------------------------------------------------------------------
 
-from spark_rapids_tpu.ops.aggregates import (  # noqa: E402
-    dense_key_stats, groupby_aggregate_fast, groupby_dense)
+_BIG = 3_000_000_000_000_000_000
+_NAN, _INF = float("nan"), float("inf")
+
+# (id, key dtype, keys, value dtype, values, ops, live rows or None,
+#  expected keys, expected aggregates)
+_EDGE_CASES = [
+    ("negative_keys_and_null_group", dt.INT32, [-3, -1, None, -3],
+     dt.FLOAT64, [1.0, 2.0, 3.0, 4.0], ["sum"], None,
+     [None, -3, -1], [[3.0, 5.0, 2.0]]),
+    ("all_null_keys", dt.INT64, [None, None],
+     dt.FLOAT64, [1.0, 2.0], ["sum"], None, [None], [[3.0]]),
+    ("empty_input", dt.INT64, [], dt.FLOAT64, [], ["sum"], None, [], [[]]),
+    # 2 * _BIG overflows int64 and wraps exactly like Spark's bigint
+    ("int64_sum_wraps_bit_exact", dt.INT64, [5, 5, 6, 6],
+     dt.INT64, [_BIG, _BIG, -_BIG, 17], ["sum"], None,
+     [5, 6], [[int(np.int64(np.uint64(_BIG * 2 % (1 << 64)))), -_BIG + 17]]),
+    ("live_mask_folds_a_filter", dt.INT64, [1, 2, 1, 2],
+     dt.FLOAT64, [10.0, 20.0, 30.0, 40.0], ["sum"],
+     [True, False, True, False], [1], [[40.0]]),
+    # beyond float32's range: nothing here rides a float32 pair
+    ("1e40_and_inf_sum_exactly", dt.INT64, [1, 1, 2, 2],
+     dt.FLOAT64, [1e40, 3.0, _INF, 5.0], ["sum"], None,
+     [1, 2], [[1e40 + 3.0, _INF]]),
+    ("nan_poisons_only_its_group", dt.INT64, [1, 1, 2, 2],
+     dt.FLOAT64, [_NAN, 2.0, 3.0, 4.0], ["sum", "avg"], None,
+     [1, 2], [[_NAN, 7.0], [_NAN, 3.5]]),
+]
 
 
-def _run_dense(key, specs, n, extra_mask=None):
-    rmin, decision = dense_key_stats(key, n, extra_mask)
-    span = int(np.asarray(decision)[0])
-    from spark_rapids_tpu.columnar.column import bucket
-    Kb = bucket(span + 2, 128)
-    out_keys, out_aggs, ng = groupby_dense(key, specs, n, Kb, rmin,
-                                           extra_mask=extra_mask)
+@pytest.mark.parametrize(
+    "kt, kv, vt, vv, ops, live, want_keys, want_aggs",
+    [c[1:] for c in _EDGE_CASES], ids=[c[0] for c in _EDGE_CASES])
+def test_groupby_edge_cases(kt, kv, vt, vv, ops, live, want_keys, want_aggs):
+    import jax.numpy as jnp
+    k, v = _col(kv, kt), _col(vv, vt)
+    mask = None if live is None else jnp.asarray(
+        live + [False] * (k.capacity - len(live)))
+    out_keys, out_aggs, ng = groupby_aggregate(
+        [k], [AggSpec(op, v) for op in ops], len(kv), k.capacity,
+        live_mask=mask)
     g = int(ng)
-    return ([k.to_pylist(g) for k in out_keys],
-            [a.to_pylist(g) for a in out_aggs])
+    assert out_keys[0].to_pylist(g) == want_keys
+    for agg, want in zip(out_aggs, want_aggs):
+        got = agg.to_pylist(g)
+        assert len(got) == len(want)
+        for a, b in zip(got, want):
+            assert (math.isnan(a) and math.isnan(b)) or a == b, (got, want)
 
 
-def test_dense_groupby_matches_sort_path():
+def test_groupby_small_span_int_key_vs_pandas():
+    """A small-span int key with negative keys and a NULL-key group, every
+    kind of aggregate: sums of doubles to float64's rounding, not to a
+    float32 pair's."""
+    import pandas as pd
     rng = np.random.default_rng(11)
     n = 500
     kv = [None if rng.random() < 0.08 else int(x)
           for x in rng.integers(-40, 40, n)]
     vv = [None if rng.random() < 0.1 else float(x)
           for x in rng.normal(0, 10, n)]
-    k = _col(kv, dt.INT64)
-    v = _col(vv, dt.FLOAT64)
-    iv = _col([None if x is None else int(x * 7) for x in kv], dt.INT64)
-    specs = [AggSpec("sum", v), AggSpec("count", v), AggSpec("avg", v),
-             AggSpec("min", v), AggSpec("max", v), AggSpec("count_star", None),
-             AggSpec("sum", iv), AggSpec("first", v), AggSpec("last", v)]
-    dk, da = _run_dense(k, specs, n)
-    sk, sa = _run_groupby([k], specs, n)
-    # dense output: keys ascending with NULL group LAST; sort path: NULL first
-    if sk[0] and sk[0][0] is None:
-        sk = [col[1:] + col[:1] for col in sk]
-        sa = [col[1:] + col[:1] for col in sa]
-    assert dk[0] == sk[0]
-    for i, (got, exp) in enumerate(zip(da, sa)):
-        for a, b in zip(got, exp):
-            if isinstance(a, float) and isinstance(b, float):
-                # float sums ride f32 hi/lo + f64 chunk accumulation:
-                # ~1e-6 abs per-chunk rounding (reference epsilon is 1e-4)
-                assert a == pytest.approx(b, rel=2e-6, abs=2e-6), (i, a, b)
+    iv = [None if x is None else x * 7 for x in kv]
+    k, v, i = _col(kv, dt.INT64), _col(vv, dt.FLOAT64), _col(iv, dt.INT64)
+    ops = [("sum", v), ("count", v), ("avg", v), ("min", v), ("max", v),
+           ("count_star", None), ("sum", i), ("first", v), ("last", v)]
+    keys, aggs = _run_groupby([k], [AggSpec(op, c) for op, c in ops], n)
+    df = pd.DataFrame({"k": pd.array(kv, dtype="Int64"),
+                       "v": pd.array(vv, dtype="Float64"),
+                       "i": pd.array(iv, dtype="Int64")})
+    g = df.groupby("k", dropna=False, sort=True)
+    want = pd.DataFrame({
+        0: g.v.sum(min_count=1), 1: g.v.count(), 2: g.v.mean(),
+        3: g.v.min(), 4: g.v.max(), 5: g.size(),
+        6: g.i.sum(min_count=1), 7: g.v.first(), 8: g.v.last()})
+    # pandas sorts the NULL key last, the engine (Spark's order) first
+    want = pd.concat([want[want.index.isna()], want[~want.index.isna()]])
+    assert keys[0] == [None if pd.isna(x) else int(x) for x in want.index]
+    for c, got in enumerate(aggs):
+        for a, b in zip(got, want[c]):
+            if a is None or pd.isna(b):
+                assert a is None and pd.isna(b), (ops[c][0], a, b)
+            elif isinstance(a, float):
+                assert a == pytest.approx(float(b), rel=1e-13), (ops[c][0],)
             else:
-                assert a == b, (i, specs[i].op, got, exp)
+                assert a == int(b), (ops[c][0], a, b)
 
 
-def test_dense_int64_sum_bit_exact():
-    big = 3_000_000_000_000_000_000
-    k = _col([5, 5, 6, 6], dt.INT64)
-    v = _col([big, big, -big, 17], dt.INT64)
-    keys, aggs = _run_dense(k, [AggSpec("sum", v)], 4)
-    assert keys[0] == [5, 6]
-    # 2*big overflows int64 and must wrap exactly like Spark bigint
-    import numpy as _np
-    exp0 = int(_np.int64(_np.uint64(big * 2 % (1 << 64))))
-    assert aggs[0] == [exp0, -big + 17]
-
-
-def test_dense_negative_keys_and_null_group():
-    k = _col([-3, -1, None, -3], dt.INT32)
-    v = _col([1.0, 2.0, 3.0, 4.0], dt.FLOAT64)
-    keys, aggs = _run_dense(k, [AggSpec("sum", v)], 4)
-    assert keys[0] == [-3, -1, None]
-    assert aggs[0] == [5.0, 2.0, 3.0]
-
-
-def test_dense_extra_mask_filter_fold():
-    k = _col([1, 2, 1, 2], dt.INT64)
-    v = _col([10.0, 20.0, 30.0, 40.0], dt.FLOAT64)
-    import jax.numpy as jnp
-    mask = jnp.asarray([True, False, True, False] + [False] * (k.capacity - 4))
-    keys, aggs = _run_dense(k, [AggSpec("sum", v)], 4, extra_mask=mask)
-    assert keys[0] == [1]
-    assert aggs[0] == [40.0]
-
-
-def test_dense_all_null_keys():
-    k = _col([None, None], dt.INT64)
-    v = _col([1.0, 2.0], dt.FLOAT64)
-    keys, aggs = _run_dense(k, [AggSpec("sum", v)], 2)
-    assert keys[0] == [None]
-    assert aggs[0] == [3.0]
-
-
-def test_dense_empty_input():
-    k = _col([], dt.INT64)
-    v = _col([], dt.FLOAT64)
-    keys, aggs = _run_dense(k, [AggSpec("sum", v)], 0)
-    assert keys[0] == []
-    assert aggs[0] == []
-
-
-def test_groupby_fast_dispatches_dense_and_matches():
-    """groupby_aggregate_fast with a dense int key must agree with the
-    explicitly non-matmul sort path on random data."""
-    rng = np.random.default_rng(23)
-    n = 800
-    kv = [None if rng.random() < 0.05 else int(x)
-          for x in rng.integers(0, 200, n)]
-    vv = [None if rng.random() < 0.1 else float(x)
-          for x in rng.normal(0, 100, n)]
-    k = _col(kv, dt.INT64)
-    v = _col(vv, dt.FLOAT64)
-    specs = [AggSpec("sum", v), AggSpec("avg", v), AggSpec("count", v),
-             AggSpec("min", v), AggSpec("max", v)]
-    cap = k.capacity
-    fk, fa, fn = groupby_aggregate_fast([k], specs, n, cap, allow_matmul=True)
-    gk, ga, gn = groupby_aggregate_fast([k], specs, n, cap, allow_matmul=False)
-    assert fn == gn
-    fkeys = fk[0].to_pylist(fn)
-    gkeys = gk[0].to_pylist(gn)
-    fmap = {kk: tuple(a.to_pylist(fn)[i] for a in fa)
-            for i, kk in enumerate(fkeys)}
-    gmap = {kk: tuple(a.to_pylist(gn)[i] for a in ga)
-            for i, kk in enumerate(gkeys)}
-    assert set(fmap) == set(gmap)
-    for kk in fmap:
-        for a, b in zip(fmap[kk], gmap[kk]):
-            if isinstance(a, float) and isinstance(b, float):
-                assert a == pytest.approx(b, rel=2e-6, abs=2e-6)
-            else:
-                assert a == b
-
-
-def test_dense_dispatch_falls_back_on_f32_unsafe_floats():
-    """Values beyond the f32-safe range (or inf) must not ride the hi/lo
-    matmul split; the dispatch falls back to the exact f64 sort path."""
-    k = _col([1, 1, 2, 2], dt.INT64)
-    v = _col([1e40, 3.0, float("inf"), 5.0], dt.FLOAT64)
-    fk, fa, fn = groupby_aggregate_fast([k], [AggSpec("sum", v)],
-                                        4, k.capacity, allow_matmul=True)
-    keys = fk[0].to_pylist(fn)
-    sums = fa[0].to_pylist(fn)
-    got = dict(zip(keys, sums))
-    assert got[1] == 1e40 + 3.0
-    assert got[2] == float("inf")
-
-
-def test_dense_nan_poisons_only_its_group():
-    """A NaN value must make only ITS group's sum/avg NaN, not every group."""
-    nan = float("nan")
-    k = _col([1, 1, 2, 2], dt.INT64)
-    v = _col([nan, 2.0, 3.0, 4.0], dt.FLOAT64)
-    keys, aggs = _run_dense(k, [AggSpec("sum", v), AggSpec("avg", v)], 4)
-    assert keys[0] == [1, 2]
-    assert math.isnan(aggs[0][0]) and math.isnan(aggs[1][0])
-    assert aggs[0][1] == 7.0 and aggs[1][1] == 3.5
-
-
-def test_fused_staged_matmul_groupby_matches_exact():
-    """Force the MXU matmul segment path (off by default on CPU): the staged
-    probe+kernel fused sort group-by must match the exact path to float-agg
-    tolerance."""
-    import numpy as np
+def test_session_with_the_retired_matmul_key_answers_exactly(monkeypatch):
+    """``spark.rapids.tpu.sql.agg.matmul.enabled`` is no conf any more
+    (PR 29), yet the benchmark's configurations still set it, in the
+    session and in the environment: an unknown key is inert. ``true`` used
+    to select float32 sums; the answer is exact and every aggregate
+    program of the query is of the ``sort`` or ``final`` family."""
     import pandas as pd
     from spark_rapids_tpu.api.session import TpuSession
     from spark_rapids_tpu.api import functions as F
+    key = "spark.rapids.tpu.sql.agg.matmul.enabled"
+    monkeypatch.setenv("SPARK_RAPIDS_TPU_CONF__" +
+                       key.upper().replace(".", "__"), "true")
     rng = np.random.default_rng(41)
     n = 5000
     df = pd.DataFrame({
         "k": [f"g{int(x)}" for x in rng.integers(0, 23, n)],  # string keys
-        "v": rng.normal(0, 10, n),
+        "v": rng.normal(0, 10, n) * 1e6,
         "q": rng.integers(0, 50, n)})
     s = TpuSession.builder.config({
-        "spark.rapids.tpu.sql.explain": "NONE",
-        "spark.rapids.tpu.sql.agg.matmul.enabled": "true"}).getOrCreate()
+        "spark.rapids.tpu.sql.explain": "NONE", key: "true"}).getOrCreate()
     got = {r[0]: r[1:] for r in
-           (s.createDataFrame(df).filter(F.col("v") > -5)
+           (s.createDataFrame(df).filter(F.col("v") > -5e6)
             .groupBy("k").agg(F.sum("v").alias("sv"),
                               F.count("*").alias("n"),
                               F.avg("v").alias("av"),
                               F.sum("q").alias("sq"),
                               F.min("v").alias("mv")).collect())}
-    sub = df[df.v > -5]
+    agg_programs = [p for p in s.last_query_metrics()["programs"]
+                    if p.startswith("agg/")]
+    assert agg_programs and all(
+        p.endswith(("/sort", "/final")) for p in agg_programs), agg_programs
+    sub = df[df.v > -5e6]
     exp = sub.groupby("k").agg(sv=("v", "sum"), n=("v", "size"),
                                av=("v", "mean"), sq=("q", "sum"),
                                mv=("v", "min"))
     assert len(got) == len(exp)
     for k, row in exp.iterrows():
         sv, cnt, av, sq, mv = got[k]
-        assert cnt == row["n"] and sq == row["sq"]
-        assert abs(sv - row["sv"]) <= 1e-6 * max(1, abs(row["sv"]))
-        assert abs(av - row["av"]) <= 1e-6 * max(1, abs(row["av"]))
-        assert abs(mv - row["mv"]) <= 1e-12
+        assert cnt == row["n"] and sq == row["sq"] and mv == row["mv"]
+        # float64's rounding; a float32 pair gave 1e-7
+        assert sv == pytest.approx(row["sv"], rel=1e-12)
+        assert av == pytest.approx(row["av"], rel=1e-12)
 
 
 # ---------------------------------------------------------------------------

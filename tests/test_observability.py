@@ -457,38 +457,57 @@ def _group_by_program_text(conf, query=_tiny_q1):
     return texts
 
 
-@pytest.mark.parametrize("matmul, present, absent", [
-    # both ways of the choice the device makes by the group count
-    ("false", ("lexsort", "gather", "segment_starts", "segment_ids_to_rows",
-               "segment_sum_masked", "segment_sum_scatter"),
-     ("segment_sum_matmul",)),
-    # what ``auto`` picks on an accelerator; forced, on this CPU
-    ("true", ("lexsort", "gather", "segment_starts",
-              "segment_sum_matmul"), ()),
-])
+_RETIRED_MATMUL_KEY = "spark.rapids.tpu.sql.agg.matmul.enabled"
+_RETIRED_MATMUL_ENV = "SPARK_RAPIDS_TPU_CONF__" + \
+    _RETIRED_MATMUL_KEY.upper().replace(".", "__")
+
+
+@pytest.mark.parametrize("matmul", ["false", "true"])
 def test_group_by_program_names_its_stages_under_the_operator(
-        monkeypatch, matmul, present, absent):
+        monkeypatch, matmul):
     """q1's group-by: every stage of the vocabulary it runs lies under
-    ``TpuHashAggregateExec`` in the lowered program's op names, and the
-    scatter and the matmul segment sums carry DIFFERENT names (the conf
-    is read from the environment: PERF.md section 7 row 2)."""
+    ``TpuHashAggregateExec`` in the lowered program's op names, both ways
+    of the choice the device makes by the group count among them. The
+    environment key that used to select float32 matmul sums (the
+    benchmark still exports it) changes nothing of that."""
     from spark_rapids_tpu.exec.tracing import STAGES
-    monkeypatch.setenv(
-        "SPARK_RAPIDS_TPU_CONF__SPARK__RAPIDS__TPU__SQL__AGG__MATMUL"
-        "__ENABLED", matmul)
+    monkeypatch.setenv(_RETIRED_MATMUL_ENV, matmul)
     text = "\n".join(_group_by_program_text({}).values())
     import re
-    for stage in present:
+    for stage in ("lexsort", "gather", "segment_starts",
+                  "segment_ids_to_rows", "segment_sum_masked",
+                  "segment_sum_scatter"):
         assert stage in STAGES
         # jax's own ``cond/branch_<i>_fun`` may lie between the two: the
         # choice between the masked and the scatter sums is made there
         assert re.search(r"/TpuHashAggregateExec/(cond/branch_\d_fun/)?"
                          + stage + "/", text), stage
-    for stage in absent:
-        assert f"/{stage}/" not in text, stage
     # the folded filter and projection keep their own operators' scopes
     assert "/TpuFilterExec/filter/" in text
     assert "jit(agg_update_" in text
+
+
+def test_retired_matmul_key_leaves_q1s_update_program_as_it_is(monkeypatch):
+    """One group-by (PR 29): with the key unset, ``false`` or ``true``, in
+    the environment and in the session, q1's update is ONE program of the
+    ``sort`` family and its lowered text is the same to the byte; the key
+    is no conf of the registry any more."""
+    from spark_rapids_tpu import config as cfg
+    assert not [k for k in cfg.REGISTRY.entries() if "agg.matmul" in k.key]
+    texts = {}
+    for value in (None, "false", "true"):
+        conf = {}
+        if value is None:
+            monkeypatch.delenv(_RETIRED_MATMUL_ENV, raising=False)
+        else:
+            monkeypatch.setenv(_RETIRED_MATMUL_ENV, value)
+            conf[_RETIRED_MATMUL_KEY] = value
+        by_family = _group_by_program_text(conf)
+        (family, text), = by_family.items()
+        assert family.startswith("agg/update/") and \
+            family.endswith("/sort"), family
+        texts[value] = text
+    assert texts[None] == texts["false"] == texts["true"]
 
 
 def _ops_by_scope(text, op):
@@ -508,15 +527,12 @@ def _ops_by_scope(text, op):
     return names
 
 
-def test_group_by_gathers_its_keys_and_no_aggregate_input(monkeypatch):
+def test_group_by_gathers_its_keys_and_no_aggregate_input():
     """The reductions of the sort-based group-by read their inputs in ROW
     order (PR 28): in q1's update program the gathers into sort order are
     those of the two string keys, with one aggregate or with eight, and
     the one scatter outside the branches carries the segment ids to the
     rows. q1's value gathers were 2.9 s of its 5.26 s on the chip."""
-    monkeypatch.setenv(
-        "SPARK_RAPIDS_TPU_CONF__SPARK__RAPIDS__TPU__SQL__AGG__MATMUL"
-        "__ENABLED", "false")
     aggs = [F.sum("l_quantity"), F.sum("l_extendedprice"),
             F.avg("l_discount"), F.min("l_tax"), F.max("l_quantity"),
             F.count("l_tax"), F.first("l_extendedprice"), F.sum("l_tax")]
@@ -575,16 +591,12 @@ def _few_groups_max():
     (4, {"aggFewGroupBatches": 1}),
     (_few_groups_max() + 1, {"aggScatterBatches": 1}),
 ])
-def test_aggregate_counts_which_reduction_each_batch_took(
-        monkeypatch, groups, counted):
+def test_aggregate_counts_which_reduction_each_batch_took(groups, counted):
     """The sort-based group-by chooses on the device; the operator says
     which way from the group count it reads back anyway — no sync more
     than before PR 26 (one: the shrink's)."""
-    monkeypatch.setenv(
-        "SPARK_RAPIDS_TPU_CONF__SPARK__RAPIDS__TPU__SQL__AGG__MATMUL"
-        "__ENABLED", "false")
     s = _session()
-    n = 6000               # a batch above DENSE_MAX_SLOTS: it is shrunk
+    n = 6000               # a batch above SHRINK_ABOVE_SLOTS: it is shrunk
     df = s.createDataFrame({"k": [f"g{i % groups}" for i in range(n)],
                             "v": [float(i) for i in range(n)]})
     rows = df.groupBy("k").agg(F.sum("v").alias("s")).collect()

@@ -460,8 +460,8 @@ def test_serving_counters_reach_the_metrics_registry():
 
 
 def test_serving_series_ride_the_history_gate():
-    """bench.py stamps plan_cache_plans_per_s (higher better) and
-    warm_traffic_q6_s (lower better) into the regression gate."""
+    """plan_cache_plans_per_s (higher better) and warm_traffic_q6_s
+    (lower better) are series of the regression gate."""
     from benchmarks import history as bh
     assert bh.WARM_TRAFFIC_Q6_S in bh.INVERTED_QUERIES
     assert bh.PLAN_CACHE_PLANS_PER_S not in bh.INVERTED_QUERIES

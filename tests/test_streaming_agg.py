@@ -173,3 +173,55 @@ def test_planner_inserts_coalesce_batches():
     df.collect()
     tree = s._last_exec_plan._tree_string()
     assert "TpuCoalesceBatchesExec" in tree, tree
+
+
+def _five_aggs_over(scan, mode_chain):
+    """sum / count / avg / min of ``v`` and a bigint sum of ``q`` by ``k``,
+    as one ``complete`` operator or as ``partial`` under ``final``."""
+    exprs = _resolve_all(
+        [ex.ColumnRef("k")] + [
+            lp.AggregateExpression(op, ex.ColumnRef(c))
+            for op, c in (("sum", "v"), ("count", "v"), ("avg", "v"),
+                          ("min", "v"), ("sum", "q"))], scan.schema)
+    node, chain = scan, []
+    for mode in mode_chain:
+        node = TpuHashAggregateExec(node, [exprs[0]], exprs, mode=mode)
+        chain.append(node)
+    return node, chain
+
+
+@pytest.mark.parametrize("mode_chain", [("complete",), ("partial", "final")],
+                         ids=["complete", "partial_final"])
+def test_eager_fallback_equals_the_fused_programs(mode_chain):
+    """The eager fallback (``_update_partial_eager``, ``_merge_to_partial``,
+    ``_final`` of an operator whose fusion broke) is the fused programs'
+    ``groupby_aggregate`` called op by op: the same rows on the same three
+    batches, the integers equal and the doubles to the last bits."""
+    rng = np.random.default_rng(23)
+    n = 5000
+    df = pd.DataFrame({
+        "k": [None if x % 19 == 0 else int(x)
+              for x in rng.integers(0, 200, n)],
+        "v": [None if rng.random() < 0.1 else float(x)
+              for x in rng.normal(0, 100, n)],
+        "q": rng.integers(-10**12, 10**12, n)}).astype({"k": "Int64"})
+
+    def rows(broken):
+        node, chain = _five_aggs_over(_scan(df, batch_rows=2048), mode_chain)
+        for op in chain:
+            op._fusion_broken = broken
+        return _collect_rows(node)
+
+    fused, eager = rows(False), rows(True)
+    assert len(fused) == len(eager) == df.k.nunique(dropna=False)
+    for a, b in zip(fused, eager):
+        assert a[0] == b[0] and a[2] == b[2] and a[5] == b[5], (a, b)
+        for x, y in ((a[1], b[1]), (a[3], b[3]), (a[4], b[4])):
+            assert x == pytest.approx(y, rel=1e-14), (a, b)
+    want = df.groupby("k", dropna=False).agg(
+        sv=("v", "sum"), n=("v", "count"), sq=("q", "sum"))
+    got = {r[0]: r for r in eager}
+    for key, w in want.iterrows():
+        r = got[None if pd.isna(key) else int(key)]
+        assert r[2] == w.n and r[5] == w.sq
+        assert r[1] == pytest.approx(w.sv, rel=1e-12)

@@ -179,5 +179,5 @@ def test_kernel_of_joins_string_tags():
         "concat"
     assert recompile.kernel_of(
         ("agg", "update", "partial", ("k",), ("b",), (), ("f64",),
-         "dense", 128)) == "agg/update/partial/dense"
+         "sort", 128)) == "agg/update/partial/sort"
     assert recompile.kernel_of(42) == "anon"

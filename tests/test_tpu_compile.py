@@ -2,11 +2,10 @@
 compiled for a DESCRIBED TPU v5e (no device attached, nothing runs).
 
 The suite runs on the CPU backend, where the accelerator-only branches —
-MXU matmul segment reductions, HBM-sized batch capacities, 64-bit
-emulation — never build. What the TPU compiler refuses (a 64-bit float
-bitcast, a program that does not fit HBM, a collective it cannot
-partition) shows here at no chip time. A compile that passes is not a
-chip run: ``chip_smoke.py`` is.
+HBM-sized batch capacities, 64-bit emulation — never build. What the TPU
+compiler refuses (a 64-bit float bitcast, a program that does not fit
+HBM, a collective it cannot partition) shows here at no chip time. A
+compile that passes is not a chip run: ``chip_smoke.py`` is.
 
 Programs WITHOUT a large sort compile at the capacities the chip run
 uses (the batch autotuner's pick for a 16 GB chip). Each large sort costs
@@ -135,76 +134,47 @@ def test_scan_unpack_of_lineitem_compiles(one_chip, monkeypatch, rows):
 
 
 # ---------------------------------------------------------------------------
-# Aggregation: the two MXU paths that are off on the CPU backend
+# Aggregation: the one group-by, ``groupby_aggregate``
 # ---------------------------------------------------------------------------
 
-def test_dense_mxu_groupby_stage_compiles(one_chip):
-    """filter -> project -> dense-range one-hot-matmul group-by, the
-    stage bench.py and __graft_entry__ build, at the autotuner's ceiling
-    (1 << 23 rows; int64 keys, float64 values)."""
+def test_graft_entry_stage_compiles_and_fits_hbm(one_chip):
+    """filter -> project -> group-by, the stage ``__graft_entry__.entry()``
+    builds, at the autotuner's ceiling (1 << 23 rows; int64 keys, float64
+    values): the sort, both sides of the choice by the group count."""
+    import __graft_entry__
+    cap = 1 << 23
+    fused, example = __graft_entry__.entry()
+    structs = [jax.ShapeDtypeStruct((cap,) * a.ndim, a.dtype,
+                                    sharding=one_chip) for a in example]
+    _fits_hbm(_compile(fused, *structs))
+
+
+def test_many_group_scatter_side_of_q1s_group_by_compiles(one_chip):
+    """q1's shape (two string keys, bigint and double sums, an average,
+    two counts) through ``groupby_aggregate`` above FEW_GROUPS_MAX slots:
+    the program holds the choice, and its many-group side is scatters."""
     from spark_rapids_tpu.ops import aggregates as agg_k
-    cap, k_slots = 1 << 23, 1152
-
-    def fused(keys, key_valid, vals, val_valid, flags, num_rows):
-        live = jnp.arange(cap) < num_rows
-        keep = live & flags & val_valid & (vals > 0)
-        proj = Column(dt.FLOAT64, vals * 2.0 + 1.0, val_valid)
-        rmin = jnp.min(jnp.where(keep & key_valid, keys,
-                                 jnp.iinfo(jnp.int64).max))
-        out_keys, out_aggs, n_groups = agg_k.groupby_dense(
-            Column(dt.INT64, keys, key_valid),
-            [agg_k.AggSpec("sum", proj), agg_k.AggSpec("count", proj),
-             agg_k.AggSpec("avg", proj)],
-            num_rows, k_slots, rmin, extra_mask=keep)
-        return (out_keys[0].data, out_aggs[0].data, out_aggs[1].data,
-                out_aggs[2].data, n_groups)
-
-    def s(npdt, shape=(cap,)):
-        return jax.ShapeDtypeStruct(shape, npdt, sharding=one_chip)
-    compiled = _compile(fused, s(jnp.int64), s(jnp.bool_), s(jnp.float64),
-                        s(jnp.bool_), s(jnp.bool_), s(jnp.int32, ()))
-    _fits_hbm(compiled)
-
-
-def test_sort_groupby_with_matmul_reductions_compiles(one_chip):
-    """q1's shape: two string keys -> lexsort -> segment ids -> every
-    aggregate through the matmul segment reductions that
-    ``agg.matmul.enabled=auto`` turns on only off the CPU."""
-    from spark_rapids_tpu.ops import aggregates as agg_k
-    from spark_rapids_tpu.ops import kernels as K
-    cap, kb = SMALL_SORT, 128
+    cap = SMALL_SORT
+    assert cap > agg_k.FEW_GROUPS_MAX
 
     def q1_kernel(num_rows, *arrays):
         flag = Column(dt.STRING, *arrays[0:3])
         status = Column(dt.STRING, *arrays[3:6])
         qty = Column(dt.INT64, *arrays[6:8])
         price = Column(dt.FLOAT64, *arrays[8:10])
-        keys = [flag, status]
-        order = K.sort_indices([K.SortKey(c) for c in keys], num_rows, cap)
-        skeys = [K.gather_column(c, order) for c in keys]
-        starts = K.segment_starts_from_sorted_keys(skeys, num_rows, cap)
-        seg_ids = K.segment_ids(starts)
-        live = jnp.arange(cap) < num_rows
-        outs, on_mxu = [], 0
-        for op, col in (("sum", qty), ("sum", price), ("avg", price),
-                        ("count", qty), ("count_star", None)):
-            spec = agg_k.AggSpec(
-                op, None if col is None else K.gather_column(col, order))
-            # per-spec mixing, as TpuHashAggregateExec._finish_sortmm does
-            if agg_k._matmul_supported(spec):
-                on_mxu += 1
-                agg = agg_k.segment_aggregate_matmul(spec, seg_ids, live, kb)
-            else:
-                agg = agg_k.segment_aggregate(spec, seg_ids, live, cap,
-                                              num_segments=kb)
-            outs.append(agg.data)
-        assert on_mxu >= 4     # only the bigint sum is left: masked at kb
-        return tuple(outs) + (jnp.sum(starts),)
+        specs = [agg_k.AggSpec(op, col) for op, col in (
+            ("sum", qty), ("sum", price), ("avg", price), ("count", qty),
+            ("count_star", None))]
+        _keys, aggs, n_groups = agg_k.groupby_aggregate(
+            [flag, status], specs, num_rows, cap)
+        return tuple(a.data for a in aggs) + (n_groups,)
 
     structs = [jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)]
     for t in (dt.STRING, dt.STRING, dt.INT64, dt.FLOAT64):
         structs += _column_structs(t, cap, one_chip)
-    _compile(q1_kernel, *structs)
+    text = _compile(q1_kernel, *structs).as_text()
+    assert " conditional(" in text
+    assert sum(" scatter(" in line for line in text.splitlines()) > 1
 
 
 def test_few_group_masked_reductions_compile_with_no_scatter(one_chip):
