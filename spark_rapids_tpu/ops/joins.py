@@ -6,8 +6,13 @@ sort-merge join with hash join. Here we invert (DESIGN.md §3): TPU has no devic
 hash tables but sorts fast, so all equality joins are sort-merge:
 
   1. lexsort the BUILD side by its keys (order-preserving unsigned encodings)
-  2. vectorized multi-word binary search gives, per STREAM row, the contiguous
-     range [lo, hi) of matching build rows
+  2. a merge rank gives, per STREAM row, the contiguous range [lo, hi) of
+     matching build rows: ONE stable sort of the build's and the stream's
+     encoded keys together (``_merge_bounds``). Not a binary search: that
+     gathers every build word once a round, 23 rounds for an 8 Mi-row
+     build, and the chip gathers ~85 M elements a second where it sorts
+     8 Mi two-operand rows in 22 ms (the ledger's PR 29 breakdowns: the
+     search was 21.4 of ``tpch_sf1_mesh4.q3``'s 38.4 s a query)
   3. a prefix-sum over match counts + gather expands the pairs into output rows
 
 Two-phase dynamic-size protocol (DESIGN.md): ``join_match`` returns the device
@@ -47,23 +52,24 @@ def _widen_string(col: Column, width: int) -> Column:
     return Column(col.dtype, data, col.validity, col.lengths)
 
 
-def _normalize_words(cols: Sequence[Column]) -> Tuple[List[jnp.ndarray], jnp.ndarray]:
-    """Stack all key columns' sort-key words into one most-significant-first
-    list, plus the row-is-usable (all keys non-NULL) mask.
+def _key_words(cols: Sequence[Column]):
+    """All key columns' sort-key words as one most-significant-first list of
+    ``(array, bit_width)`` pairs, plus the row-is-usable (all keys non-NULL)
+    mask.
 
-    Uses EXACTLY the encoding ``sort_indices`` sorts by (``_key_arrays``:
-    null-rank word + value words), so the binary search's lexicographic order
-    matches the build side's sorted order — including NULL rows, which sort
-    first and carry zeroed data words. Word equality == SQL join-key equality
-    for usable rows: NaNs unified by the NaN-rank word, -0.0 == 0.0 by native
-    float compare, f64 compared at full precision.
+    EXACTLY the encoding ``sort_indices`` sorts by (``_key_arrays_bits``:
+    null-rank word + value words), so the merge's lexicographic order is
+    the build side's sorted order, NULL rows included (they sort first and
+    carry zeroed data words). Word equality == SQL join-key equality for
+    usable rows: NaNs unified by the NaN-rank word, f64 compared at full
+    precision, -0.0 == 0.0 once ``_merge_bounds`` has folded the zeros.
     """
-    all_words: List[jnp.ndarray] = []
+    words = []
     usable = None
     for c in cols:
-        all_words.extend(K._key_arrays(K.SortKey(c)))
+        words.extend(K._key_arrays_bits(K.SortKey(c)))
         usable = c.validity if usable is None else (usable & c.validity)
-    return all_words, usable
+    return words, usable
 
 
 def _lex_cmp(a_words: List[jnp.ndarray], b_words: List[jnp.ndarray]):
@@ -76,40 +82,49 @@ def _lex_cmp(a_words: List[jnp.ndarray], b_words: List[jnp.ndarray]):
     return lt, eq
 
 
-def _search_bounds(build_words: List[jnp.ndarray], n_build,
-                   probe_words: List[jnp.ndarray], side: str) -> jnp.ndarray:
-    """Vectorized binary search of each probe key into the sorted build keys.
+def _merge_bounds(build_words, n_build, probe_words
+                  ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """``(lo, hi)`` per probe row: the range of the SORTED build keys equal
+    to it, ``numpy.searchsorted`` ``"left"`` / ``"right"`` over the first
+    ``n_build`` build rows (the rows behind them are +infinity).
 
-    side='left' -> first index with build >= probe; 'right' -> first with
-    build > probe. Build rows beyond n_build are treated as +infinity.
+    A merge rank: ONE stable sort of build and probe keys together reads
+    every key once, where a binary search gathers every build word once a
+    round (module docstring, step 2). Build rows stand first in the
+    concatenation, so on equal keys a build row precedes a probe row; a
+    probe row's ``hi`` is then the number of live build rows before it,
+    and its ``lo`` that number as it stood where its run of equal keys
+    began. The build's padding rows count for nothing wherever their
+    words send them: they are no live build row, and inside a run they
+    carry the run's key.
     """
-    cap = build_words[0].shape[0]
-    steps = max(1, (cap - 1).bit_length())
-    # a host row count would be a literal of the loop: one program per
-    # count, built anew for every data set. As an array it is an operand
+    cap_b = build_words[0][0].shape[0]
+    # a host row count would be a literal of a jitted caller: one program
+    # per count, built anew for every data set. As an array it is an operand
     n_build = jnp.asarray(n_build, dtype=jnp.int32)
-    lo = jnp.zeros(probe_words[0].shape, dtype=jnp.int32)
-    hi = jnp.full(probe_words[0].shape, n_build, dtype=jnp.int32)
-
-    def body(_, lohi):
-        lo, hi = lohi
-        active = lo < hi                        # converged lanes must freeze
-        mid = (lo + hi) // 2
-        midc = jnp.clip(mid, 0, cap - 1)
-        bw = [w[midc] for w in build_words]
-        blt, beq = _lex_cmp(bw, probe_words)   # build[mid] < probe, == probe
-        if side == "left":
-            go_right = blt                      # build < probe -> search right
-        else:
-            go_right = blt | beq                # build <= probe -> search right
-        # rows at/after n_build are +infinity, never less-or-equal
-        go_right = go_right & (mid < n_build)
-        lo = jnp.where(active & go_right, mid + 1, lo)
-        hi = jnp.where(active & ~go_right, mid, hi)
-        return lo, hi
-
-    lo, hi = jax.lax.fori_loop(0, steps, body, (lo, hi))
-    return lo
+    words = []
+    for (b, bits), (p, _) in zip(build_words, probe_words):
+        w = jnp.concatenate([b, p])
+        if bits is None:
+            # a float value: the sort would put every -0.0 before every
+            # 0.0, SQL calls them equal
+            w = jnp.where(w == 0, jnp.zeros((), w.dtype), w)
+        words.append((w, bits))
+    src = jnp.arange(words[0][0].shape[0], dtype=jnp.int32)
+    lanes, src = K.lexsort_carrying(K.pack_key_bits(words), src)
+    is_build = src < n_build                     # a LIVE build row
+    below = jnp.cumsum(is_build, dtype=jnp.int32)   # live build rows <= here
+    differs = lanes[0][1:] != lanes[0][:-1]
+    for w in lanes[1:]:
+        differs = differs | (w[1:] != w[:-1])
+    run_start = jnp.concatenate([jnp.ones(1, dtype=jnp.bool_), differs])
+    # live build rows before the run: never decreases, so the run's first
+    # value rides along it as a running maximum
+    lo = jax.lax.cummax(jnp.where(run_start, below - is_build, 0))
+    # back to probe order: the probe rows are the last of the concatenation
+    # (two scatters by ``src`` cost three times this sort on the chip)
+    _, lo, hi = jax.lax.sort((src, lo, below), num_keys=1, is_stable=False)
+    return lo[cap_b:], hi[cap_b:]
 
 
 class JoinMatch(NamedTuple):
@@ -144,13 +159,11 @@ def join_match(build_keys: Sequence[Column], n_build,
 def _probe_sorted(sorted_build: Sequence[Column], order, n_build,
                   build_cap: int, stream_keys: Sequence[Column], n_stream,
                   stream_capacity: int) -> JoinMatch:
-    """The probe half of :func:`join_match`: binary-search every stream
-    key in the sorted build keys."""
-    b_words, b_usable = _normalize_words(sorted_build)
-    s_words, s_usable = _normalize_words(stream_keys)
-
-    lo = _search_bounds(b_words, n_build, s_words, "left")
-    hi = _search_bounds(b_words, n_build, s_words, "right")
+    """The probe half of :func:`join_match`: rank every stream key among
+    the sorted build keys."""
+    b_words, b_usable = _key_words(sorted_build)
+    s_words, s_usable = _key_words(stream_keys)
+    lo, hi = _merge_bounds(b_words, n_build, s_words)
 
     s_live = jnp.arange(stream_capacity) < n_stream
     ok = s_usable & s_live
@@ -176,9 +189,12 @@ def _expand_indices(m: JoinMatch, out_capacity: int
     starts = cum - m.count                       # exclusive prefix
     out_i = jnp.arange(out_capacity, dtype=jnp.int32)
     live = out_i < m.total_pairs
-    # which stream row does output slot i belong to: first j with cum[j] > i
-    stream_idx = jnp.searchsorted(cum, out_i, side="right").astype(jnp.int32)
-    stream_idx = jnp.clip(stream_idx, 0, m.count.shape[0] - 1)
+    # which stream row does output slot i belong to: the first j with
+    # cum[j] > i, which is how many j have cum[j] <= i. The slots are an
+    # arange, so that is a histogram of cum summed up to i: no search
+    ends = jnp.zeros(out_capacity, dtype=jnp.int32).at[cum].add(
+        1, mode="drop", indices_are_sorted=True)
+    stream_idx = jnp.clip(jnp.cumsum(ends), 0, m.count.shape[0] - 1)
     offset = out_i - starts[stream_idx]
     build_sorted_idx = m.lo[stream_idx] + offset
     return stream_idx, build_sorted_idx, live

@@ -209,6 +209,19 @@ def _sort_pass(lane: jnp.ndarray, perm: jnp.ndarray) -> jnp.ndarray:
     return perm
 
 
+def _split_uint64_lanes(lanes_msf: Sequence[jnp.ndarray]
+                        ) -> List[jnp.ndarray]:
+    """uint64 lanes as two uint32 lanes each, most significant first."""
+    lanes: List[jnp.ndarray] = []
+    for a in lanes_msf:
+        if a.dtype == jnp.uint64:
+            lanes += [(a >> jnp.uint64(32)).astype(jnp.uint32),
+                      a.astype(jnp.uint32)]
+        else:
+            lanes.append(a)
+    return lanes
+
+
 @stage("lexsort")
 def _lexsort_passes(lanes_msf: List[jnp.ndarray]) -> jnp.ndarray:
     """Stable int32 permutation ordering rows by most-significant-first key
@@ -227,13 +240,7 @@ def _lexsort_passes(lanes_msf: List[jnp.ndarray]) -> jnp.ndarray:
     uint64 lanes (values wider than 32 bits) split into two uint32 lanes
     first — the TPU emulates 64-bit compares as two 32-bit ones anyway;
     float lanes (unpackable value keys) get a pass of their own."""
-    lanes: List[jnp.ndarray] = []
-    for a in lanes_msf:
-        if a.dtype == jnp.uint64:
-            lanes += [(a >> jnp.uint64(32)).astype(jnp.uint32),
-                      a.astype(jnp.uint32)]
-        else:
-            lanes.append(a)
+    lanes = _split_uint64_lanes(lanes_msf)
     perm = jnp.arange(lanes[0].shape[0], dtype=jnp.int32)
     hi = len(lanes)
     while hi > 0:                        # least-significant lane first
@@ -252,6 +259,31 @@ def _lexsort_passes(lanes_msf: List[jnp.ndarray]) -> jnp.ndarray:
             perm = _sort_pass(lanes[hi - 1], perm)
             hi -= 1
     return perm
+
+
+@stage("lexsort")
+def lexsort_carrying(lanes_msf: Sequence[jnp.ndarray], payload: jnp.ndarray
+                     ) -> Tuple[List[jnp.ndarray], jnp.ndarray]:
+    """Rows stably ordered by most-significant-first key lanes, as the
+    SORTED lanes themselves (uint64 lanes split in two) and ``payload``
+    carried along: for a caller that reads the keys in sorted order.
+
+    The same least-significant-first passes as ``_lexsort_passes``, but
+    each pass sorts one lane as the key and CARRIES the others as operands
+    where that one reads ``lane[perm]``: a full-size gather costs the chip
+    three sorts (60 ms against 21.6 ms for 8 Mi rows, ``tpch_sf1.q1``,
+    ledger PR 29). Single-key passes and not one variadic sort, for the
+    compiler's sake again: the two-key, three-operand sort of 10 Mi rows
+    that an int64 join key needs took it 134-161 s cold on the chip's host,
+    a single-key pass carrying the same lanes 52 s (PERF.md §6, PR 30).
+    Called eagerly, the passes over equal dtypes share one program."""
+    lanes = _split_uint64_lanes(lanes_msf)
+    for t in reversed(range(len(lanes))):          # least significant first
+        key, *rest = jax.lax.sort(
+            (lanes[t], *lanes[:t], *lanes[t + 1:], payload),
+            num_keys=1, is_stable=True)
+        lanes, payload = rest[:t] + [key] + rest[t:-1], rest[-1]
+    return lanes, payload
 
 
 # ---------------------------------------------------------------------------

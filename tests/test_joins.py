@@ -9,9 +9,15 @@ import pandas as pd
 import pytest
 
 from spark_rapids_tpu.columnar import dtypes as dt
+import jax
+import jax.numpy as jnp
+
 from spark_rapids_tpu.columnar.column import Column, bucket
-from spark_rapids_tpu.ops.joins import (cross_join_gather, join_gather,
-                                        join_match, unmatched_build_gather)
+from spark_rapids_tpu.ops import kernels as K
+from spark_rapids_tpu.ops.joins import (_key_words, _merge_bounds,
+                                        _probe_sorted, cross_join_gather,
+                                        join_gather, join_match,
+                                        unmatched_build_gather)
 
 
 def _col(vals, dtype):
@@ -217,6 +223,147 @@ def test_string_keys_different_widths():
     s_out, b_out, _ = _join([bk], [bv], 2, [sk], [sv], 3, "inner")
     got = _rows(s_out[0], b_out[0])
     assert got == _rows([20, 30], [1, 2])
+
+
+# ---------------------------------------------------------------------------
+# The probe's rank itself: [lo, hi) against numpy.searchsorted
+# ---------------------------------------------------------------------------
+
+_NAN = float("nan")
+_RNG = np.random.default_rng(30)
+_DUP_B = [int(x) for x in _RNG.integers(0, 6, 100)]
+_DUP_S = [int(x) for x in _RNG.integers(-1, 8, 120)]
+
+# name -> (key dtypes, build rows, stream rows, n_build); a row is one value
+# per key column. n_build None: every build row is live; "device": that
+# count as a device scalar, as the pipelined exec layer passes it
+_RANK_CASES = {
+    "int64": ([dt.INT64], [5, -3, 9, 5, 0, 2 ** 40],
+              [5, 0, 7, -3, 2 ** 40, -(2 ** 40)], None),
+    "int32": ([dt.INT32], [5, -3, 9, 5, 0], [5, 0, 7, -3, -9], None),
+    "date": ([dt.DATE], [9000, 8000, 9000, -1], [9000, -1, 8500, 8000], None),
+    "float64_nan": ([dt.FLOAT64], [1.5, _NAN, -2.0, _NAN, 1e300],
+                    [_NAN, 1.5, 2.0, -1e300, 1e300], None),
+    "float64_negative_zero": ([dt.FLOAT64], [0.0, -0.0, 1.0, -0.0, -1.0],
+                              [-0.0, 0.0, 1.0, -1.0, 0.5], None),
+    "string": ([dt.STRING], ["pear", "apple", "", "pear", "a-long-key-12"],
+               ["pear", "", "kiwi", "a-long-key-12", "a-long-key"], None),
+    "two_columns": ([dt.INT64, dt.STRING],
+                    [(1, "x"), (1, "y"), (2, "x"), (1, "x"), (0, "z")],
+                    [(1, "y"), (2, "x"), (1, "z"), (1, "x"), (2, "y")], None),
+    "nulls_in_build": ([dt.INT64], [None, 4, None, 0, 4], [4, 0, 1, -7],
+                       None),
+    "nulls_in_stream": ([dt.INT64], [4, 0, 4, 8], [None, 4, None, 0, 9],
+                        None),
+    "nulls_two_columns": ([dt.INT64, dt.STRING],
+                          [(1, None), (None, "x"), (1, "x")],
+                          [(1, "x"), (1, None), (None, "x")], None),
+    "heavy_duplicates": ([dt.INT64], _DUP_B, _DUP_S, None),
+    "n_build_0": ([dt.INT64], [], [3, 0, -1], None),
+    "n_build_1": ([dt.INT64], [3], [3, 0, 4], None),
+    "n_build_cap": ([dt.INT64], [int(x) for x in _RNG.integers(0, 40, 128)],
+                    [int(x) for x in _RNG.integers(-2, 42, 128)], None),
+    "n_build_below_rows": ([dt.INT64], [7, 7, 1, 9, 7, 3], [7, 1, 9, 3], 4),
+    "n_stream_0": ([dt.INT64], [3, 1, 3], [], None),
+    "n_build_device_scalar": ([dt.INT64], [3, 1, 3, 8], [3, 8, 2, 1],
+                              "device"),
+}
+
+
+def _key_cols(dtypes, rows, cap):
+    if len(dtypes) == 1:
+        rows = [(r,) for r in rows]
+    # one byte width for both sides, as join_match's widening leaves them
+    return [Column.from_pylist([r[i] for r in rows], t, capacity=cap,
+                               width=16 if t == dt.STRING else None)
+            for i, t in enumerate(dtypes)]
+
+
+def _encoded(cols):
+    """Each row's encoded key words as one python tuple."""
+    arrs = [np.asarray(w) for w, _bits in _key_words(cols)[0]]
+    return list(zip(*(a.tolist() for a in arrs)))
+
+
+@pytest.mark.parametrize("case", sorted(_RANK_CASES))
+def test_probe_rank_equals_searchsorted(case):
+    dtypes, b_rows, s_rows, n_build = _RANK_CASES[case]
+    as_device = n_build == "device"
+    if n_build is None or as_device:
+        n_build = len(b_rows)
+    n_stream = len(s_rows)
+    cap_b, cap_s = bucket(len(b_rows)), bucket(n_stream)
+    build = _key_cols(dtypes, b_rows, cap_b)
+    stream = _key_cols(dtypes, s_rows, cap_s)
+    nb = jnp.asarray(n_build, jnp.int32) if as_device else n_build
+
+    order = K.sort_indices([K.SortKey(c) for c in build], nb, cap_b)
+    sorted_build = [K.gather_column(c, order) for c in build]
+    s_words, s_usable = _key_words(stream)
+    lo, hi = _merge_bounds(_key_words(sorted_build)[0], nb, s_words)
+
+    # the reference: numpy.searchsorted over the dense ranks of the
+    # encoded keys (python tuples order as the words do; -0.0 == 0.0)
+    b_keys = _encoded(sorted_build)[:n_build]
+    s_keys = _encoded(stream)
+    rank = {k: i for i, k in enumerate(sorted(set(b_keys) | set(s_keys)))}
+    b_rank = np.array([rank[k] for k in b_keys], dtype=np.int64)
+    s_rank = np.array([rank[k] for k in s_keys], dtype=np.int64)
+    assert (np.diff(b_rank) >= 0).all()       # the build sort's own order
+    assert np.array_equal(np.asarray(lo),
+                          np.searchsorted(b_rank, s_rank, "left"))
+    assert np.array_equal(np.asarray(hi),
+                          np.searchsorted(b_rank, s_rank, "right"))
+
+    # and what join_match makes of it: NULL and dead stream rows match
+    # nothing, the others their [lo, hi)
+    m = join_match(build, nb, stream, n_stream, cap_s)
+    usable = np.asarray(s_usable) & (np.arange(cap_s) < n_stream)
+    want = np.where(usable, np.asarray(hi) - np.asarray(lo), 0)
+    assert np.array_equal(np.asarray(m.count), want)
+    assert np.array_equal(np.asarray(m.lo)[usable], np.asarray(lo)[usable])
+    assert int(m.total_pairs) == int(want.sum())
+
+
+def _primitives(jaxpr):
+    """Names of every primitive in a jaxpr, sub-jaxprs included."""
+    names = []
+    for eqn in jaxpr.eqns:
+        names.append(eqn.primitive.name)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            names.extend(_primitives(sub))
+    return names
+
+
+def test_probe_holds_no_loop_of_gathers():
+    """The probe reads each key once: no ``while`` whose body gathers, and
+    the build's row count is an operand (two counts, one trace)."""
+    cap = 128
+    traces = []
+
+    def probe(n_build, bk, bv, sk, sv):
+        traces.append(n_build)
+        m = _probe_sorted([Column(dt.INT64, bk, bv)],
+                          jnp.arange(cap, dtype=jnp.int32), n_build, cap,
+                          [Column(dt.INT64, sk, sv)], cap, cap)
+        return m.lo, m.count
+
+    keys = jnp.arange(cap, dtype=jnp.int64) // 2      # sorted, in pairs
+    valid = jnp.ones(cap, jnp.bool_)
+    jaxpr = jax.make_jaxpr(probe)(jnp.int32(cap), keys, valid, keys, valid)
+    assert "sort" in _primitives(jaxpr.jaxpr)
+    for eqn in jaxpr.eqns:
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            if eqn.primitive.name in ("while", "scan"):
+                assert "gather" not in _primitives(sub), eqn
+    del traces[:]
+    jitted = jax.jit(lambda *args: probe(*args))
+    lo_a, count_a = jitted(jnp.int32(cap), keys, valid, keys, valid)
+    lo_b, count_b = jitted(jnp.int32(10), keys, valid, keys, valid)
+    assert len(traces) == 1
+    assert np.asarray(count_a).tolist() == [2] * cap
+    assert np.asarray(count_b).tolist() == [2] * 10 + [0] * (cap - 10)
+    assert np.asarray(lo_a).tolist() == [i - i % 2 for i in range(cap)]
 
 
 def test_runtime_broadcast_switch():

@@ -223,8 +223,9 @@ def test_few_group_masked_reductions_compile_with_no_scatter(one_chip):
 # ---------------------------------------------------------------------------
 
 def test_sort_merge_join_kernels_compile(one_chip):
-    """join_match (sort the build side, binary-search the stream side)
-    and join_gather (expand the matches) on int64 keys."""
+    """join_match (sort the build side, rank the stream keys among the
+    build keys by one stable sort of both) and join_gather (expand the
+    matches) on int64 keys."""
     from spark_rapids_tpu.ops import joins as J
     cap = SMALL_SORT
 
