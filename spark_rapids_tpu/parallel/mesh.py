@@ -288,33 +288,55 @@ def _scope(operator: str, stage_name: str):
 # In-jit exchange: bucket-by-hash + all_to_all (the ICI shuffle data plane)
 # ---------------------------------------------------------------------------
 
+def _zero_unless(keep: jnp.ndarray, a: jnp.ndarray) -> jnp.ndarray:
+    """``a`` with every row whose ``keep`` is False set to zero."""
+    keep = keep.reshape(keep.shape + (1,) * (a.ndim - 1))
+    return jnp.where(keep, a, jnp.zeros((), a.dtype))
+
+
 def bucket_rows_for_exchange(arrays: Sequence[jnp.ndarray],
                              pids: jnp.ndarray, live: jnp.ndarray,
                              n_workers: int, cap: int
                              ) -> Tuple[List[jnp.ndarray], jnp.ndarray]:
     """Pack rows into [n_workers, cap] slots by target worker id.
 
-    Slot t holds the rows destined for worker t, compacted to the front and
-    zero-padded (the bounce-buffer window analog, WindowedBlockIterator —
-    except static shapes make it one gather instead of a windowing protocol).
+    Slot t holds the rows destined for worker t in their row order,
+    compacted to the front and zero-padded (the bounce-buffer window
+    analog, WindowedBlockIterator, at static shapes). ONE permutation
+    serves every target: a row's class is its target, or ``n_workers`` if
+    it is not live or names no worker; a cumsum per class ranks the rows
+    inside it (n is 2-8: a counting pass, no sort), the classes' exclusive
+    offsets make that a position, and one int32 scatter inverts it, as
+    ``K.compaction_indices`` does for two classes. Each array is then
+    moved ONCE, gathered into target order, and its n slots are cut from
+    that as contiguous slices at the offsets, zeroed past each count: k
+    gathers of ``cap`` rows for k arrays, whatever n.
     Returns (stacked arrays [n, cap, ...], counts int32[n]).
     """
-    outs = [[] for _ in arrays]
-    counts = []
-    for t in range(n_workers):
-        keep = live & (pids == t)
-        perm, cnt = K.compaction_indices(keep)
-        slot_live = jnp.arange(cap) < cnt
-        for i, a in enumerate(arrays):
-            g = a[perm]
-            if g.ndim == 1:
-                g = jnp.where(slot_live, g, jnp.zeros((), g.dtype))
-            else:
-                g = jnp.where(slot_live[:, None], g, jnp.zeros((), g.dtype))
-            outs[i].append(g)
-        counts.append(cnt)
-    stacked = [jnp.stack(o) for o in outs]
-    return stacked, jnp.stack(counts).astype(jnp.int32)
+    classes = [live & (pids == t) for t in range(n_workers)]
+    unsent = ~(live & (pids >= 0) & (pids < n_workers))
+    pos = jnp.zeros(cap, jnp.int32)
+    offsets, counts = [], []
+    offset = jnp.int32(0)
+    for mine in classes + [unsent]:
+        running = jnp.cumsum(mine, dtype=jnp.int32)
+        pos = jnp.where(mine, offset + running - 1, pos)
+        offsets.append(offset)
+        counts.append(running[-1])
+        offset = offset + running[-1]
+    perm = jnp.zeros(cap, jnp.int32).at[pos].set(
+        jnp.arange(cap, dtype=jnp.int32), unique_indices=True)
+    row = jnp.arange(cap, dtype=jnp.int32)
+    stacked = []
+    for a in arrays:
+        # a second ``cap`` of zeros behind the rows: a slice of ``cap``
+        # rows from any offset stays inside, so none is clamped
+        by_target = jnp.concatenate([a[perm], jnp.zeros_like(a)])
+        stacked.append(jnp.stack([
+            _zero_unless(row < counts[t], jax.lax.dynamic_slice_in_dim(
+                by_target, offsets[t], cap, 0))
+            for t in range(n_workers)]))
+    return stacked, jnp.stack(counts[:n_workers])
 
 
 def exchange(stacked: List[jnp.ndarray], counts: jnp.ndarray, axis: str
@@ -329,34 +351,35 @@ def flatten_received(stacked: List[jnp.ndarray], counts: jnp.ndarray,
                      out_cap: int) -> Tuple[List[jnp.ndarray], jnp.ndarray]:
     """[n, cap, ...] received slots -> single [out_cap, ...] compacted arrays.
 
-    Received rows are compacted front-of-slot; build a gather index mapping
-    output position -> (slot, offset)."""
+    Every slot arrives compacted to the front and ZERO-PADDED by its
+    sender (:func:`bucket_rows_for_exchange`), so the window is the
+    concatenation of n contiguous prefixes: into a zeroed window slot 0,
+    1, ..., n-1 are written WHOLE, slot s at the sum of the counts before
+    it. Slot s+1 lands where slot s's live rows end and overwrites its
+    zero padding; the last slot's padding is the window's. ``starts[s] +
+    cap <= (s + 1) * cap <= out_cap`` even when one sender fills every
+    slot, so no write is clamped. n copies an array, no gather."""
     n, cap = stacked[0].shape[0], stacked[0].shape[1]
+    assert out_cap >= n * cap, (out_cap, n, cap)
     starts = jnp.cumsum(counts) - counts          # exclusive prefix
-    total = jnp.sum(counts)
-    out_i = jnp.arange(out_cap, dtype=jnp.int32)
-    live = out_i < total
-    slot = jnp.searchsorted(jnp.cumsum(counts), out_i, side="right"
-                            ).astype(jnp.int32)
-    slot = jnp.clip(slot, 0, n - 1)
-    offset = out_i - starts[slot]
-    offset = jnp.clip(offset, 0, cap - 1)
     outs = []
     for a in stacked:
-        flat = a[slot, offset]
-        if flat.ndim == 1:
-            flat = jnp.where(live, flat, jnp.zeros((), flat.dtype))
-        else:
-            flat = jnp.where(live[:, None], flat, jnp.zeros((), flat.dtype))
-        outs.append(flat)
-    return outs, total.astype(jnp.int32)
+        out = jnp.zeros((out_cap,) + a.shape[2:], a.dtype)
+        for s in range(n):
+            out = jax.lax.dynamic_update_slice_in_dim(out, a[s], starts[s],
+                                                      0)
+        outs.append(out)
+    return outs, jnp.sum(counts).astype(jnp.int32)
 
 
 def _route(operator: str, payload: Sequence[jnp.ndarray],
            pids: jnp.ndarray, live: jnp.ndarray, n: int, cap: int,
            out_cap: int) -> Tuple[List[jnp.ndarray], jnp.ndarray]:
     """A stage's exchange, in its three scoped steps: rows bucketed by
-    target worker, one ``all_to_all``, the received slots flattened."""
+    target worker (one permutation, each payload array moved once), one
+    ``all_to_all``, the received slots flattened (n contiguous writes an
+    array). The window holds slot 0's rows, then slot 1's ..., each in
+    its sender's row order, zeros behind the last live row."""
     with _scope(operator, "bucket"):
         stacked, counts = bucket_rows_for_exchange(payload, pids, live, n,
                                                    cap)

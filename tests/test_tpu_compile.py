@@ -306,14 +306,20 @@ def test_float64_bits_match_numpy_on_the_cpu():
 # Four chips: the SPMD stages, one program across the 2x2 mesh
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("stage", ["groupby", "copartition", "sort"])
+@pytest.mark.parametrize("stage", ["groupby", "copartition", "sort",
+                                   "partition_exchange",
+                                   "copartition_lineitem"])
 def test_mesh_stage_compiles_with_an_all_to_all(four_chips, stage):
     """Each mesh pipeline is ONE program whose exchange is an all-to-all
-    over ICI, sharded over the four chips, within each chip's HBM."""
+    over ICI, sharded over the four chips, within each chip's HBM. The
+    exchange itself (bucket, all_to_all, flatten: PR 33) holds no sort, so
+    the join's stage compiles at the chip run's own shape too: lineitem's
+    four columns of Q3 at 2 Mi rows a worker, an 8 Mi-row window."""
     from spark_rapids_tpu.parallel import mesh as M
     mesh, sharded = four_chips
     cap = SMALL_SORT // 4                # receive windows are 4 x cap
     dtypes = [dt.INT64, dt.FLOAT64, dt.STRING]
+    extra = []
     if stage == "groupby":
         fn = M.distributed_groupby_fn(mesh, [dt.INT64, dt.STRING],
                                       [dt.FLOAT64, dt.INT64],
@@ -321,12 +327,24 @@ def test_mesh_stage_compiles_with_an_all_to_all(four_chips, stage):
         dtypes = [dt.INT64, dt.STRING, dt.FLOAT64, dt.INT64]
     elif stage == "copartition":
         fn = M.copartition_exchange_fn(mesh, dtypes, [0], cap)
+    elif stage == "copartition_lineitem":
+        cap = 1 << 21
+        dtypes = [dt.INT64, dt.FLOAT64, dt.FLOAT64, dt.DATE]
+        fn = M.copartition_exchange_fn(mesh, dtypes, [0], cap)
+    elif stage == "partition_exchange":
+        fn = M.partition_exchange_fn(mesh, dtypes, cap, 16)
+        extra = [jax.ShapeDtypeStruct((4, cap), jnp.int32,
+                                      sharding=sharded)]      # the pids
     else:
         fn = M.distributed_sort_fn(mesh, dtypes, [1, 0], (False, True),
                                    (False, True), cap)
     structs = [a for t in dtypes
                for a in _column_structs(t, cap, sharded, lead=(4,))]
+    structs += extra
     structs.append(jax.ShapeDtypeStruct((4,), jnp.int32, sharding=sharded))
     compiled = fn.lower(*structs).compile()
-    assert "all-to-all" in compiled.as_text()
+    text = compiled.as_text()
+    assert "all-to-all" in text
+    if stage.startswith("copartition"):
+        assert " sort(" not in text          # a counting pass, not a sort
     _fits_hbm(compiled)                      # memory_analysis is per device
