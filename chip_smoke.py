@@ -212,6 +212,34 @@ def run_query(session, tables, name: str, fusion: FusionWarnings,
     return rec
 
 
+def boundary_counts(session, sf: float) -> Dict:
+    """Float64 predicates at their boundaries (ROADMAP M1): ``count(*)``
+    where ``l_discount`` equals, or lies between, values the data holds,
+    against numpy on the host column, exact. The queries' fixed parameters
+    never met the fault this catches: a column value and the equal literal
+    comparing unequal on the chip."""
+    import numpy as np
+    from benchmarks import datagen
+    rec: Dict = {"phase": "boundary", "faults": [], "counts": {}}
+    try:
+        d = datagen.gen_lineitem(sf)["l_discount"]
+        checks = [(f"l_discount = {v}", d == v) for v in (0.05, 0.06, 0.09)]
+        checks += [(f"l_discount BETWEEN {lo} AND {hi}", (d >= lo) & (d <= hi))
+                   for lo, hi in ((0.05, 0.07), (0.02, 0.04))]
+        for where, keep in checks:
+            got = session.sql("SELECT count(*) FROM lineitem WHERE "
+                              + where).collect()[0][0]
+            rec["counts"][where] = got
+            rec["faults"] += plan_faults(session)
+            if got != int(keep.sum()):
+                rec["faults"].append(f"count(*) WHERE {where}: {got}, numpy "
+                                     f"counts {int(keep.sum())}")
+    except Exception as e:
+        rec["faults"].append(f"raised {type(e).__name__}: {e}"[:2000])
+    rec["faults"] = sorted(set(rec["faults"]))
+    return rec
+
+
 def verify_query(name: str, result_rows: Optional[List[tuple]],
                  oracles: Oracles, timeout_s: float) -> Dict:
     """The oracle half: the chip's rows against the pandas oracle's."""
@@ -293,6 +321,7 @@ def run(sf: float, queries: Sequence[str], mesh: bool = False,
                        "customer": int(datagen.CUSTOMER_PER_SF * sf)},
               "compileCacheDir": jax.config.jax_compilation_cache_dir,
               "memoryBudgetBytes": DeviceManager.get().memory_budget_bytes})
+        note(boundary_counts(session, sf))
         results: Dict[str, Optional[List[tuple]]] = {}
         for name in queries:
             rec = run_query(session, tables, name, fusion,

@@ -582,10 +582,29 @@ class TpuSession:
         # collect returned
         rec = getattr(self, "_last_span_recorder", None)
         from ..analysis import recompile
+        operators = [
+            {"depth": d, "operator": name, "metrics": m}
+            for d, name, m in self._last_exec_plan.metrics_tree()]
+        scans = [op["metrics"] for op in operators
+                 if "Scan" in op["operator"]]
+        serving = getattr(self, "_last_serving", None) or {}
         return {
-            "operators": [
-                {"depth": d, "operator": name, "metrics": m}
-                for d, name, m in self._last_exec_plan.metrics_tree()],
+            "operators": operators,
+            # what the scans handed on, and how much of it was uploaded in
+            # THIS query and not served from the device scan cache (0 while
+            # a registered table stays resident)
+            "scan": {
+                "batches": sum(m.get("numOutputBatches", 0) for m in scans),
+                "uploadedBatches": sum(m.get("uploadedBatches", 0)
+                                       for m in scans),
+            },
+            # the parameterized-plan cache: whether this query's plan was
+            # served from it, and how many literals rode as bound
+            # parameters (docs/plan_cache.md)
+            "planCache": {
+                "hit": int(serving.get("planCache") == "hit"),
+                "params": int(serving.get("params") or 0),
+            },
             "memory": {
                 "deviceBytesHeld": cat.device_bytes,
                 "hostBytesHeld": cat.host_bytes,

@@ -50,6 +50,49 @@ def string_width_bucket(max_len: int) -> int:
     return bucket(max_len, MIN_STRING_WIDTH)
 
 
+# ---------------------------------------------------------------------------
+# ONE route for a host float64 onto the device
+# ---------------------------------------------------------------------------
+# A TPU holds a float64 as a PAIR of float32. A value that arrives as a
+# float64 (an argument, a device_put, a constant of the program) is split on
+# the host or by the compiler; one that arrives as bytes and is bitcast on
+# the device, as every scanned column is (batch._unpack_program), is split
+# by the program, and the two splits round the low word differently: equal
+# float64 values then compare unequal. So every float64 the HOST hands the
+# device goes as its eight bytes and becomes a float64 THERE: columns through
+# the staging buffer's unpack, scalars through the two functions below.
+
+def float64_words(value) -> np.ndarray:
+    """The eight bytes of ``value`` as a float64, ``uint8[8]``."""
+    return np.asarray(value, dtype=np.float64).reshape(1).view(np.uint8)  # lint: host-sync-ok boxes a python scalar host-side; no device value involved
+
+
+def float64_from_words(words):
+    """``uint8[..., 8]`` on the device -> ``float64[...]``, the bitcast the
+    scan unpack makes its columns by."""
+    return jax.lax.bitcast_convert_type(words, jnp.float64)
+
+
+def device_scalar(value, npdt):
+    """A host scalar as a 0-d device value of ``npdt``; a value that is
+    already on the device or traced passes through. The float64's bytes sit
+    behind a barrier: folded at compile time they would be split as a
+    constant is, not as the columns are."""
+    if np.dtype(npdt) != np.float64 or isinstance(value, jax.Array):
+        return jnp.asarray(value, dtype=npdt)
+    return float64_from_words(
+        jax.lax.optimization_barrier(jnp.asarray(float64_words(value))))
+
+
+def device_column(dtype: dt.DType, arrays) -> "Column":
+    """Host arrays of one column -> a device Column; float64 data takes the
+    scan's route (one staging buffer, the cached unpack program)."""
+    if any(a.dtype == np.float64 for a in arrays):
+        from .batch import _upload_packed
+        return _upload_packed([(dtype, list(arrays))])[0]
+    return Column(dtype, *[jnp.asarray(a) for a in arrays])
+
+
 @dataclass(frozen=True)
 class Scalar:
     """Device-free scalar value paired with its SQL type (cuDF ``Scalar`` analog,
@@ -149,7 +192,7 @@ class Column:
         storage[:n] = np.where(validity, v, np.zeros((), dtype=dtype.numpy_dtype)) \
             if len(v) else v
         valid[:n] = validity
-        return Column(dtype, jnp.asarray(storage), jnp.asarray(valid))
+        return device_column(dtype, (storage, valid))
 
     @staticmethod
     def from_pylist(values: Sequence[Any], dtype: dt.DType,
@@ -261,7 +304,7 @@ class Column:
                 vals = [dict(v) if v is not None else None for v in vals]
             return Column.from_pylist(vals, dtype, capacity, width)
         dtype, arrays = host
-        return Column(dtype, *[jnp.asarray(a) for a in arrays])
+        return device_column(dtype, arrays)
 
     @staticmethod
     def host_from_arrow(arr, capacity: Optional[int] = None,
@@ -375,8 +418,9 @@ class Column:
                              jnp.zeros((), jnp.uint8))
             lengths = jnp.where(valid, jnp.int32(len(b)), 0)
             return Column(dt.STRING, data, valid, lengths)
-        data = jnp.full(cap, scalar.value, dtype=scalar.dtype.numpy_dtype)
-        data = jnp.where(valid, data, jnp.zeros((), dtype=scalar.dtype.numpy_dtype))
+        npdt = scalar.dtype.numpy_dtype
+        data = jnp.where(valid, device_scalar(scalar.value, npdt),
+                         jnp.zeros((), dtype=npdt))
         return Column(scalar.dtype, data, valid)
 
     # -- host extraction -----------------------------------------------------

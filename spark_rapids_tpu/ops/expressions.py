@@ -19,7 +19,8 @@ import numpy as np
 
 from ..columnar import dtypes as dt
 from ..columnar.batch import ColumnarBatch
-from ..columnar.column import Column, Scalar
+from ..columnar.column import (Column, Scalar, device_scalar,
+                               float64_from_words, float64_words)
 
 ColumnOrScalar = Union[Column, Scalar]
 
@@ -245,8 +246,12 @@ class Parameter(Literal):
                 "execution")
         pv = getattr(batch, "params", ()) if batch is not None else ()
         if pv and self.trace_pos is not None and self.trace_pos < len(pv):
-            # inside a fused trace: the value is a traced 0-d argument
-            return Scalar(pv[self.trace_pos], self.dtype)
+            # inside a fused trace: the value is a traced 0-d argument; a
+            # float64's is its eight bytes (param_arg_values)
+            v = pv[self.trace_pos]
+            if self.dtype == dt.FLOAT64:
+                v = float64_from_words(v)
+            return Scalar(v, self.dtype)
         return Scalar(self.value, self.dtype)
 
     def __repr__(self):
@@ -337,11 +342,14 @@ def string_literal_array(value: str) -> np.ndarray:
 def param_arg_values(params: Sequence["Literal"]) -> tuple:
     """The current value of each of :func:`ordered_params` as a
     dtype-stable numpy array — the extra jit arguments appended after a
-    batch's flat arrays: a parameter's binding as a 0-d scalar, a string
-    literal as :func:`string_literal_array`. Host-side value boxing, no
-    device sync."""
+    batch's flat arrays: a parameter's binding as a 0-d scalar, or for a
+    float64 as its eight bytes (the columns' route onto the device,
+    ``columnar/column.float64_words``); a string literal as
+    :func:`string_literal_array`. Host-side value boxing, no device
+    sync."""
     return tuple(
         string_literal_array(p.value) if type(p) is Literal else
+        float64_words(p.value) if p.dtype == dt.FLOAT64 else
         np.asarray(p.value, dtype=p.dtype.numpy_dtype)  # lint: host-sync-ok boxes a python scalar host-side; no device value involved
         for p in params)
 
@@ -455,12 +463,13 @@ def data_validity(value: ColumnOrScalar, dtype: dt.DType):
     """(data, validity) pair usable in jnp broadcasting.
 
     Scalars become 0-d jnp values + validity True/False python bools so XLA folds
-    them as constants inside fused computations.
+    them as constants inside fused computations (a float64 stays its bytes
+    until the device: ``columnar/column.device_scalar``).
     """
     if isinstance(value, Scalar):
         if value.is_null:
             return jnp.zeros((), dtype=dtype.numpy_dtype), False
-        return jnp.asarray(value.value, dtype=dtype.numpy_dtype), True
+        return device_scalar(value.value, dtype.numpy_dtype), True
     return value.data, value.validity
 
 

@@ -15,7 +15,7 @@ import jax.numpy as jnp
 
 from ..columnar import dtypes as dt
 from ..columnar.batch import ColumnarBatch
-from ..columnar.column import Column, Scalar
+from ..columnar.column import Column, Scalar, device_scalar
 from .expressions import (Expression, combine_validity, data_validity,
                           result_column)
 from .strings_util import string_equal, string_compare
@@ -346,7 +346,8 @@ class In(Expression):
             match = jnp.zeros(batch.capacity, dtype=jnp.bool_)
             for x in concrete:
                 match = match | jnp.broadcast_to(
-                    vd == jnp.asarray(x, child.dtype.numpy_dtype), (batch.capacity,))
+                    vd == device_scalar(x, child.dtype.numpy_dtype),
+                    (batch.capacity,))
         vval = v.validity if isinstance(v, Column) else jnp.broadcast_to(
             jnp.asarray(not v.is_null), (batch.capacity,))
         validity = vval & (match | (not has_null))

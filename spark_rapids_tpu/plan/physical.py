@@ -837,7 +837,7 @@ class TpuLocalScanExec(TpuExec):
     """In-memory arrow table scan -> device batches (HostColumnarToGpu analog)."""
 
     CONTRACT = exec_contract(schema="defined", partitioning="source")
-    METRICS = exec_metrics("scanTime", "cacheHitBatches")
+    METRICS = exec_metrics("scanTime", "cacheHitBatches", "uploadedBatches")
 
     def __init__(self, table, schema: dt.Schema, batch_rows: int = 1 << 20,
                  num_partitions: int = 1, base_data=None):
@@ -982,6 +982,7 @@ class TpuLocalScanExec(TpuExec):
                     nbytes = ColumnarBatch.prepped_size_bytes(prepped)
                     _reserve(nbytes)
                     batch = ColumnarBatch.upload_prepped(prepped)
+                    self.metrics.inc("uploadedBatches")
                     cls = TpuLocalScanExec
                     if cache is not None and prepped[0] == "packed":
                         # budget check under the lock: concurrent tasks

@@ -134,3 +134,31 @@ def test_cpu_fallback_node_and_raised_query_fail_the_smoke(monkeypatch):
                                chip_smoke.FusionWarnings())
     assert rec["faults"] == ["raised ValueError: planner exploded"]
     assert "resultRows" not in rec
+
+
+def test_boundary_counts_are_exact_and_a_dropped_boundary_fails(
+        clean_records, monkeypatch):
+    """The check that would have caught ROADMAP M1: float64 equality and
+    BETWEEN at values the column holds, against numpy, exact. A count that
+    loses its boundary rows (here: the engine's rows are right and the
+    oracle's column moved, which reads the same) fails the smoke."""
+    import numpy as np
+    from benchmarks import datagen
+    from spark_rapids_tpu.api.session import TpuSession
+    rec = next(r for r in clean_records if r["phase"] == "boundary")
+    d = datagen.gen_lineitem(SF)["l_discount"]
+    assert rec["faults"] == []
+    assert rec["counts"]["l_discount = 0.05"] == int((d == 0.05).sum()) > 0
+    assert rec["counts"]["l_discount BETWEEN 0.05 AND 0.07"] == int(
+        ((d >= 0.05) & (d <= 0.07)).sum())
+    assert len(rec["counts"]) == 5
+
+    session = TpuSession.builder.config(
+        {"spark.rapids.tpu.sql.explain": "NONE"}).getOrCreate()
+    datagen.register_tables(session, SF)
+    real = datagen.gen_lineitem
+    monkeypatch.setattr(datagen, "gen_lineitem", lambda sf: dict(
+        real(sf), l_discount=np.nextafter(real(sf)["l_discount"], 1.0)))
+    bad = chip_smoke.boundary_counts(session, SF)
+    assert len(bad["faults"]) == 5 and "numpy counts 0" in bad["faults"][0]
+    assert not chip_smoke.verdict([bad], V5E, 1, ())["ok"]
