@@ -587,6 +587,8 @@ class TpuSession:
             for d, name, m in self._last_exec_plan.metrics_tree()]
         scans = [op["metrics"] for op in operators
                  if "Scan" in op["operator"]]
+        coalesces = [op["metrics"] for op in operators
+                     if op["operator"] == "TpuCoalesceBatchesExec"]
         serving = getattr(self, "_last_serving", None) or {}
         return {
             "operators": operators,
@@ -597,6 +599,15 @@ class TpuSession:
                 "batches": sum(m.get("numOutputBatches", 0) for m in scans),
                 "uploadedBatches": sum(m.get("uploadedBatches", 0)
                                        for m in scans),
+            },
+            # what the plan's coalesces did with their input batches:
+            # handed on untouched (already at the target), or concatenated
+            # into ``outputs`` runs (TpuCoalesceBatchesExec)
+            "coalesce": {
+                "passed": sum(m.get("passedBatches", 0) for m in coalesces),
+                "concatenated": sum(m.get("concatBatches", 0)
+                                    for m in coalesces),
+                "outputs": sum(m.get("concatOutputs", 0) for m in coalesces),
             },
             # the parameterized-plan cache: whether this query's plan was
             # served from it, and how many literals rode as bound

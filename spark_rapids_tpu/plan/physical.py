@@ -117,9 +117,11 @@ class TpuExec:
     def children_coalesce_goal(self, i: int):
         """Per-child batch goal (CoalesceGoal lattice,
         GpuCoalesceBatches.scala:117-130): None (no requirement), "target"
-        (concat small batches toward the configured batch size), or "single"
-        (RequireSingleBatch: the op needs the whole partition in one batch).
-        The transition pass inserts TpuCoalesceBatchesExec accordingly."""
+        (batches under the configured batch size are concatenated up to it
+        and never past it; one already at it is handed on untouched), or
+        "single" (RequireSingleBatch: the op needs the whole partition in
+        one batch). The transition pass inserts TpuCoalesceBatchesExec
+        accordingly."""
         return None
 
     def execute(self) -> List[Partition]:
@@ -1189,11 +1191,21 @@ class TpuFilterExec(TpuExec):
 
 
 class TpuCoalesceBatchesExec(TpuExec):
-    """Concatenate small batches up to a goal (GpuCoalesceBatches). goal:
-    'single' (RequireSingleBatch) or target row count."""
+    """Bring small batches up to a goal (GpuCoalesceBatches). goal 'single'
+    (RequireSingleBatch): the whole partition as one batch. goal 'target':
+    ``target_rows`` is a CEILING, not a trigger. A batch already at or over
+    it is handed on as it is (the same object, no program), and smaller
+    ones are concatenated in runs that stop before they would pass it, so
+    an output is larger than the target only where one input batch was.
+    Row order within the partition is kept.
+
+    Metrics: ``passedBatches`` (handed on untouched), ``concatBatches``
+    (inputs that went into a run; a run of one costs no program either)
+    and ``concatOutputs`` (runs); ``concatTime`` is round the runs only."""
 
     CONTRACT = exec_contract(schema="passthrough", partitioning="preserve")
-    METRICS = exec_metrics("concatTime")
+    METRICS = exec_metrics("concatTime", "passedBatches", "concatBatches",
+                           "concatOutputs")
 
     def __init__(self, child: TpuExec, goal: Any = "single",
                  target_rows: int = 1 << 22):
@@ -1213,38 +1225,59 @@ class TpuCoalesceBatchesExec(TpuExec):
         # batches must not pin a whole partition in HBM below sort/window
         # (the reference's GpuCoalesceBatches accumulates spillable batches).
         # Device-resident counts resolve in chunked batched readbacks (one
-        # host round-trip per 8 batches), and coalesced outputs still yield
-        # INCREMENTALLY so downstream consumes while upstream streams; the
-        # target-size check runs at chunk granularity.
+        # host round-trip per 8 batches) and the goal is then applied batch
+        # by batch; while no such count is waiting, a host-known count (every
+        # scan-cache batch) is judged as it arrives. Outputs yield
+        # INCREMENTALLY so downstream consumes while upstream streams.
         from ..columnar.batch import resolve_counts
         from ..exec.spill import SpillableColumnarBatch
+        to_target = self.goal != "single"
         pending: List[SpillableColumnarBatch] = []
         pending_rows = 0
         chunk: List[ColumnarBatch] = []
+        lazy = False                 # a device-resident count is waiting
 
-        def admit() -> None:
-            nonlocal pending_rows
+        def flush() -> Partition:
+            nonlocal pending, pending_rows
+            if not pending:
+                return
+            run, pending, pending_rows = pending, [], 0
+            self.metrics.inc("concatBatches", len(run))
+            self.metrics.inc("concatOutputs")
+            with trace_span("concat", self.metrics, "concatTime"):
+                out = concat_spillable(self.schema, run)
+            yield out
+
+        def admit() -> Partition:
+            nonlocal pending_rows, lazy
             resolve_counts(chunk)        # one round-trip per chunk
             for b in chunk:
-                if b.num_rows > 0:
-                    pending.append(SpillableColumnarBatch(b))
-                    pending_rows += b.num_rows
+                n = b.num_rows
+                if n == 0:
+                    continue
+                if to_target:
+                    if n >= self.target_rows:
+                        yield from flush()   # what came before goes first
+                        self.metrics.inc("passedBatches")
+                        yield b
+                        continue
+                    if pending_rows + n > self.target_rows:
+                        yield from flush()
+                pending.append(SpillableColumnarBatch(b))
+                pending_rows += n
             chunk.clear()
+            lazy = False
 
         for batch in part:
-            if isinstance(batch.num_rows_raw, int) and batch.num_rows_raw == 0:
+            if not isinstance(batch.num_rows_raw, int):
+                lazy = True
+            elif batch.num_rows_raw == 0:
                 continue
             chunk.append(batch)
-            if len(chunk) >= 8:
-                admit()
-                if self.goal != "single" and pending_rows >= self.target_rows:
-                    with trace_span("concat", self.metrics, "concatTime"):
-                        yield concat_spillable(self.schema, pending)
-                    pending, pending_rows = [], 0
-        admit()
-        if pending:
-            with trace_span("concat", self.metrics, "concatTime"):
-                yield concat_spillable(self.schema, pending)
+            if not lazy or len(chunk) >= 8:
+                yield from admit()
+        yield from admit()
+        yield from flush()
 
 
 # ---------------------------------------------------------------------------
@@ -1357,8 +1390,11 @@ class TpuHashAggregateExec(TpuExec):
         return self._out_schema
 
     def children_coalesce_goal(self, i: int):
-        # stream per batch, but small scan batches waste per-batch dispatch:
-        # coalesce toward the target batch size (the reference's TargetSize)
+        # _stream_merge updates per batch. Batches UNDER the target batch
+        # size are concatenated up to it first (each costs a dispatch of
+        # the update program); a batch already at the target is passed as
+        # it is: a copy of it would buy no dispatch back (the reference's
+        # TargetSize)
         return "target"
 
     @property
