@@ -49,9 +49,9 @@ _enabled_cache: Optional[bool] = None
 
 #: one family's entry in a query's ``programs`` map
 #: (``last_query_metrics()["programs"]``, docs/observability.md §9)
-_PROGRAM_ZERO = {"dispatches": 0, "traces": 0, "traceS": 0.0, "lowerS": 0.0,
-                 "compiles": 0, "compileS": 0.0, "cacheLoads": 0,
-                 "loadS": 0.0}
+_PROGRAM_ZERO = {"dispatches": 0, "dispatchS": 0.0, "traces": 0,
+                 "traceS": 0.0, "lowerS": 0.0, "compiles": 0,
+                 "compileS": 0.0, "cacheLoads": 0, "loadS": 0.0}
 
 
 def _enabled() -> bool:
@@ -115,11 +115,12 @@ def note_compile(kernel: str, key: Any) -> None:
 
 
 def note_call(kernel: str,
-              query_programs: Optional[Dict[str, Dict[str, Any]]] = None
-              ) -> None:
+              query_programs: Optional[Dict[str, Dict[str, Any]]] = None,
+              seconds: float = 0.0) -> None:
     """Record one dispatch of a family's program: the audit's ``calls``
-    and, where a query is recording, its ``programs`` map — one lock for
-    both (exec/compile_cache.Program calls this per program call)."""
+    and, where a query is recording, its ``programs`` map with the
+    host's ``seconds`` inside the call — one lock for both
+    (exec/compile_cache.Program calls this per program call)."""
     audit = _enabled()
     if not audit and query_programs is None:
         return
@@ -127,7 +128,9 @@ def note_call(kernel: str,
         if audit:
             _ent(kernel)["calls"] += 1
         if query_programs is not None:
-            _program_ent(query_programs, kernel)["dispatches"] += 1
+            ent = _program_ent(query_programs, kernel)
+            ent["dispatches"] += 1
+            ent["dispatchS"] += seconds
 
 
 def _program_ent(programs: Dict[str, Dict[str, Any]], family: str):

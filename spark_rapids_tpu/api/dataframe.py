@@ -32,6 +32,9 @@ class DataFrame:
     def __init__(self, plan: lp.LogicalPlan, session: "TpuSession"):
         self._plan = plan
         self.session = session
+        # (begin, seconds, cache hit) of the ``parse`` span, on a frame
+        # that ``session.sql`` built: the first action takes it over
+        self._parsed = None
 
     # -- plan access ---------------------------------------------------------
     @property
@@ -337,7 +340,8 @@ class DataFrame:
         from ..plan import plan_cache as pc
         # the query's recorders open BEFORE planning: the root span
         # ``query`` covers plan + execute (docs/observability.md §9)
-        recording = QueryRecording().open()
+        parsed, self._parsed = self._parsed, None
+        recording = QueryRecording().open(parsed)
         try:
             try:
                 exec_plan = self._execute()
@@ -449,67 +453,72 @@ class DataFrame:
                     from ..service.telemetry import dump_on_error
                     dump_on_error(e)
                     raise
-            self.session._last_execute_time_s = time.perf_counter() - t0
-            # a materializing collect serves its first row when it serves
-            # its last: firstRowS == executeTimeS, honestly (collect_iter
-            # is the path that beats it; docs/observability.md)
-            self.session._last_first_row_s = \
-                self.session._last_execute_time_s
-            try:
-                # AQE post-execution hook: store observed cardinalities +
-                # exchange bytes under this fingerprint for the NEXT
-                # execution (drift feedback, admission cost weighting)
-                from ..plan import aqe
-                aqe.note_execution(self.session, exec_plan, serving)
-            except Exception:
-                pass
-            try:
-                from ..service.telemetry import MetricsRegistry
-                MetricsRegistry.get().histogram(
-                    "tpu_query_execute_seconds",
-                    "collect-action execute wall seconds").observe(
-                    self.session._last_execute_time_s)
-            except Exception:
-                pass           # observability must never fail the query
-            self.session._last_sync_report = sc.report()
-            self.session._last_span_report = spans.report()
-            # the recorder itself stays reachable so the bench runner /
-            # tests can export the Chrome-trace timeline of this query
-            self.session._last_span_recorder = spans
-            if listeners:
-                from ..analysis import recompile
-                from .session import QueryExecution
-                ov = self.session._last_overrides
-                self.session._notify_query_listeners(QueryExecution(
-                    self.session, exec_plan,
-                    self.session._last_sync_report,
-                    self.session._last_span_report,
-                    recompile.recompiles_of(spans.programs),
-                    lockdep.stats_delta(lk0),
-                    violations=getattr(ov, "last_violations", ()) if ov
-                    else ()))
-            rkey = serving.get("resultKey")
-            if rkey is not None:
-                # store AFTER the sync/span windows closed: the caching
-                # fetch must not perturb this query's reported sync counts
-                out = pc.store_result(self.session, rkey, out)
-            # end-of-query buffer-lifecycle audit (analysis/ledger.py):
-            # runs AFTER store_result so a cached result's pinned buffers
-            # are owned by the cache, not leaked by this query.
-            # BufferLeakError propagates in enforce mode — leak
-            # discipline is the point.
-            from ..analysis import ledger as _ledger
-            self.session._last_ledger = _ledger.end_of_query(qid)
-            try:
-                # opt-in structured query log (service/query_log.py, conf
-                # telemetry.queryLog.dir): one JSONL record per execution.
-                # Best-effort — the log must never fail the query.
-                from ..service import query_log
-                query_log.maybe_log(self.session, exec_plan, serving, qid,
-                                    faults_before=faults0,
-                                    tenant=ctx.tenant)
-            except Exception:
-                pass
+            # what follows the execution, once a query (the adaptive
+            # feedback, the reports, the listeners, the result cache, the
+            # ledger's audit, the query log), is the span ``query_end`` of
+            # the same query: the sync window stays closed
+            with recording.resumed("query_end"):
+                self.session._last_execute_time_s = time.perf_counter() - t0
+                # a materializing collect serves its first row when it serves
+                # its last: firstRowS == executeTimeS, honestly (collect_iter
+                # is the path that beats it; docs/observability.md)
+                self.session._last_first_row_s = \
+                    self.session._last_execute_time_s
+                try:
+                    # AQE post-execution hook: store observed cardinalities +
+                    # exchange bytes under this fingerprint for the NEXT
+                    # execution (drift feedback, admission cost weighting)
+                    from ..plan import aqe
+                    aqe.note_execution(self.session, exec_plan, serving)
+                except Exception:
+                    pass
+                try:
+                    from ..service.telemetry import MetricsRegistry
+                    MetricsRegistry.get().histogram(
+                        "tpu_query_execute_seconds",
+                        "collect-action execute wall seconds").observe(
+                        self.session._last_execute_time_s)
+                except Exception:
+                    pass           # observability must never fail the query
+                self.session._last_sync_report = sc.report()
+                self.session._last_span_report = spans.report()
+                # the recorder itself stays reachable so the bench runner /
+                # tests can export the Chrome-trace timeline of this query
+                self.session._last_span_recorder = spans
+                if listeners:
+                    from ..analysis import recompile
+                    from .session import QueryExecution
+                    ov = self.session._last_overrides
+                    self.session._notify_query_listeners(QueryExecution(
+                        self.session, exec_plan,
+                        self.session._last_sync_report,
+                        self.session._last_span_report,
+                        recompile.recompiles_of(spans.programs),
+                        lockdep.stats_delta(lk0),
+                        violations=getattr(ov, "last_violations", ()) if ov
+                        else ()))
+                rkey = serving.get("resultKey")
+                if rkey is not None:
+                    # store AFTER the sync/span windows closed: the caching
+                    # fetch must not perturb this query's reported sync counts
+                    out = pc.store_result(self.session, rkey, out)
+                # end-of-query buffer-lifecycle audit (analysis/ledger.py):
+                # runs AFTER store_result so a cached result's pinned buffers
+                # are owned by the cache, not leaked by this query.
+                # BufferLeakError propagates in enforce mode — leak
+                # discipline is the point.
+                from ..analysis import ledger as _ledger
+                self.session._last_ledger = _ledger.end_of_query(qid)
+                try:
+                    # opt-in structured query log (service/query_log.py, conf
+                    # telemetry.queryLog.dir): one JSONL record per execution.
+                    # Best-effort — the log must never fail the query.
+                    from ..service import query_log
+                    query_log.maybe_log(self.session, exec_plan, serving, qid,
+                                        faults_before=faults0,
+                                        tenant=ctx.tenant)
+                except Exception:
+                    pass
             return out
         finally:
             import sys as _sys
@@ -548,7 +557,8 @@ class DataFrame:
         SERVED, as a single batch)."""
         from ..exec.tracing import QueryRecording
         from ..plan import plan_cache as pc
-        recording = QueryRecording().open()     # before planning
+        parsed, self._parsed = self._parsed, None
+        recording = QueryRecording().open(parsed)     # before planning
         try:
             try:
                 exec_plan = self._execute()

@@ -347,6 +347,20 @@ class TpuSession:
         return DataFrameReader(self)
 
     def sql(self, query: str) -> DataFrame:
+        """The frame of a SQL text. The lookup in the parse cache (or the
+        lexer and parser on a miss) runs under the span ``parse``, before
+        any query records: the frame carries its begin, seconds and
+        whether the cache hit, and the query that collects the frame
+        takes them over (``last_query_metrics()["host"]``)."""
+        import time
+        from ..exec.tracing import trace_span
+        t0 = time.perf_counter()
+        with trace_span("parse"):
+            df, hit = self._sql_frame(query)
+        df._parsed = (t0, time.perf_counter() - t0, hit)
+        return df
+
+    def _sql_frame(self, query: str):
         from ..plan import plan_cache as pc
         from .sql import parse_sql
         st = pc.serving_stats(self)
@@ -356,13 +370,13 @@ class TpuSession:
             # lexer/parser is skipped entirely; the plan-cache
             # fingerprint downstream still decides plan reuse
             st["parseCacheHits"] += 1
-            return DataFrame(plan, self)
+            return DataFrame(plan, self), True
         if int(self.conf.get(cfg.PARSE_CACHE_MAX_ENTRIES)) > 0:
             st["parseCacheMisses"] += 1
         st["parses"] += 1
         df = parse_sql(query, self)
         self._parse_cache_put(query, df.logical_plan())
-        return df
+        return df, False
 
     # -- SQL-text -> parsed-plan cache (PR 12 follow-up: the layer AHEAD
     # of the plan-cache fingerprint for non-prepared sql() traffic) ------
@@ -590,6 +604,10 @@ class TpuSession:
         coalesces = [op["metrics"] for op in operators
                      if op["operator"] == "TpuCoalesceBatchesExec"]
         serving = getattr(self, "_last_serving", None) or {}
+        sync = getattr(self, "_last_sync_report",
+                       {"hostSyncs": 0, "syncSites": {}})
+        programs = recompile.programs_report(rec.programs) \
+            if rec is not None else {}
         return {
             "operators": operators,
             # what the scans handed on, and how much of it was uploaded in
@@ -625,8 +643,7 @@ class TpuSession:
             # attributed blocking device->host readbacks during the collect
             # (the dominant end-to-end cost on high-latency links; see
             # exec/tracing.SyncCounter)
-            "sync": getattr(self, "_last_sync_report",
-                            {"hostSyncs": 0, "syncSites": {}}),
+            "sync": sync,
             # per-span wall-clock breakdown (self time, nesting excluded):
             # names where executeTimeS went — concurrent partition tasks
             # can legitimately sum past the wall clock
@@ -636,8 +653,14 @@ class TpuSession:
             # family (``<eager>:<op>`` for a jnp op outside the program
             # funnel): dispatches, and XLA's own count and seconds of
             # traces, lowerings, backend compiles and persistent-cache
-            # loads (docs/observability.md §9)
-            "programs": recompile.programs_report(rec.programs)
+            # loads, and the host's seconds inside the calls
+            # (docs/observability.md §9)
+            "programs": programs,
+            # the host's account of the caller's call, from session.sql
+            # to the result's fetch_to_host, in parts that tile it; under
+            # tracing.enabled also the per-batch host sites
+            # (SpanRecorder.host_ledger, docs/observability.md §9)
+            "host": rec.host_ledger(programs, sync.get("syncWaitS", 0.0))
             if rec is not None else {},
             # what the query's SPMD mesh stages moved and how long their
             # three steps took (exec/tracing.MESH_COUNTERS; all zero for
@@ -699,7 +722,9 @@ class TpuSession:
             f"firstRowS={rep.get('firstRowS')} "
             f"hostSyncs={sync.get('hostSyncs', 0)} "
             f"spanWallS={spans.get('wallS', 0.0)} "
-            f"concurrency={spans.get('concurrency', 0.0)}")
+            f"concurrency={spans.get('concurrency', 0.0)}"
+            + "".join(f" {k}={v}" for k, v in rep.get("host", {}).items()
+                      if k != "sites"))
         # serving-cache hit/miss per layer (plan/plan_cache.py)
         from ..plan.plan_cache import serving_line
         sl = serving_line(getattr(self, "_last_serving", None))

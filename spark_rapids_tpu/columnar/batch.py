@@ -14,6 +14,7 @@ from typing import Any, Dict, Iterable, List, Optional, Sequence
 import jax.numpy as jnp
 import numpy as np
 
+from ..exec.tracing import host_site
 from . import dtypes as dt
 from .column import Column, Scalar, bucket
 
@@ -241,6 +242,7 @@ class ColumnarBatch:
         return ColumnarBatch(schema, cols, 0)
 
     # -- flat array form (fused stages / spill / wire share this layout) -----
+    @host_site("flat_args")
     def flat_arrays(self) -> List[jnp.ndarray]:
         """All underlying arrays in schema order: [data, validity(, lengths)]
         per column — the jit-boundary form of a batch."""

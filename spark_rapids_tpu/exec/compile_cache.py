@@ -496,8 +496,10 @@ def install_compile_listener() -> None:
 class Program:
     """One compiled program as its cache hands it out: the jitted
     callable named after its family, and the ONE place a call of it is
-    seen — a dispatch count (the audit's ``calls`` and the active
-    query's ``programs`` map, one lock), the family left open on the
+    seen — a dispatch count and the host's seconds inside the call (the
+    audit's ``calls`` and the active query's ``programs`` map, one lock;
+    the seconds also leave the innermost open span's and host site's own
+    time, ``SpanRecorder.note_inner``), the family left open on the
     thread for the compile listener, and under ``tracing.enabled`` a
     ``program:<family>`` profiler span carrying the query id and the
     innermost open exec."""
@@ -532,11 +534,10 @@ class Program:
 
     def __call__(self, *args, **kwargs):
         rec = tracing.SpanRecorder.active
-        recompile.note_call(
-            self._family, rec.programs if rec is not None else None)
         trace = None if self._called else self._first_call(args)
         prev = open_family()
         _tls.family = self._family
+        t0 = time.perf_counter()
         try:
             if tracing._tracing_on():
                 with tracing.program_annotation(self._family, rec):
@@ -545,6 +546,16 @@ class Program:
                 out = self._fn(*args, **kwargs)
         finally:
             _tls.family = prev
+            # the host's seconds inside the call: argument processing,
+            # transfer of host arguments, enqueue (a first call: its
+            # trace and load too). A program called while another one
+            # traces is inlined into it: a dispatch, no seconds of its own
+            seconds = time.perf_counter() - t0 if prev is None else 0.0
+            recompile.note_call(
+                self._family, rec.programs if rec is not None else None,
+                seconds)
+            if rec is not None and seconds:
+                rec.note_inner(seconds)
         if trace:
             with open(trace, "a") as f:
                 f.write(f"END {time.time():.1f} {self._family}\n")

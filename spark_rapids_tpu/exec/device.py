@@ -170,13 +170,13 @@ class TpuSemaphore:
 
     def release_if_necessary(self) -> None:
         import time
-        from .tracing import record_span
+        from .tracing import record_hold
         if getattr(self._held, "value", False):
             held_for = time.perf_counter() - getattr(
                 self._held, "acquired_at", time.perf_counter())
             self._sem.release()
             self._held.value = False
-            record_span("semaphore_hold", held_for)
+            record_hold(held_for)
             with self._stats_mu:
                 self.hold_s += held_for
 

@@ -118,27 +118,37 @@ def _stack() -> list:
     return st
 
 
+def push_exec(metrics: "TpuMetrics") -> None:
+    """Mark ``metrics`` as the innermost open exec bag on this thread
+    (``trace_span`` with a metered exec; paired with :func:`pop_exec`)."""
+    _stack().append(metrics)
+
+
+def pop_exec(metrics: "TpuMetrics") -> None:
+    # remove by identity, not pop(): spans held open across generator
+    # yields close out of order (the SpanRecorder._pop lesson), and a
+    # bare pop would steal a younger exec's open scope
+    st = _stack()
+    for i in range(len(st) - 1, -1, -1):
+        if st[i] is metrics:
+            del st[i]
+            break
+
+
 @contextmanager
 def exec_scope(metrics: Optional["TpuMetrics"]) -> Iterator[None]:
     """Mark ``metrics`` as the innermost open exec bag on this thread for
-    the duration (no-op for None). Entered by ``trace_span`` whenever a
-    metered exec span opens, and by ``PipelineWindow`` around its batched
-    resolve so deferred readbacks still charge the exec that parked them."""
+    the duration (no-op for None): the ``with`` form of the pair, for a
+    region that is no span (``trace_span`` pushes and pops the bag of a
+    metered span itself)."""
     if metrics is None:
         yield
         return
-    st = _stack()
-    st.append(metrics)
+    push_exec(metrics)
     try:
         yield
     finally:
-        # remove by identity, not pop(): spans held open across generator
-        # yields close out of order (the SpanRecorder._pop lesson), and a
-        # bare pop would steal a younger exec's open scope
-        for i in range(len(st) - 1, -1, -1):
-            if st[i] is metrics:
-                del st[i]
-                break
+        pop_exec(metrics)
 
 
 def current() -> Optional["TpuMetrics"]:

@@ -39,7 +39,7 @@ from __future__ import annotations
 from collections import deque
 from typing import Any, Callable, List
 
-from .tracing import trace_span
+from .tracing import host_site, trace_span
 
 
 class PipelineWindow:
@@ -64,6 +64,7 @@ class PipelineWindow:
     def __len__(self) -> int:
         return len(self._pending)
 
+    @host_site("window")
     def push(self, continuation: Callable[..., Any],
              *scalars) -> List[Any]:
         """Enqueue one entry; returns the results of any entries that
@@ -130,8 +131,7 @@ class PipelineWindow:
             return vals
         import jax
         import jax.numpy as jnp
-        from .metrics import exec_scope
-        with trace_span("pipeline_resolve"), exec_scope(self.metrics):
+        with trace_span("pipeline_resolve", self.metrics):
             try:
                 groups: dict = {}
                 for i, s in device:

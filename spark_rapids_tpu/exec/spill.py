@@ -34,6 +34,7 @@ from ..analysis.lockdep import named_lock, named_rlock
 from ..columnar import dtypes as dt
 from ..columnar.batch import ColumnarBatch
 from ..columnar.column import Column
+from .tracing import host_site
 
 # Spill priority constants (SpillPriorities.scala:26-60): lower spills first.
 OUTPUT_FOR_SHUFFLE_PRIORITY = -100.0   # shuffle outputs idle longest
@@ -741,6 +742,7 @@ class SpillableColumnarBatch:
     """Handle to a batch that may be spilled and rematerialized on demand
     (SpillableColumnarBatch.scala:28-137)."""
 
+    @host_site("spillable")
     def __init__(self, batch: ColumnarBatch,
                  priority: float = ACTIVE_ON_DECK_PRIORITY,
                  catalog: Optional[BufferCatalog] = None):
@@ -761,6 +763,7 @@ class SpillableColumnarBatch:
             self._num_rows = nr
         return nr
 
+    @host_site("spillable")
     def get_batch(self) -> ColumnarBatch:
         if self._closed:
             raise BufferLostError(f"buffer {self._id} released")

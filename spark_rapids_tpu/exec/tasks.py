@@ -20,7 +20,15 @@ import threading
 from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Callable, Iterable, List, Sequence, TypeVar
 
+from .tracing import host_site
+
 T = TypeVar("T")
+
+
+def _pool_threads() -> int:
+    from .. import config as cfg
+    with host_site("conf_read"):
+        return cfg.TpuConf().task_pool_threads
 
 
 def _release_semaphore() -> None:
@@ -270,8 +278,7 @@ def stream_partition_tasks(parts: Sequence[Any],
     completed after the consumer left are logged via the teardown
     discipline, never silently discarded."""
     if max_workers <= 0:
-        from .. import config as cfg
-        max_workers = cfg.TpuConf().task_pool_threads
+        max_workers = _pool_threads()
     from .spill import drain_deferred_finalizers
     drain_deferred_finalizers()
     from . import query_context as _qc
@@ -340,8 +347,7 @@ def run_partition_tasks(parts: Sequence[Any],
     a parent task waiting on child tasks could starve the pool); each task
     releases the TpuSemaphore on completion regardless of outcome."""
     if max_workers <= 0:
-        from .. import config as cfg
-        max_workers = cfg.TpuConf().task_pool_threads
+        max_workers = _pool_threads()
     # safe point for GC-deferred cleanup (exec/spill.defer_finalizer):
     # no engine locks are held at task launch
     from .spill import drain_deferred_finalizers

@@ -26,11 +26,13 @@ or ui.perfetto.dev (see docs/observability.md).
 from __future__ import annotations
 
 import functools
+import threading
+import time
 from contextlib import contextmanager
 from typing import Any, Dict, List, Optional
 
 from ..analysis.lockdep import named_lock
-from .metrics import exec_scope, metrics_enabled
+from .metrics import current, metrics_enabled, pop_exec, push_exec
 
 _enabled: Optional[bool] = None
 _timeline: Optional[bool] = None
@@ -89,6 +91,15 @@ MESH_COUNTERS = ("stages", "iciExchanges", "iciBytes", "placeBytes",
                  "gatherBytes", "placeS", "spmdS", "gatherS")
 
 
+#: what the host does per batch between two program calls, as named sites
+#: (:func:`host_site`; docs/observability.md §9 lists the functions under
+#: each): only under ``tracing.enabled``, as profiler annotations and as
+#: ``last_query_metrics()["host"]["sites"]``
+HOST_SITES = (
+    "count_arg", "conf_read", "fusable", "program_key", "program_lookup",
+    "param_args", "flat_args", "spillable", "admission", "window", "shrink")
+
+
 def stage(name: str, operator: Optional[str] = None):
     """Decorator: the kernel runs inside ``jax.named_scope(name)``, one of
     :data:`STAGES` — and, given ``operator``, inside that scope first.
@@ -130,15 +141,94 @@ def _annotation(name: str, rec: Optional["SpanRecorder"], **kw):
     return jax.profiler.TraceAnnotation(name, **kw)
 
 
+def _open_exec() -> Dict[str, str]:
+    """``op=<exec>`` of the innermost open exec on this thread, for an
+    annotation."""
+    op = getattr(current(), "owner", None)
+    return {"op": op} if op else {}
+
+
 def program_annotation(family: str, rec: Optional["SpanRecorder"]):
     """``program:<family>`` round one program call (only under
     ``tracing.enabled``): the host's tracing, cache load and enqueue lie
     in the profile beside the device ops the call launched."""
-    from .metrics import current
-    bag = current()
-    op = getattr(bag, "owner", None) if bag is not None else None
-    return _annotation("program:" + family, rec,
-                       **({"op": op} if op else {}))
+    return _annotation("program:" + family, rec, **_open_exec())
+
+
+# ---------------------------------------------------------------------------
+# Host sites: what the host does per batch, named (tracing.enabled only)
+# ---------------------------------------------------------------------------
+
+_site_tls = threading.local()
+_SITES: Dict[str, "_HostSite"] = {}
+
+
+class _HostSite:
+    """One name of :data:`HOST_SITES` as a context manager and a
+    decorator. Off, it costs the cached ``_tracing_on()`` check; on, it is
+    a profiler annotation ``site:<name>`` (``query=<id>``, ``op=<exec>``)
+    and adds a count and its SELF seconds to the open recorder: the sites
+    nested inside it, the program calls and the readback waits
+    (:meth:`SpanRecorder.note_inner`) are taken out, so that sites,
+    ``dispatchS`` and ``syncWaitS`` add up without overlap."""
+
+    __slots__ = ("name",)
+
+    def __init__(self, name: str):
+        self.name = name
+
+    def __enter__(self):
+        if _tracing_on():
+            rec = SpanRecorder.active
+            ann = _annotation("site:" + self.name, rec, **_open_exec())
+            ann.__enter__()
+            stack = getattr(_site_tls, "stack", None)
+            if stack is None:
+                stack = _site_tls.stack = []
+            # [site, annotation, recorder, begin, seconds not its own]
+            stack.append([self, ann, rec, time.perf_counter(), 0.0])
+        return self
+
+    def __exit__(self, *exc):
+        if not _enabled:
+            return False
+        stack = getattr(_site_tls, "stack", None)
+        if not stack or stack[-1][0] is not self:
+            return False            # entered with tracing off
+        _site, ann, rec, t0, inner = stack.pop()
+        elapsed = time.perf_counter() - t0
+        ann.__exit__(*exc)
+        if stack:
+            stack[-1][4] += elapsed
+        if rec is not None:
+            rec.note_site(self.name, max(0.0, elapsed - inner))
+        return False
+
+    def __call__(self, fn):
+        @functools.wraps(fn)
+        def at_site(*args, **kwargs):
+            if not _tracing_on():
+                return fn(*args, **kwargs)
+            with self:
+                return fn(*args, **kwargs)
+        return at_site
+
+
+def host_site(name: str) -> _HostSite:
+    """The site ``name`` of :data:`HOST_SITES`: ``with host_site(n):`` or
+    ``@host_site(n)``."""
+    site = _SITES.get(name)
+    if site is None:
+        assert name in HOST_SITES, name
+        site = _SITES[name] = _HostSite(name)
+    return site
+
+
+# the process registry's ``tpu_span_seconds`` handles by span name: a
+# lookup per span close would take the registry's lock twice
+_span_hists: Dict[str, Any] = {}
+_span_hists_of: Any = None
+_tel: Any = None
 
 
 def _telemetry_span(name: str, begin: float, elapsed: float,
@@ -147,7 +237,11 @@ def _telemetry_span(name: str, begin: float, elapsed: float,
     boundary): the always-on flight ring gets the span (error-marked
     when it unwound on an exception — the post-mortem breadcrumb), and
     the registry span histogram gets its duration."""
-    from ..service import telemetry as tel
+    global _tel, _span_hists, _span_hists_of
+    tel = _tel
+    if tel is None:
+        from ..service import telemetry as tel
+        _tel = tel
     try:
         if tel._flight_on():
             data = {"beginS": round(begin, 6), "durS": round(elapsed, 6)}
@@ -155,45 +249,72 @@ def _telemetry_span(name: str, begin: float, elapsed: float,
                 data["error"] = True
             tel.FlightRecorder.get().record("span", name, data)
         if metrics_enabled():
-            tel.MetricsRegistry.get().histogram(
-                "tpu_span_seconds", "trace span durations",
-                name=name).observe(elapsed)
+            reg = tel.MetricsRegistry.get()
+            if reg is not _span_hists_of:       # the registry was reset
+                _span_hists, _span_hists_of = {}, reg
+            hist = _span_hists.get(name)
+            if hist is None:
+                hist = _span_hists[name] = reg.histogram(
+                    "tpu_span_seconds", "trace span durations", name=name)
+            hist.observe(elapsed)
     except Exception:
         pass                   # telemetry must never fail the span
 
 
-@contextmanager
-def trace_span(name: str, metrics=None, metric_key: Optional[str] = None):
+class trace_span:
     """Named profiler span (NvtxWithMetrics: optionally also feeds a
     metrics timer). Always feeds the active :class:`SpanRecorder` (the
     per-query wall-clock breakdown) and the ALWAYS-ON flight recorder
     (``service/telemetry``: post-mortems without tracing pre-enabled);
     the jax profiler annotation is config-gated. When ``metrics`` is an
     exec's bag, the span also marks that exec as the innermost open one
-    on this thread (``exec/metrics.exec_scope``) so attributed events —
+    on this thread (``exec/metrics.push_exec``) so attributed events —
     host syncs, recompiles, spill bytes — land on its operator node."""
-    import time
-    rec = SpanRecorder.active
-    t0 = time.perf_counter()
-    frame = rec._push(name) if rec is not None else None
-    err = False
-    try:
-        with exec_scope(metrics):
-            if _tracing_on():
-                with _annotation(name, rec):
-                    yield
-            else:
-                yield
-    except BaseException:
-        err = True
-        raise
-    finally:
+
+    __slots__ = ("name", "metrics", "metric_key", "_rec", "_frame", "_t0",
+                 "_ann")
+
+    def __init__(self, name: str, metrics=None,
+                 metric_key: Optional[str] = None):
+        self.name = name
+        self.metrics = metrics
+        self.metric_key = metric_key
+
+    def __enter__(self):
+        rec = self._rec = SpanRecorder.active
+        self._t0 = time.perf_counter()
+        self._frame = rec._push(self.name) if rec is not None else None
+        if self.metrics is not None:
+            push_exec(self.metrics)
+        if _tracing_on():
+            self._ann = _annotation(self.name, rec)
+            self._ann.__enter__()
+        else:
+            self._ann = None
+        return None
+
+    def __exit__(self, etype, evalue, tb):
+        if self._ann is not None:
+            self._ann.__exit__(etype, evalue, tb)
+        metrics = self.metrics
+        if metrics is not None:
+            pop_exec(metrics)
+        t0 = self._t0
         elapsed = time.perf_counter() - t0
-        if rec is not None:
-            rec._pop(frame, name, elapsed, begin=t0)
-        if metrics is not None and metric_key:
-            metrics.inc(metric_key, elapsed)
-        _telemetry_span(name, t0, elapsed, err)
+        if self._rec is not None:
+            self._rec._pop(self._frame, self.name, elapsed, begin=t0)
+        if metrics is not None and self.metric_key:
+            metrics.inc(self.metric_key, elapsed)
+        _telemetry_span(self.name, t0, elapsed, etype is not None)
+        return False
+
+
+#: the parts of the host ledger a span's own seconds go to: the spans
+#: named here (and what is nested in them) are a part by themselves, the
+#: root keeps what nobody claims, every other span is execution
+_SECTIONS = ("parse", "plan", "operator", "fetch", "root")
+_SECTION_OF = {"parse": "parse", "plan": "plan", "fetch_to_host": "fetch",
+               "query": "root"}
 
 
 class SpanRecorder:
@@ -215,7 +336,6 @@ class SpanRecorder:
 
     def __init__(self, timeline: Optional[bool] = None):
         import collections
-        import threading
         self._self_s = collections.defaultdict(float)
         self._count = collections.defaultdict(int)
         self._mu = named_lock("exec.tracing.SpanRecorder._mu")
@@ -237,6 +357,26 @@ class SpanRecorder:
         self._root: Optional[str] = None   # the driving thread's open root
         self._t0: Optional[float] = None   # entered wall-clock origin
         self._wall: Optional[float] = None
+        # -- the host ledger (:meth:`host_ledger`) -----------------------
+        # the thread that entered the recorder, whose wall the ledger
+        # tiles; the host's own seconds of its spans by ledger section;
+        # its seconds inside program calls and readback waits
+        self._driver: Optional[int] = None
+        self._host_s = dict.fromkeys(_SECTIONS, 0.0)
+        self._driver_inner_s = 0.0
+        # where the caller's call began and ended on the host clock: the
+        # parse of ``session.sql`` where the frame came from it, else the
+        # enter; the exit, or the end of the last resumed span
+        self._origin: Optional[float] = None
+        self._end: Optional[float] = None
+        self._parse_s = 0.0
+        self.parse_cache_hit = 0
+        # seconds the task semaphore was held (acquire to release: it
+        # brackets the task, so it is no self time of any span)
+        self._hold_s = 0.0
+        # host site -> [count, self seconds, those seconds by innermost
+        # open span] (tracing.enabled only)
+        self._sites: Dict[str, list] = {}
         # the query id this recorder's spans belong to (set by the
         # collect that enters the recorder, exec/query_context.py):
         # rides every exported Chrome-trace event so merged multi-worker
@@ -244,18 +384,37 @@ class SpanRecorder:
         self.query_id: Optional[str] = None
 
     def __enter__(self):
-        import time
         self._prev = SpanRecorder.active  # lint: unguarded-ok recorder entered on the driving thread only; pool workers read .active, never swap it
         SpanRecorder.active = self  # lint: unguarded-ok single driving-thread swap; worker reads race only with query start/end, where no spans are open
         self._t0 = time.perf_counter()  # lint: unguarded-ok driving-thread-only enter bookkeeping
+        self._driver = threading.get_ident()  # lint: unguarded-ok driving-thread-only enter bookkeeping
+        if self._origin is None:
+            self._origin = self._t0  # lint: unguarded-ok driving-thread-only enter bookkeeping
         return self
 
     def __exit__(self, *exc):
-        import time
         SpanRecorder.active = self._prev  # lint: unguarded-ok same single driving-thread swap as __enter__
         if self._t0 is not None:
-            self._wall = time.perf_counter() - self._t0  # lint: unguarded-ok driving-thread-only exit bookkeeping
+            self._end = time.perf_counter()  # lint: unguarded-ok driving-thread-only exit bookkeeping
+            self._wall = self._end - self._t0  # lint: unguarded-ok driving-thread-only exit bookkeeping
         return False
+
+    def adopt_parse(self, begin: float, seconds: float, cache_hit: bool
+                    ) -> None:
+        """Take over the ``parse`` span ``session.sql`` timed before this
+        recorder existed: its seconds as a span of this query's report,
+        its begin as the origin of the ledger's ``callS``."""
+        self._origin = begin  # lint: unguarded-ok driving-thread-only, before any span of the query opens
+        self._parse_s = seconds  # lint: unguarded-ok driving-thread-only, before any span of the query opens
+        self.parse_cache_hit = int(cache_hit)
+        with self._mu:
+            self._self_s["parse"] += seconds
+            self._count["parse"] += 1
+            self._host_s["parse"] += seconds
+            if self._timeline:
+                t = threading.current_thread()
+                self._events.append(("parse", begin, seconds, t.ident,
+                                     t.name, None))
 
     def _stack(self):
         st = getattr(self._tls, "stack", None)
@@ -270,10 +429,23 @@ class SpanRecorder:
         st = self._stack()
         # the span that caused this one: the enclosing span on this
         # thread, else (a pool thread's first span) the query's root
-        parent = st[-1]["name"] if st else self._root
-        if not st and self._root is None:
-            self._root = name  # lint: unguarded-ok set once by the driving thread's first span, before any task thread runs
-        frame = {"name": name, "child_s": 0.0, "parent": parent}
+        if st:
+            parent = st[-1]["name"]
+            # what lies under ``parse`` / ``plan`` / ``fetch_to_host``
+            # belongs to them; under the root or an operator span, a span
+            # is what its own name says
+            section = st[-1]["section"]
+            if section == "root" or section == "operator":
+                section = _SECTION_OF.get(name, "operator")
+        else:
+            parent = self._root
+            section = _SECTION_OF.get(name, "operator")
+            if parent is None:
+                self._root = name  # lint: unguarded-ok set once by the driving thread's first span, before any task thread runs
+        # inner_s: the seconds of this frame's SELF time the host spent
+        # inside program calls and readback waits (note_inner)
+        frame = {"name": name, "child_s": 0.0, "parent": parent,
+                 "section": section, "inner_s": 0.0}
         st.append(frame)
         return frame
 
@@ -303,14 +475,46 @@ class SpanRecorder:
         self_s = max(0.0, elapsed - frame["child_s"])
         ev = None
         if self._timeline and begin is not None:
-            import threading
             t = threading.current_thread()
             ev = (name, begin, elapsed, t.ident, t.name, frame["parent"])
+        driver = threading.get_ident() == self._driver
         with self._mu:
             self._self_s[name] += self_s
             self._count[name] += 1
+            if driver:
+                self._host_s[frame["section"]] += max(
+                    0.0, self_s - frame["inner_s"])
             if ev is not None:
                 self._events.append(ev)
+
+    def note_inner(self, seconds: float) -> None:
+        """Seconds the host just spent inside a program call
+        (``exec/compile_cache.Program``) or a counted readback wait
+        (:class:`SyncCounter`): no part of the host's OWN time in the
+        innermost open span, nor in the innermost open host site."""
+        st = getattr(self._tls, "stack", None)
+        if st:
+            st[-1]["inner_s"] += seconds
+        if threading.get_ident() == self._driver:
+            self._driver_inner_s += seconds  # lint: unguarded-ok written by the driving thread alone
+        sites = getattr(_site_tls, "stack", None)
+        if sites:
+            sites[-1][4] += seconds
+
+    def note_site(self, name: str, seconds: float) -> None:
+        """One pass through a host site (:class:`_HostSite`), inside the
+        innermost span open on this thread."""
+        span = self.current_span() or "<no-span>"
+        with self._mu:
+            ent = self._sites.setdefault(name, [0, 0.0, {}])
+            ent[0] += 1
+            ent[1] += seconds
+            ent[2][span] = ent[2].get(span, 0.0) + seconds
+
+    def note_hold(self, seconds: float) -> None:
+        """The task semaphore was released after ``seconds``."""
+        with self._mu:
+            self._hold_s += seconds
 
     def note_rebuild(self, seconds: float) -> None:
         """Charge one compile event of XLA's to the innermost span open
@@ -328,37 +532,45 @@ class SpanRecorder:
                 self.mesh[key] += value
 
     def add(self, name, seconds):
-        """Account an externally-timed interval as a leaf span (semaphore
-        hold time is measured acquire->release, which brackets yields and
-        cannot be a context-managed span)."""
+        """Account an externally-timed interval that has just ended as a
+        leaf span (the wait for the task semaphore): a child of the span
+        open on this thread, where there is one, so that the interval is
+        not that span's self time as well."""
+        st = self._stack()
+        if st:
+            st[-1]["child_s"] += seconds
         ev = None
         if self._timeline:
-            import threading
-            import time
             t = threading.current_thread()
             ev = (name, time.perf_counter() - seconds, seconds,
-                  t.ident, t.name, self.current_span() or self._root)
+                  t.ident, t.name, st[-1]["name"] if st else self._root)
+        driver = threading.get_ident() == self._driver
         with self._mu:
             self._self_s[name] += seconds
             self._count[name] += 1
+            if driver:
+                self._host_s[_SECTION_OF.get(name, "operator")] += seconds
             if ev is not None:
                 self._events.append(ev)
 
     def wall_s(self) -> float:
         """Wall clock between __enter__ and __exit__ (or now, while still
-        open); 0.0 when the recorder was never entered."""
+        open), with the adopted ``parse`` before it and the resumed spans
+        after it; 0.0 when the recorder was never entered."""
         if self._wall is not None:
-            return self._wall
+            return self._wall + self._parse_s
         if self._t0 is None:
             return 0.0
-        import time
-        return time.perf_counter() - self._t0
+        return time.perf_counter() - self._t0 + self._parse_s
 
     def report(self) -> dict:
-        """name -> {selfS, count}, most-expensive first, plus two reserved
-        scalar entries: ``wallS`` (the recorder's wall clock) and
+        """name -> {selfS, count}, most-expensive first, plus three
+        reserved scalar entries: ``wallS`` (the recorder's wall clock),
         ``concurrency`` (sum of self-time over wall — pool threads
-        legitimately push this past 1.0; ~1.0 means serial execution).
+        legitimately push this past 1.0; ~1.0 means serial execution:
+        the self times of a one-thread query tile its wall) and
+        ``semaphoreHoldS`` (seconds the task semaphore was held: it
+        brackets whole tasks, so it is no part of that sum).
         A span under which XLA rebuilt anything also carries
         ``rebuilds`` / ``rebuildS`` (compile events and their seconds)."""
         with self._mu:
@@ -374,7 +586,50 @@ class SpanRecorder:
         wall = self.wall_s()
         out["wallS"] = round(wall, 4)
         out["concurrency"] = round(total_self / wall, 2) if wall > 0 else 0.0
+        out["semaphoreHoldS"] = round(self._hold_s, 4)
         return out
+
+    def host_ledger(self, programs: Dict[str, Dict[str, Any]],
+                    sync_wait_s: float) -> Dict[str, Any]:
+        """The host's account of ONE query, in seconds on the host clock
+        of the thread that ran it (``last_query_metrics()["host"]``,
+        docs/observability.md §9): ``callS`` from the origin (the parse
+        of ``session.sql``, else the action) to the end (the caller's
+        last ``fetch_to_host`` of the result, else the end of the
+        collect), and the parts that tile it. ``dispatchS`` and
+        ``syncWaitS`` are the query's totals (the sums over ``programs``,
+        its reported map, and the ``sync`` report's); what of them ran on
+        pool threads, beside the driving thread's wall and not inside it,
+        is ``offThreadS``.
+        ``unaccountedS`` is what no span but the root names."""
+        with self._mu:
+            host = dict(self._host_s)
+            sites = {k: {"count": n, "s": round(secs, 6),
+                         "bySpan": {sp: round(v, 6)
+                                    for sp, v in sorted(by.items())}}
+                     for k, (n, secs, by) in sorted(self._sites.items())}
+        dispatches = sum(p["dispatches"] for p in programs.values())
+        dispatch_s = sum(p["dispatchS"] for p in programs.values())
+        end = self._end if self._end is not None else time.perf_counter()
+        call_s = end - self._origin if self._origin is not None else 0.0
+        off_thread_s = max(
+            0.0, dispatch_s + sync_wait_s - self._driver_inner_s)
+        named = (host["parse"] + host["plan"] + host["operator"] +
+                 host["fetch"] + dispatch_s + sync_wait_s - off_thread_s)
+        return {
+            "callS": round(call_s, 6),
+            "parseS": round(host["parse"], 6),
+            "parseCacheHit": self.parse_cache_hit,
+            "planS": round(host["plan"], 6),
+            "dispatchS": round(dispatch_s, 6),
+            "dispatches": dispatches,
+            "syncWaitS": round(sync_wait_s, 6),
+            "operatorS": round(host["operator"], 6),
+            "fetchS": round(host["fetch"], 6),
+            "offThreadS": round(off_thread_s, 6),
+            "unaccountedS": round(call_s - named, 6),
+            "sites": sites,
+        }
 
     # -- Chrome-trace / Perfetto timeline export ----------------------------
     def chrome_trace(self) -> dict:
@@ -482,6 +737,14 @@ def record_span(name: str, seconds: float) -> None:
         rec.add(name, seconds)
 
 
+def record_hold(seconds: float) -> None:
+    """The task semaphore's hold, acquire to release, into the active
+    recorder's ``semaphoreHoldS``."""
+    rec = SpanRecorder.active
+    if rec is not None:
+        rec.note_hold(seconds)
+
+
 class QueryRecording:
     """The recorders of ONE collect: a :class:`SyncCounter` and a
     :class:`SpanRecorder` with the root span ``query`` open. Opened
@@ -494,8 +757,12 @@ class QueryRecording:
         self.spans = SpanRecorder()
         self._open = None
 
-    def open(self) -> "QueryRecording":
+    def open(self, parsed: Optional[tuple] = None) -> "QueryRecording":
+        """``parsed``: the (begin, seconds, cache hit) ``session.sql``
+        left on the frame this query collects, if it built it."""
         import contextlib
+        if parsed is not None:
+            self.spans.adopt_parse(*parsed)
         with contextlib.ExitStack() as st:
             st.enter_context(self.sync)
             st.enter_context(self.spans)
@@ -513,14 +780,15 @@ class QueryRecording:
     @contextmanager
     def resumed(self, name: str):
         """A span of the same query after its recorder closed: recorded
-        with the query's id, its time added to the recorder's wall."""
-        import time
+        with the query's id, its time added to the recorder's wall, its
+        end the end of the ledger's ``callS``."""
         rec = self.spans
         if self._open is not None or SpanRecorder.active is not None:
             with trace_span(name):    # still (or again) inside a query
                 yield
             return
         SpanRecorder.active = rec  # lint: unguarded-ok the caller's thread, after its query ended and with no other recorder active
+        rec._driver = threading.get_ident()  # lint: unguarded-ok the caller's thread, after its query ended
         t0 = time.perf_counter()
         try:
             with trace_span(name):
@@ -528,7 +796,8 @@ class QueryRecording:
         finally:
             SpanRecorder.active = None  # lint: unguarded-ok restores the idle state checked above
             if rec._wall is not None:
-                rec._wall += time.perf_counter() - t0  # lint: unguarded-ok caller-thread bookkeeping after the query ended
+                rec._end = time.perf_counter()  # lint: unguarded-ok caller-thread bookkeeping after the query ended
+                rec._wall += rec._end - t0  # lint: unguarded-ok caller-thread bookkeeping after the query ended
 
 
 # ---------------------------------------------------------------------------
@@ -626,17 +895,18 @@ class SyncCounter:
     def _timed_read(self, read, arr):
         """Count the readback, then time the wait for it; under
         ``tracing.enabled`` the wait is a ``host_sync`` profiler span."""
-        import time
         site, span = self._record()
+        rec = SpanRecorder.active
         t0 = time.perf_counter()
         try:
             if _tracing_on():
-                with _annotation("host_sync", SpanRecorder.active,
-                                 site=site):
+                with _annotation("host_sync", rec, site=site):
                     return read(arr)
             return read(arr)
         finally:
             waited = time.perf_counter() - t0
+            if rec is not None:
+                rec.note_inner(waited)
             self.wait_s += waited  # lint: unguarded-ok best-effort counter, see total in _record
             self.site_wait_s[site] = self.site_wait_s.get(site, 0.0) + waited  # lint: unguarded-ok best-effort counter map, see total in _record
             self.span_wait_s[span] = self.span_wait_s.get(span, 0.0) + waited  # lint: unguarded-ok best-effort counter map, see total in _record
@@ -672,7 +942,6 @@ class SyncCounter:
 
     # -- context ------------------------------------------------------------
     def __enter__(self):
-        import threading
         cls = SyncCounter
         cls._install()
         if cls._tls is None:

@@ -19,6 +19,7 @@ import numpy as np
 
 from ..columnar import dtypes as dt
 from ..columnar.batch import ColumnarBatch
+from ..exec.tracing import host_site
 from ..columnar.column import (Column, Scalar, device_scalar,
                                float64_from_words, float64_words)
 
@@ -339,6 +340,7 @@ def string_literal_array(value: str) -> np.ndarray:
     return out
 
 
+@host_site("param_args")
 def param_arg_values(params: Sequence["Literal"]) -> tuple:
     """The current value of each of :func:`ordered_params` as a
     dtype-stable numpy array — the extra jit arguments appended after a

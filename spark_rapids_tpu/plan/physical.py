@@ -31,7 +31,8 @@ from ..ops import expressions as ex
 from ..ops import kernels as K
 from ..ops import aggregates as agg_k
 from ..ops import joins as join_k
-from ..exec.tracing import operator_scope, shared_stage, trace_span
+from ..exec.tracing import (host_site, operator_scope, shared_stage,
+                            trace_span)
 from . import logical as lp
 
 Partition = Iterator[ColumnarBatch]
@@ -55,6 +56,7 @@ def bind_refs(e: ex.Expression, schema: dt.Schema) -> ex.Expression:
 # Metrics (GpuMetricNames, GpuExec.scala:27-56)
 # ---------------------------------------------------------------------------
 
+@host_site("count_arg")
 def _dev_count(batch) -> "Any":
     """A batch's row count as a device int32 scalar for a fused-program
     argument — WITHOUT forcing a host sync when the count is still
@@ -136,8 +138,12 @@ class TpuExec:
         from ..exec.tasks import run_partition_tasks
 
         try:
-            per_part = run_partition_tasks(
-                self.execute(), lambda pid, part: drain_spillable(part))
+            # ``drain``, once a query: its own time is the pull through
+            # the operators' iterators, between their spans (with several
+            # partitions, the wait for the task pool)
+            with trace_span("drain"):
+                per_part = run_partition_tasks(
+                    self.execute(), lambda pid, part: drain_spillable(part))
             with trace_span("collect_concat"):
                 return concat_spillable(
                     self.schema, [s for lst in per_part for s in lst])
@@ -154,15 +160,23 @@ class TpuExec:
         consumer closes it early (generator finally)."""
         from ..exec.tasks import stream_partition_tasks
 
+        done = object()
+        drains = stream_partition_tasks(
+            self.execute(), lambda pid, part: drain_spillable(part))
         try:
-            for spillables in stream_partition_tasks(
-                    self.execute(),
-                    lambda pid, part: drain_spillable(part)):
+            while True:
+                # ``drain``, once a partition: the wait for its task (the
+                # consumer's time between two batches is outside it)
+                with trace_span("drain"):
+                    spillables = next(drains, done)
+                if spillables is done:
+                    break
                 if not spillables:
                     continue
                 with trace_span("collect_concat"):
                     yield concat_spillable(self.schema, spillables)
         finally:
+            drains.close()
             self.cleanup()
 
     def cleanup(self) -> None:
@@ -297,18 +311,20 @@ def _prepare_stateful(exprs: List[ex.Expression], pid: int
     return exprs, [n for n in stateful if hasattr(n, "advance")]
 
 
+@host_site("admission")
 def _task_begin() -> None:
     """Device admission at task (partition evaluation) start: the semaphore
     bounds concurrently-executing device tasks. Ordering contract preserved
     from the reference (GpuSemaphore.scala:74-78): acquire after host-side
     input is ready, before device work. The semaphore itself records the
-    wait-vs-hold span split (``semaphore_wait`` / ``semaphore_hold``) —
-    the NVTX-range analog of GpuSemaphore.scala:107, but separable into
-    admission contention vs device occupancy."""
+    wait-vs-hold split (span ``semaphore_wait`` / the span report's
+    ``semaphoreHoldS``) — the NVTX-range analog of GpuSemaphore.scala:107,
+    but separable into admission contention vs device occupancy."""
     from ..exec.device import TpuSemaphore
     TpuSemaphore.get().acquire_if_necessary()
 
 
+@host_site("admission")
 def _reserve(nbytes: int) -> None:
     """Admission-check ~nbytes of imminent device materialization against the
     spill catalog (DeviceMemoryEventHandler.onAllocFailure analog): spills
@@ -517,7 +533,8 @@ def _fusion_enabled(node) -> bool:
     if flag is not None:
         return flag
     from .. import config as cfg
-    return bool(cfg.TpuConf().get(cfg.WHOLESTAGE_FUSION))
+    with host_site("conf_read"):
+        return bool(cfg.TpuConf().get(cfg.WHOLESTAGE_FUSION))
 
 
 # Fused programs cache GLOBALLY on (expression structure, schema dtypes,
@@ -567,6 +584,7 @@ class _trace_exec:
         _trace_exec_stack().pop()
 
 
+@host_site("program_lookup")
 def _fused_fn(key: tuple, builder):
     from ..analysis import recompile as _recompile
     from ..exec import compile_cache as _cc
@@ -601,6 +619,7 @@ def fused_cached(key: tuple) -> bool:
     return key in _FUSED_CACHE
 
 
+@host_site("flat_args")
 def _donate_argnums(batch: ColumnarBatch, start: int) -> tuple:
     """jit argnums donating ``batch``'s flat arrays to a fused program
     that CONSUMES the batch (XLA reuses/frees the HBM eagerly), or ()
@@ -637,6 +656,7 @@ def _donation_consumed(batch: ColumnarBatch) -> bool:
         return True
 
 
+@host_site("flat_args")
 def _note_donated(batch: ColumnarBatch, donate: tuple) -> None:
     """After a SUCCESSFUL donated fused invocation: tombstone ``batch``
     in the buffer-lifecycle ledger (analysis/ledger.py) — its arrays are
@@ -648,6 +668,7 @@ def _note_donated(batch: ColumnarBatch, donate: tuple) -> None:
         ledger.mark_donated(batch)
 
 
+@host_site("program_key")
 def _schema_sig(schema: dt.Schema) -> tuple:
     return tuple(f.dtype.name for f in schema)
 
@@ -963,8 +984,10 @@ class TpuLocalScanExec(TpuExec):
             if first:
                 _task_begin()
                 first = False
-            with trace_span("scan_upload", self.metrics, "scanTime"):
-                if kind == "cached":
+            batch = None
+            if kind == "cached":
+                # served by the device scan cache: nothing is uploaded
+                with trace_span("scan_cached", self.metrics, "scanTime"):
                     try:
                         batch = payload.get_batch()
                         batch.origin = payload
@@ -976,11 +999,10 @@ class TpuLocalScanExec(TpuExec):
                                 del cache[key]
                                 TpuLocalScanExec._device_cache_bytes -= \
                                     payload.size_bytes
-                        kind = "prep"
-                        payload = ColumnarBatch.prep_from_arrow(
-                            self.table.slice(*key))
-                if kind != "cached":
-                    prepped = payload
+            if batch is None:
+                with trace_span("scan_upload", self.metrics, "scanTime"):
+                    prepped = payload if kind == "prep" else \
+                        ColumnarBatch.prep_from_arrow(self.table.slice(*key))
                     nbytes = ColumnarBatch.prepped_size_bytes(prepped)
                     _reserve(nbytes)
                     batch = ColumnarBatch.upload_prepped(prepped)
@@ -1364,6 +1386,7 @@ class TpuHashAggregateExec(TpuExec):
         if mode == "partial":
             self._out_schema = self._partial_schema()
 
+    @host_site("shrink")
     def _partial_schema(self) -> dt.Schema:
         """Internal partial-form schema: key cols + per-leaf update cols
         (identical construction in the upstream partial and downstream final
@@ -1469,16 +1492,22 @@ class TpuHashAggregateExec(TpuExec):
             if len(pending) >= self.MERGE_FAN_IN:
                 merge_pending()
 
-        depth = max(1, int(cfg.TpuConf().get(cfg.AGG_PIPELINE_DEPTH)))
+        with host_site("conf_read"):
+            depth = max(1, int(cfg.TpuConf().get(cfg.AGG_PIPELINE_DEPTH)))
         # metrics=: the window's batched readbacks charge THIS exec's
         # hostSyncs (exec/metrics.exec_scope), not just the span string
         win = PipelineWindow(depth, metrics=self.metrics)
         for batch in batches:
-            # semaphore ordering contract: acquire only once the first input
-            # batch exists (upstream host IO done), GpuSemaphore.scala:74-78
-            _task_begin()
-            _reserve(batch.device_size_bytes())
+            # the span covers ALL the host does for the batch: admission
+            # and banking the partial too (the host sites of
+            # exec/tracing.HOST_SITES lie inside it)
             with trace_span("aggregate", self.metrics, "computeAggTime"):
+                # semaphore ordering contract: acquire only once the first
+                # input batch exists (upstream host IO done),
+                # GpuSemaphore.scala:74-78; the wait is the child span
+                # ``semaphore_wait``
+                _task_begin()
+                _reserve(batch.device_size_bytes())
                 if self.mode == "final":
                     ready = win.push(lambda b=batch: b)
                 else:
@@ -1566,6 +1595,7 @@ class TpuHashAggregateExec(TpuExec):
     #: and its device-resident count.
     SHRINK_ABOVE_SLOTS = 4096
 
+    @host_site("shrink")
     def _shrink_fused(self, kind: str, pb: ColumnarBatch) -> ColumnarBatch:
         """A fused phase's output (``_fused_dispatch``'s token, spread),
         shrunk where it is large (a small one keeps its device-resident
@@ -1610,6 +1640,7 @@ class TpuHashAggregateExec(TpuExec):
 
     # -- whole-stage fused group-by (expression eval + kernel in ONE
     # device program per batch; see the fusion section above) ---------------
+    @host_site("program_key")
     def _fusion_sig(self, phase: str, in_schema: dt.Schema):
         gk = [_expr_cache_key(g) for g in self.grouping]
         bk = [None if b is None else _expr_cache_key(b)
@@ -1663,12 +1694,13 @@ class TpuHashAggregateExec(TpuExec):
         ``("sorted", partial)`` for a group-by, or None -> eager."""
         if getattr(self, "_fusion_broken", False) or not _fusion_enabled(self):
             return None
-        if not all(e.tree_fusable() for e in self.grouping) or any(
-                b is not None and not b.tree_fusable()
-                for b in self.bound_leaf_inputs):
-            return None
-        if self.pre_stage is not None and not self.pre_stage.fusable():
-            return None
+        with host_site("fusable"):
+            if not all(e.tree_fusable() for e in self.grouping) or any(
+                    b is not None and not b.tree_fusable()
+                    for b in self.bound_leaf_inputs):
+                return None
+            if self.pre_stage is not None and not self.pre_stage.fusable():
+                return None
         import jax
 
         in_schema = batch.schema
@@ -1817,14 +1849,16 @@ class TpuHashAggregateExec(TpuExec):
         final phase (merge groupby -> leaf assembly -> result expressions)."""
         if getattr(self, "_fusion_broken", False) or not _fusion_enabled(self):
             return None
-        if not all(e.tree_fusable() for e in self.aggregate_exprs):
-            return None
+        with host_site("fusable"):
+            if not all(e.tree_fusable() for e in self.aggregate_exprs):
+                return None
         import jax
         import jax.numpy as jnp
         sig = self._fusion_sig("final", batch.schema)
         if sig is None:
             return None
-        rkeys = [_expr_cache_key(e) for e in self.aggregate_exprs]
+        with host_site("program_key"):
+            rkeys = [_expr_cache_key(e) for e in self.aggregate_exprs]
         if any(k is None for k in rkeys):
             return None
         in_schema = batch.schema
@@ -2551,7 +2585,8 @@ class TpuSortMergeJoinExec(TpuExec):
         d = getattr(self, "pipeline_depth", None)
         if d is None:
             from .. import config as cfg
-            d = cfg.TpuConf().get(cfg.JOIN_PIPELINE_DEPTH)
+            with host_site("conf_read"):
+                d = cfg.TpuConf().get(cfg.JOIN_PIPELINE_DEPTH)
         return max(1, int(d))
 
     def _join_part(self, part: Partition,

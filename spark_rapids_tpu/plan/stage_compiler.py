@@ -40,7 +40,7 @@ from ..columnar.batch import ColumnarBatch
 from ..columnar.column import Scalar
 from ..ops import expressions as ex
 from ..ops import kernels as K
-from ..exec.tracing import operator_scope, trace_span
+from ..exec.tracing import host_site, operator_scope, trace_span
 from . import physical as ph
 from .physical import (Partition, TpuExec, _dev_count, _donate_argnums,
                        _donation_consumed, _expr_cache_key, _fused_fn,
@@ -90,6 +90,7 @@ class StageChain:
         return all(e.tree_fusable() for e in self.exprs()) and not any(
             e.collect(lambda x: not x.side_effect_free) for e in self.exprs())
 
+    @host_site("program_key")
     def cache_key(self) -> Optional[tuple]:
         """Structural key of the whole chain, or None when any expression
         is unkeyable (the stage then stays on the per-op path — a per-exec

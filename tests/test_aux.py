@@ -300,7 +300,7 @@ def test_span_breakdown_names_query_time():
     # reserved query-level scalars ride next to the per-name records
     assert spans["wallS"] > 0.0 and spans["concurrency"] >= 0.0
     for name, rec in spans.items():
-        if name in ("wallS", "concurrency"):
+        if name in ("wallS", "concurrency", "semaphoreHoldS"):
             continue
         assert rec["selfS"] >= 0.0 and rec["count"] >= 1, (name, rec)
     # the aggregate/sort pipeline must be named

@@ -371,9 +371,13 @@ def test_cold_or_disk_is_what_xla_reports(default_compile_conf):
     ent = recompile.delta(base)["test25/xla-truth"]
     assert (ent["diskHits"], ent["coldCompiles"], ent["calls"]) == (1, 1, 1)
     assert ent["compileS"] == pytest.approx(0.875)
-    assert rec.programs["test25/xla-truth"] == {
+    called = dict(rec.programs["test25/xla-truth"])
+    # the host's seconds inside the one call (PR 36): a clock reading
+    assert 0.0 < called.pop("dispatchS") < 1.0
+    assert called == {
         "dispatches": 1, "traces": 0, "traceS": 0.0, "lowerS": 0.125,
         "compiles": 1, "compileS": 0.5, "cacheLoads": 1, "loadS": 0.25}
+    assert rec.programs["<eager>:x"]["dispatchS"] == 0.0
     eager = rec.programs["<eager>:x"]
     assert (eager["dispatches"], eager["compiles"],
             eager["cacheLoads"]) == (0, 1, 1)

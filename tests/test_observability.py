@@ -194,7 +194,8 @@ def test_timeline_round_trips_valid_chrome_trace(tmp_path):
             assert e["name"] and e["tid"] in named_tids, e
         # the span names match the flat report's names
         rep_names = {n for n in rec.report()
-                     if n not in ("wallS", "concurrency")}
+                     if n not in ("wallS", "concurrency",
+                                  "semaphoreHoldS")}
         assert {e["name"] for e in xs} <= rep_names | {"process_name"}
     finally:
         from spark_rapids_tpu.exec import tracing
